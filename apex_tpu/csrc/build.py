@@ -2,13 +2,16 @@
 
 The reference ships csrc/ as setuptools CUDAExtensions (setup.py:96-589) and
 falls back to Python when the modules are absent; here the build is a single
-``g++ -O3 -shared`` invocation, cached beside the source, with the same
-fallback stance.
+``g++ -O3 -shared`` invocation, cached beside the source under a name that
+carries a hash of the source (a copied tree keeps no mtimes, and a library
+built from other source must never be trusted), with the same fallback
+stance.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,26 +21,36 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "apex_runtime.cpp")
-_LIB_PATH = os.path.join(_DIR, "_apex_runtime.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_apex_runtime.{digest}.so")
+
+
 def _build() -> Optional[ctypes.CDLL]:
     global _build_failed
     try:
-        if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC):
-            return ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        pass  # stale/corrupt/wrong-arch cache: fall through to rebuild
-    try:
+        lib_path = _lib_path()
+        try:
+            if os.path.exists(lib_path):
+                return ctypes.CDLL(lib_path)
+        except OSError:
+            pass  # corrupt/wrong-arch cache: fall through to rebuild
+        # build under a private name, then rename: a concurrent process
+        # never loads a half-written library
+        tmp = f"{lib_path}.{os.getpid()}.tmp"
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-             _SRC, "-o", _LIB_PATH],
+             _SRC, "-o", tmp],
             check=True, capture_output=True, timeout=120,
         )
-        return ctypes.CDLL(_LIB_PATH)
+        os.replace(tmp, lib_path)
+        return ctypes.CDLL(lib_path)
     except Exception:  # noqa: BLE001 - any failure selects the numpy fallback
         _build_failed = True
         return None

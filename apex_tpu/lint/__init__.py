@@ -2,8 +2,8 @@
 
 The repo's hardest-won correctness and performance invariants used to be
 enforced by hand: CLAUDE.md prose (never differentiate a bare
-``lax.psum``/``pmean`` of the loss; never time off a bare
-``block_until_ready``; the T(8,128) lane-padding tax) plus one ad-hoc AST
+``lax.psum``/``pmean`` of the loss; the T(8,128) lane-padding tax) plus
+one ad-hoc AST
 walker inside tests/test_diagnose.py. veScale-style SPMD stacks (PAPERS.md,
 arxiv 2509.07003) and the cross-replica weight-update sharding work (arxiv
 2004.13336) both argue for MECHANICAL consistency checking of
@@ -16,8 +16,7 @@ Three engines:
   ``python -m apex_tpu.lint [--strict] [--format json]``): walks
   ``apex_tpu/`` + ``examples/`` + ``benchmarks/`` and enforces the named,
   individually suppressable rules (``comm-scope``, ``grad-collective``,
-  ``pallas-interpret``, ``module-citation``, ``bare-block-until-ready``,
-  ``exception-retention``). Wired into tier-1 as tests/test_lint.py: the
+  ``pallas-interpret``, ``module-citation``, ``exception-retention``). Wired into tier-1 as tests/test_lint.py: the
   repo must lint clean, every suppression justified.
 - **Engine 2 -- jaxpr/trace analyzers** (:mod:`trace`): hazards XLA
   compiles without complaint -- :func:`trace.lane_padding_report` (bytes

@@ -211,8 +211,7 @@ def fit(records: Sequence[Dict[str, Any]],
     - ``peak_flops``: the median achieved FLOP/s
       (``predicted.flops_per_step / measured wall p50``) — the ceiling
       under which the cost model's compute seconds equal the measured
-      wall for compute-bound runs (the honest tunnel denominator,
-      PERF_NOTES "71-78 TF/s sustained vs the datasheet").
+      wall for compute-bound runs.
     - ``peak_ici_bytes_per_sec``: the median of booked-or-predicted comm
       bytes over the non-compute residual of the wall (clamped to at
       least ``min_comm_frac`` of the wall so a compute-saturated record

@@ -5,7 +5,7 @@ driver) asks one question per mode:
 
 - ``report <run.jsonl>``: is this run healthy? Prints throughput
   percentiles, stall gaps (wall-clock holes between step records — the
-  wedged-tunnel / co-tenant-spike signature), the loss-spike list,
+  wedged-device / contended-chip signature), the loss-spike list,
   HBM-growth trend (the below-Python leak detector's journal-side view),
   per-rank straggler skew, comm-bytes-per-axis rollup, MFU summary, and
   recompile/forensics rollups.

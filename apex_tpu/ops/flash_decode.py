@@ -56,7 +56,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.flash_attention import _NEG_INF, _NUM_LANES
-from apex_tpu.ops.layer_norm import _interpret, _resolve_impl
+from apex_tpu.ops.layer_norm import (
+    _interpret,
+    _pallas_unsupported,
+    _resolve_impl,
+)
 
 
 def paged_attention_reference(
@@ -234,7 +238,9 @@ def flash_decode(
     scale = (d ** -0.5) if scale is None else float(scale)
     use = _resolve_impl(impl)
     if use == "pallas" and (blk % 8 or d < 8):
-        use = "xla"  # sub-tile pages: fall back like flash_attention does
+        use = _pallas_unsupported(
+            "flash_decode", impl,
+            f"page block={blk}, head_dim={d} is below one (8, 8) tile")
     if use == "xla":
         return paged_attention_reference(
             q, k_pages, v_pages, block_tables, lengths,
@@ -369,13 +375,17 @@ def flash_decode_multi(
     scale = (d ** -0.5) if scale is None else float(scale)
     use = _resolve_impl(impl)
     if use == "pallas" and (blk % 8 or d < 8):
-        use = "xla"  # sub-tile pages: fall back like flash_attention does
+        use = _pallas_unsupported(
+            "flash_decode_multi", impl,
+            f"page block={blk}, head_dim={d} is below one (8, 8) tile")
     if use == "pallas" and (h // kh) * kq > 1024:
         # the kernel's scratch (acc (g*K, d) + m/l (g*K, lanes), all f32)
         # scales linearly with the query rows — past ~1k rows it crowds
-        # VMEM; fall back to the dense path rather than fail Mosaic
-        # (serve/engine.py clamps its chunk width below this)
-        use = "xla"
+        # VMEM (serve/engine.py clamps its chunk width below this)
+        use = _pallas_unsupported(
+            "flash_decode_multi", impl,
+            f"{(h // kh) * kq} query rows per kv head exceed the 1024 the "
+            "kernel's VMEM scratch holds")
     if use == "xla":
         return paged_attention_multi_reference(
             q, k_pages, v_pages, block_tables, lengths,

@@ -25,6 +25,7 @@ testable on CPU.
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -47,6 +48,17 @@ def _resolve_impl(impl: str) -> str:
     if impl not in ("pallas", "xla"):
         raise ValueError(f"impl must be 'auto' | 'pallas' | 'xla', got {impl!r}")
     return impl
+
+
+def _pallas_unsupported(op: str, impl: str, why: str) -> str:
+    """A call the Pallas kernel cannot take. An explicit ``impl='pallas'``
+    raises; ``'auto'`` says so (Python shows a warning once per call site)
+    and returns ``'xla'`` — a kernel that silently became its XLA twin is
+    how a run on the chip "passes" without running the kernel."""
+    if impl == "pallas":
+        raise ValueError(f"{op}: impl='pallas' but {why}")
+    warnings.warn(f"{op}: {why}; taking the XLA path", stacklevel=3)
+    return "xla"
 
 
 def _row_block(n_rows: int, hidden: int) -> int:

@@ -633,8 +633,12 @@ class Engine:
     @property
     def stats(self) -> Dict[str, Any]:
         """Host-side feature counters (prefix sharing / COW / speculation)
-        — the numbers the serve evidence and the example harness print."""
-        s: Dict[str, Any] = {"cow_forks": self.cow_forks}
+        and what is allocated right now (pages, seated slots: both 0 after
+        a drained run) — the numbers the serve evidence, the example
+        harness and ``chip_smoke.py`` read."""
+        s: Dict[str, Any] = {"cow_forks": self.cow_forks,
+                             "pages_used": self.allocator.used,
+                             "active_slots": len(self.batcher.active)}
         if self.prefix_cache is not None:
             pc = self.prefix_cache
             s.update(prefix_hits=pc.hits, prefix_misses=pc.misses,

@@ -784,9 +784,6 @@ def traced_pipeline_timeline(
     global _RING_DRIVES
     _RING_DRIVES += 1
     from apex_tpu.monitor import tracing as tracing_mod
-    from apex_tpu.utils.compat import ensure_jax_compat
-
-    ensure_jax_compat()  # jax<0.5 shard_map rename (library-safe, idempotent)
     from jax.sharding import NamedSharding
 
     tr = tracer if tracer is not None else tracing_mod.get_tracer()
@@ -1300,9 +1297,6 @@ def traced_schedule_timeline(
     global _RING_DRIVES
     _RING_DRIVES += 1
     from apex_tpu.monitor import tracing as tracing_mod
-    from apex_tpu.utils.compat import ensure_jax_compat
-
-    ensure_jax_compat()
     from jax.sharding import NamedSharding
 
     tr = tracer if tracer is not None else tracing_mod.get_tracer()

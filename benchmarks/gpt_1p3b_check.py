@@ -34,10 +34,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from apex_tpu.utils.io import atomic_write_json  # noqa: E402
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -275,9 +275,6 @@ def main() -> int:
         jax.config.update("jax_platforms", "cpu")
     except Exception:  # noqa: BLE001 - backend already up: run on it
         pass
-    from apex_tpu.utils.compat import ensure_jax_compat
-
-    ensure_jax_compat()
 
     record = {"evidence": "auto-parallelism planner: blind picks + "
                           "calibration closure (ISSUE 18)"}

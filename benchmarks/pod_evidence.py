@@ -50,7 +50,6 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8"
                            ).strip()
 
-from apex_tpu.utils.compat import ensure_jax_compat  # noqa: E402
 from apex_tpu.utils.io import atomic_write_json  # noqa: E402
 
 import jax  # noqa: E402
@@ -59,8 +58,6 @@ try:
     jax.config.update("jax_platforms", "cpu")
 except Exception:  # noqa: BLE001 - backend already up: run on it
     pass
-
-ensure_jax_compat()
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402

@@ -47,19 +47,15 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from apex_tpu.utils.io import atomic_write_json  # noqa: E402
 
+if not os.environ.get("JAX_PLATFORMS"):
+    # its latencies mean one thing on a CPU and another on a chip: the
+    # caller says which, the script does not pick
+    raise SystemExit("serve_bench.py: set JAX_PLATFORMS (cpu for the "
+                     "structural evidence, tpu on the chip)")
+
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-else:
-    jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp
 import numpy as np
-
-from apex_tpu.utils.compat import ensure_jax_compat
-
-ensure_jax_compat()
 
 from apex_tpu.lint.trace import decode_recompile_hazards
 from apex_tpu.models import GPTConfig, GPTModel

@@ -17,16 +17,13 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu import amp
 from apex_tpu.models import BertConfig, BertModel
 from apex_tpu.optimizers import FusedLAMB
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def parse_args():
@@ -127,6 +124,7 @@ def synthetic_batch(rng, batch, seq, vocab):
 
 def main():
     args = parse_args()
+    enable_compile_cache()
     cfg = BertConfig(
         hidden_size=args.hidden, num_layers=args.layers,
         num_attention_heads=args.heads, max_seq_len=args.seq,
@@ -144,9 +142,7 @@ def main():
         from jax.sharding import Mesh, PartitionSpec as P
 
         from apex_tpu.parallel import collectives
-        from apex_tpu.utils.compat import ensure_jax_compat
 
-        ensure_jax_compat()  # jax<0.5: shard_map/axis_size API renames
         n_dev = len(jax.devices())
         if args.batch % n_dev:
             raise SystemExit(f"--batch {args.batch} must divide the "

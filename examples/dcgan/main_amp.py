@@ -19,15 +19,12 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 from flax import linen as nn
 
 from apex_tpu import amp
 from apex_tpu.optimizers import FusedAdam
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 class Generator(nn.Module):
@@ -67,6 +64,7 @@ def main():
     p.add_argument("--batch", type=int, default=32)
     p.add_argument("--nz", type=int, default=32)
     args = p.parse_args()
+    enable_compile_cache()
 
     policy = amp.get_policy("O2")
     G, D = Generator(), Discriminator()

@@ -31,24 +31,17 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
-
-from apex_tpu.utils.compat import ensure_jax_compat
-
-ensure_jax_compat()  # jax<0.5: shard_map/axis_size API renames
 
 from apex_tpu import checkpoint
 from apex_tpu.models import GPTConfig, GPTModel
 from apex_tpu.parallel import mesh as mesh_lib
 from apex_tpu.serve import Engine, Request, ServeConfig
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--hidden", type=int, default=256)
@@ -130,7 +123,7 @@ def parse_args():
                         "rollup) to the run ledger "
                         "(apex_tpu.monitor.ledger); "
                         "APEX_TPU_LEDGER=<path> arms it too")
-    args = p.parse_args()
+    args = p.parse_args(argv)
     if not args.ledger and os.environ.get("APEX_TPU_LEDGER"):
         args.ledger = os.environ["APEX_TPU_LEDGER"]
     if args.flight == "auto":
@@ -154,8 +147,12 @@ def load_prompts(args) -> list:
             for n in (5, 12, 3, 9, 17, 7)]
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Serve; returns the run's record — per-request ``tokens``,
+    ``ttft_s`` and ``itl_s`` keyed by request id, the engine's ``ticks``
+    and ``stats``."""
+    args = parse_args(argv)
+    enable_compile_cache()
     mesh = None
     if args.tp > 1:
         mesh = mesh_lib.make_virtual_mesh(
@@ -306,6 +303,13 @@ def main():
             print(f"chrome export failed: {e}")
     if mesh is not None:
         mesh_lib.destroy_model_parallel()
+    return {
+        "tokens": {rid: list(r.tokens) for rid, r in results.items()},
+        "ttft_s": {rid: r.ttft_s for rid, r in results.items()},
+        "itl_s": {rid: list(r.itl_s) for rid, r in results.items()},
+        "ticks": engine.ticks,
+        "stats": stats,
+    }
 
 
 if __name__ == "__main__":

@@ -25,17 +25,9 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from apex_tpu.utils.compat import ensure_jax_compat
-
-ensure_jax_compat()  # jax<0.5: shard_map/axis_size API renames
 
 from apex_tpu import amp, checkpoint
 from apex_tpu.models import GPTConfig, GPTModel
@@ -48,6 +40,7 @@ from apex_tpu.parallel.distributed import (
 from apex_tpu.parallel.multiproc import initialize_distributed
 from apex_tpu.transformer import tensor_parallel as tp_mod
 from apex_tpu.transformer.pipeline_parallel import pipeline_specs, pipelined_loss_fn
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def _apply_plan(args):
@@ -117,7 +110,7 @@ def _apply_plan(args):
         "ici_source": result["ici_spec"]["source"]}}))
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--pp", type=int, default=1)
@@ -276,7 +269,7 @@ def parse_args():
                         "watchdog kill — with an HBM snapshot and the "
                         "last loss-scale state. Default PATH: "
                         "<journal>.flight.json")
-    args = p.parse_args()
+    args = p.parse_args(argv)
     if args.plan:
         if args.plan != "auto":
             p.error("--plan accepts 'auto' (the static placement search)")
@@ -362,8 +355,17 @@ def parse_args():
     return args
 
 
-def main():
-    args = parse_args()
+def main(argv=None):
+    """Train; returns the run's record — per-step ``losses``,
+    ``loss_scales`` and ``found_inf`` flags, ``first_step_seconds`` (entry
+    to the first loss on the host: set-up, compile and one step),
+    ``seconds_per_step`` after it, and the live ``train_step`` /
+    ``params`` / ``opt_state`` / ``next_batch`` for a caller that keeps
+    driving the step (``chip_smoke.py`` reads its compiled text and times
+    it)."""
+    t_entry = time.perf_counter()
+    args = parse_args(argv)
+    enable_compile_cache()
     initialize_distributed()  # no-op single-process
     n_dev = len(jax.devices())
     mesh = mesh_lib.make_virtual_mesh(
@@ -621,6 +623,23 @@ def main():
                 opt_state, params, scaled_grads)
             return new_params, new_state, scaled_loss / opt_state.scaler.loss_scale, metrics
 
+    if hasattr(train_step, "lower"):
+        # A jitted step returns its state under shardings spelled XLA's
+        # way (size-1 axes dropped, scalars committed to the mesh), not
+        # the way the state was built, so a step fed its own output would
+        # compile a second time — inside the timed steps. Commit the
+        # state to the mesh and pin the step's outputs to its inputs'
+        # shardings: the placement is the same, and so is the cache key.
+        # (The offload and two-program traced drives are host code.)
+        replicated = NamedSharding(mesh, P())
+        params, opt_state = jax.tree.map(
+            lambda a: a if isinstance(a.sharding, NamedSharding)
+            else jax.device_put(a, replicated), (params, opt_state))
+        state_shardings = jax.tree.map(lambda a: a.sharding,
+                                       (params, opt_state))
+        train_step = jax.jit(train_step,
+                             out_shardings=(*state_shardings, None, None))
+
     if args.data:
         from apex_tpu.csrc import TokenLoader
         files = sorted(
@@ -818,6 +837,10 @@ def main():
         except Exception as e:  # noqa: BLE001 - telemetry must not kill a run
             print(f"bubble probe failed (run continues without): {e}")
 
+    # per-step device scalars, fetched after the loop so the record costs
+    # the timed steps no host sync
+    step_log = []
+    first_step_seconds = None
     t0 = time.perf_counter()
     for i in range(start, start + args.steps):
         toks, tgts = next_batch()
@@ -834,15 +857,17 @@ def main():
         else:
             params, opt_state, loss, metrics = train_step(
                 params, opt_state, shard(toks), shard(tgts))
+        step_log.append((loss, metrics["loss_scale"], metrics["found_inf"]))
         if journal is not None:
-            # the journal's float(loss) IS the step's execution barrier
-            # (tunnel discipline); metrics/scaler fetches ride after it
+            # the journal's float(loss) IS the step's execution barrier;
+            # metrics/scaler fetches ride after it
             journal.step_end(step=i, loss=loss, tokens=batch * args.seq,
                              metrics=metrics, scaler=opt_state.scaler)
             forensics.observe(step=i, loss=loss, metrics=metrics)
         if i == start:
             float(loss)  # exclude compile
             t0 = time.perf_counter()
+            first_step_seconds = t0 - t_entry
         if i % 5 == 0 or i == start + args.steps - 1:
             print(f"step {i:5d} loss {float(loss):.4f} "
                   f"scale {float(metrics['loss_scale']):.0f}")
@@ -891,6 +916,18 @@ def main():
         except Exception as e:  # noqa: BLE001 - telemetry must not kill a run
             print(f"ledger append failed: {e}")
     mesh_lib.destroy_model_parallel()
+    return {
+        "losses": [float(l) for l, _, _ in step_log],
+        "loss_scales": [float(s) for _, s, _ in step_log],
+        "found_inf": [bool(f) for _, _, f in step_log],
+        "first_step_seconds": first_step_seconds,
+        "seconds_per_step": dt,
+        "tokens_per_step": batch * args.seq,
+        "train_step": train_step,
+        "params": params,
+        "opt_state": opt_state,
+        "next_batch": lambda: tuple(shard(a) for a in next_batch()),
+    }
 
 
 if __name__ == "__main__":

@@ -22,14 +22,6 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-from apex_tpu.utils.compat import ensure_jax_compat
-
-ensure_jax_compat()  # jax<0.5: shard_map/axis_size API renames
-
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -39,6 +31,7 @@ from apex_tpu.models import GPTConfig, GPTModel
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.parallel import collectives, mesh as mesh_lib
 from apex_tpu.parallel.distributed import allreduce_gradients_by_spec
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -57,6 +50,7 @@ def main():
     p.add_argument("--steps", type=int, default=10)
     p.add_argument("--lr", type=float, default=3e-4)
     args = p.parse_args()
+    enable_compile_cache()
 
     ep = args.ep or len(jax.devices())
     serial = ep == 1

@@ -16,19 +16,17 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.optimizers import FusedSGD
 from apex_tpu.parallel import mesh as mesh_lib
 from apex_tpu.parallel.distributed import DistributedDataParallel
+from apex_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     mesh = mesh_lib.make_virtual_mesh(len(jax.devices()))
 
     def model(params, x):

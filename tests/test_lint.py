@@ -340,26 +340,6 @@ def test_module_citation_rule(tmp_path):
     assert [f.path for f in hits] == ["apex_tpu/nocite.py"]
 
 
-def test_bare_block_until_ready_rule(tmp_path):
-    path = _write(tmp_path, "timing.py", '''
-        import time
-        import jax
-
-        def timed_loop(step, params):
-            t0 = time.perf_counter()
-            params = step(params)
-            jax.block_until_ready(params)
-            return time.perf_counter() - t0
-
-        def warmup_sync(params):
-            # no clock in this scope: a bare sync is fine here
-            jax.block_until_ready(params)
-    ''')
-    rep = run_paths(paths=[str(path)], root=str(tmp_path))
-    hits = [f for f in rep.errors if f.rule == "bare-block-until-ready"]
-    assert len(hits) == 1 and hits[0].line == 8
-
-
 def test_exception_retention_rule(tmp_path):
     path = _write(tmp_path, "oom.py", '''
         def retains(fn):

@@ -19,9 +19,6 @@ from apex_tpu.lint.passes import (
     dtype_drift_pass,
     static_hbm_pass,
 )
-from apex_tpu.utils.compat import ensure_jax_compat
-
-ensure_jax_compat()
 
 
 def _mesh(n=4, name="i"):

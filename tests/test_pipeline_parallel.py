@@ -433,10 +433,7 @@ def test_sharded_head_flops_match_serial():
         tgt = jnp.roll(toks, -1, axis=-1)
 
         def compiled_flops(compiled):
-            ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-                ca = ca[0]
-            return ca["flops"]
+            return compiled.cost_analysis()["flops"]
 
         serial_flops = compiled_flops(
             jax.jit(jax.value_and_grad(serial.loss))

@@ -10,9 +10,6 @@ import jax
 import pytest
 
 from apex_tpu import plan as plan_mod
-from apex_tpu.utils.compat import ensure_jax_compat
-
-ensure_jax_compat()
 
 TINY = plan_mod.ModelSpec("plan-tiny", 128, 64, 4, 4, 32)
 

@@ -29,12 +29,14 @@ def test_peak_spec_table_rows(monkeypatch):
     # device_kind variants land on the right row
     assert peak_spec("tpu TPU v5 lite")["peak_flops"] == 197e12
     assert peak_spec("cpu")["source"] == "table:cpu"
-    assert peak_spec("weird-accelerator")["source"] == "fallback"
+    # a device with no row is an error, never priced as some other chip
+    with pytest.raises(ValueError, match="weird-accelerator"):
+        peak_spec("weird-accelerator")
 
 
 def test_peak_spec_env_overrides(monkeypatch):
-    """The tunnel-calibration knobs: a measured sustained ceiling beats
-    the datasheet, and the record says so via source='env'."""
+    """The env knobs replace the table row's numbers, and the record
+    says so via source='env'."""
     monkeypatch.setenv(mfu_lib.ENV_PEAK_FLOPS, "78e12")
     monkeypatch.setenv(mfu_lib.ENV_PEAK_HBM_GBPS, "900")
     spec = peak_spec("tpu v4")
