@@ -1,0 +1,8 @@
+"""The share of the traced window in which no instruction ran on the
+device, averaged over the cell's devices."""
+
+
+def read(ctx):
+    if ctx["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - ctx["busy_s"] / ctx["window_s"])

@@ -1,0 +1,3 @@
+"""Plain references, one module per model family, found by the name a
+configuration file gives under ``reference``. They import nothing of the
+program under test."""
