@@ -42,6 +42,8 @@ __version__ = "0.1.0"
 
 from apex_tpu import amp  # noqa: F401
 from apex_tpu import optimizers  # noqa: F401
+from apex_tpu.utils.compile_cache import keep_scope_names_in_cache_key
 from apex_tpu.utils.log_util import get_logger  # noqa: F401
 
 logger = get_logger()
+keep_scope_names_in_cache_key()

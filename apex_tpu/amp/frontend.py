@@ -496,19 +496,23 @@ class MixedPrecisionOptimizer:
         gather follows: the new bf16 chunks (cast from the stepped
         masters) ARE the returned model params.
         """
-        grads32, found_inf = state.scaler.unscale(scaled_grads, out_dtype=jnp.float32)
-        if self.zero_axis is not None:
-            from apex_tpu.parallel import collectives as _coll
+        # the step's phases carry names (amp_unscale, optimizer_update,
+        # amp_cast, amp_scale_update): a device trace is cut by them
+        with jax.named_scope("amp_unscale"):
+            grads32, found_inf = state.scaler.unscale(
+                scaled_grads, out_dtype=jnp.float32)
+            if self.zero_axis is not None:
+                from apex_tpu.parallel import collectives as _coll
 
-            # each rank unscaled a DIFFERENT local grad: the skip decision
-            # must agree along the shard axis (the whole two-tier group
-            # when dcn_axis is set) or the chunks diverge
-            found_inf = _coll.pmax(
-                found_inf.astype(jnp.float32),
-                self._zero_group() if self.dcn_axis is not None
-                else self.zero_axis) > 0
-        if found_inf_reducer is not None:
-            found_inf = found_inf_reducer(found_inf)
+                # each rank unscaled a DIFFERENT local grad: the skip
+                # decision must agree along the shard axis (the whole
+                # two-tier group when dcn_axis is set) or the chunks diverge
+                found_inf = _coll.pmax(
+                    found_inf.astype(jnp.float32),
+                    self._zero_group() if self.dcn_axis is not None
+                    else self.zero_axis) > 0
+            if found_inf_reducer is not None:
+                found_inf = found_inf_reducer(found_inf)
 
         if self.zero_axis is not None:
             if self.zero_level >= 3:
@@ -530,21 +534,24 @@ class MixedPrecisionOptimizer:
         def _skip_step(operand):
             return operand
 
-        new_step_params, new_inner = jax.lax.cond(
-            found_inf, _skip_step, _do_step, (step_params, state.inner)
-        )
+        with jax.named_scope("optimizer_update"):
+            new_step_params, new_inner = jax.lax.cond(
+                found_inf, _skip_step, _do_step, (step_params, state.inner)
+            )
 
         if state.master is not None:
             # master -> model copy-out in the model dtypes.
-            new_model = jax.tree.map(
-                lambda mp, p: mp.astype(p.dtype), new_step_params, model_params
-            )
+            with jax.named_scope("amp_cast"):
+                new_model = jax.tree.map(
+                    lambda mp, p: mp.astype(p.dtype), new_step_params,
+                    model_params)
             new_master = new_step_params
         else:
             new_model = new_step_params
             new_master = None
 
-        new_scaler = state.scaler.update(found_inf)
+        with jax.named_scope("amp_scale_update"):
+            new_scaler = state.scaler.update(found_inf)
         metrics = {
             "found_inf": found_inf,
             "loss_scale": new_scaler.loss_scale,
@@ -645,13 +652,14 @@ class MixedPrecisionOptimizer:
                 lambda g, sh: (g if sh else scatter_chunk(g, n, axis)) / n,
                 grads32, sharded)
 
-        updates, stepped_inner = self.inner.update(
-            g_chunks, state.inner, state.master, **update_kwargs)
-        stepped_master = optax.apply_updates(state.master, updates)
         keep = lambda new, old: jax.tree.map(  # noqa: E731
             lambda a, b: jnp.where(found_inf, b, a), new, old)
-        new_master = keep(stepped_master, state.master)
-        new_inner = keep(stepped_inner, state.inner)
+        with jax.named_scope("optimizer_update"):
+            updates, stepped_inner = self.inner.update(
+                g_chunks, state.inner, state.master, **update_kwargs)
+            stepped_master = optax.apply_updates(state.master, updates)
+            new_master = keep(stepped_master, state.master)
+            new_inner = keep(stepped_inner, state.inner)
         if self.reduce_dtype is not None or self.dcn_wire is not None:
             new_residual = dict(
                 new_residual,
@@ -675,11 +683,13 @@ class MixedPrecisionOptimizer:
             def _gather(c, p):
                 return gather_leaf(c, p.shape, p.dtype, axis,
                                    gather_dtype=self.gather_dtype)
-        new_model = jax.tree.map(
-            lambda c, p, sh: c.astype(p.dtype) if sh else _gather(c, p),
-            new_master, model_params, sharded)
+        with jax.named_scope("amp_cast"):
+            new_model = jax.tree.map(
+                lambda c, p, sh: c.astype(p.dtype) if sh else _gather(c, p),
+                new_master, model_params, sharded)
 
-        new_scaler = state.scaler.update(found_inf)
+        with jax.named_scope("amp_scale_update"):
+            new_scaler = state.scaler.update(found_inf)
         metrics = {
             "found_inf": found_inf,
             "loss_scale": new_scaler.loss_scale,
@@ -718,19 +728,22 @@ class MixedPrecisionOptimizer:
         # averaging factor allreduce_gradients applies
         g_chunks = jax.tree.map(lambda g: g / n, grads32)
 
-        updates, stepped_inner = self.inner.update(
-            g_chunks, state.inner, state.master, **update_kwargs)
-        stepped_master = optax.apply_updates(state.master, updates)
         keep = lambda new, old: jax.tree.map(  # noqa: E731
             lambda a, b: jnp.where(found_inf, b, a), new, old)
-        new_master = keep(stepped_master, state.master)
-        new_inner = keep(stepped_inner, state.inner)
+        with jax.named_scope("optimizer_update"):
+            updates, stepped_inner = self.inner.update(
+                g_chunks, state.inner, state.master, **update_kwargs)
+            stepped_master = optax.apply_updates(state.master, updates)
+            new_master = keep(stepped_master, state.master)
+            new_inner = keep(stepped_inner, state.inner)
 
         # master -> model copy-out in the model dtypes, chunk for chunk
-        new_params = jax.tree.map(
-            lambda m, c: m.astype(c.dtype), new_master, param_chunks)
+        with jax.named_scope("amp_cast"):
+            new_params = jax.tree.map(
+                lambda m, c: m.astype(c.dtype), new_master, param_chunks)
 
-        new_scaler = state.scaler.update(found_inf)
+        with jax.named_scope("amp_scale_update"):
+            new_scaler = state.scaler.update(found_inf)
         metrics = {
             "found_inf": found_inf,
             "loss_scale": new_scaler.loss_scale,

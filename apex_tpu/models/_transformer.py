@@ -375,9 +375,10 @@ class TransformerBase:
         # is the mode's memory win), so γβ ride _sp_param by default; head
         # LNs past the sequence gather pass sequence_region=False.
         scale, bias = p["scale"], p["bias"]
-        if sequence_region is None or sequence_region:
-            scale, bias = self._sp_param(scale), self._sp_param(bias)
-        return fused_layer_norm_op(x, scale, bias)
+        with jax.named_scope("layer_norm"):
+            if sequence_region is None or sequence_region:
+                scale, bias = self._sp_param(scale), self._sp_param(bias)
+            return fused_layer_norm_op(x, scale, bias)
 
     def _dense(self, p: Params, x: jax.Array) -> jax.Array:
         return x @ p["kernel"].astype(x.dtype) + p["bias"].astype(x.dtype)
@@ -442,7 +443,10 @@ class TransformerBase:
         # NVTX range the reference's nvmarker.py pushes around each module)
         with jax.named_scope("attention"):
             q, k, v = self._qkv_heads(p["qkv"], h)
-            attn = self._attend(q, k, v, bias)
+            # the kernel apart from the projections and the head
+            # split/merge that share "attention"
+            with jax.named_scope("attention_core"):
+                attn = self._attend(q, k, v, bias)
             return self._attn_out(p, attn)
 
     def _seq_shard_start(self, s_local: int):
@@ -557,6 +561,16 @@ class TransformerBase:
         return_aux: bool = False,
         chunk_meta=None,
     ):
+        """:meth:`_run_layers` under the scope ``layers``: in a device
+        trace, what lies under the step and under none of ``embed``,
+        ``layers``, ``head`` and the optimizer's scopes is the step's
+        glue."""
+        with jax.named_scope("layers"):
+            return self._run_layers(layers, h, attn_bias, dropout_key,
+                                    return_aux, chunk_meta)
+
+    def _run_layers(self, layers, h, attn_bias, dropout_key, return_aux,
+                    chunk_meta):
         """Scan the (stacked) layer params over the hidden state. ``layers``
         may be any contiguous slice of the stack — a pipeline stage's chunk.
         Activation checkpointing is ``jax.checkpoint`` on the scanned body

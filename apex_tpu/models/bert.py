@@ -310,11 +310,16 @@ class BertModel(TransformerBase):
         GLOBAL weight sum — a per-shard mean would mis-weight shards with
         unequal masked-token counts — scaled by axis_size so the harness's
         pmean recovers sum/W exactly."""
-        c = self.cfg
         lm_loss, binary_logits = self.apply(
             params, tokens, attention_mask, tokentype_ids,
             masked_lm_labels, dropout_key,
             layer_chunk_meta=layer_chunk_meta)
+        with jax.named_scope("head"):
+            return self._masked_loss(lm_loss, binary_logits, loss_mask,
+                                     nsp_labels)
+
+    def _masked_loss(self, lm_loss, binary_logits, loss_mask, nsp_labels):
+        c = self.cfg
         w = loss_mask.astype(jnp.float32)
         local = jnp.sum(lm_loss * w)
         if c.context_axis is not None:

@@ -538,5 +538,7 @@ class GPTModel(TransformerBase):
              layer_chunk_meta=None) -> jax.Array:
         """Mean per-token loss — the fwd_step_func contract
         (schedules/common.py:196-255 loss reduction)."""
-        return jnp.mean(self.apply(params, tokens, targets, dropout_key,
-                                   layer_chunk_meta=layer_chunk_meta))
+        per_token = self.apply(params, tokens, targets, dropout_key,
+                               layer_chunk_meta=layer_chunk_meta)
+        with jax.named_scope("head"):
+            return jnp.mean(per_token)

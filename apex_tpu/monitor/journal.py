@@ -396,7 +396,7 @@ class MetricsJournal:
                     ici=self._step_comm["ici"],
                     dcn_bytes=self._step_comm.get("dcn_bytes"),
                     dcn=self._step_comm.get("dcn"))
-                for k in ("compute_s", "comm_s", "host_stall_s",
+                for k in ("compute_s", "comm_s",
                           "compute_frac", "comm_frac", "stall_frac",
                           "overlap_fraction", "ici_s", "dcn_s"):
                     if k in an:

@@ -158,8 +158,8 @@ def mfu_metrics(
 
     Returns the journal-ready fields: ``mfu``, ``hbm_bw_util``,
     ``bound`` (``"compute"`` / ``"memory"`` / ``"balanced"``), achieved
-    TFLOP/s and GB/s, arithmetic intensity vs the roofline ridge, and
-    the peak-spec provenance. ``flops``/``bytes_accessed`` are per
+    TFLOP/s, the roofline's ridge intensity, and the peak-spec
+    provenance. ``flops``/``bytes_accessed`` are per
     executed region (multiply per-step costs by the step count yourself
     when timing multi-step windows).
     """
@@ -170,7 +170,6 @@ def mfu_metrics(
     ach_f = flops / wall_s
     ach_b = bytes_accessed / wall_s
     out["achieved_tflops"] = round(ach_f / 1e12, 4)
-    out["achieved_hbm_gbps"] = round(ach_b / 1e9, 3)
     pf, pb = spec["peak_flops"], spec["peak_hbm_bytes_per_sec"]
     if pf:
         out["mfu"] = round(ach_f / pf, 4)
@@ -188,7 +187,6 @@ def mfu_metrics(
             else:
                 out["bound"] = "compute" if t_compute > t_memory else "memory"
         if bytes_accessed > 0:
-            out["arithmetic_intensity"] = round(flops / bytes_accessed, 2)
             out["ridge_intensity"] = round(pf / pb, 2)
     if tokens and flops:
         out["flops_per_token"] = round(flops / tokens, 1)

@@ -47,6 +47,8 @@ import os
 import time
 from typing import Any, Dict, IO, List, Optional, Sequence, Union
 
+from jax.profiler import TraceAnnotation
+
 from apex_tpu.monitor.journal import (
     JournalRecords,
     MetricsJournal,
@@ -267,12 +269,15 @@ class Tracer:
     def span(self, name: str, *, cat: str = "host", **attrs):
         """Open a nested named span; the record lands at exit with its
         depth and measured duration. Exceptions propagate (the span still
-        records, marked ``"error": true``)."""
+        records, marked ``"error": true``). The span is also a
+        ``jax.profiler.TraceAnnotation`` of the same name and attrs, so a
+        profiler capture holds it on the device events' clock."""
         sp = Span(self, name, cat, dict(attrs))
         sp.depth = len(self._stack)
         self._stack.append(sp)
         try:
-            yield sp
+            with TraceAnnotation(name, **attrs):
+                yield sp
         except BaseException:
             sp.attrs.setdefault("error", True)
             raise
@@ -391,19 +396,19 @@ def scoped(tracer: Optional[Tracer]):
         _GLOBAL, _ENV_CHECKED = prev, prev_checked
 
 
-@contextlib.contextmanager
 def maybe_span(tracer: Optional[Tracer], name: str, *, cat: str = "host",
                **attrs):
-    """``tracer.span(...)`` when armed, a no-op Span otherwise — so hot
-    loops wire one context manager and pay nothing disarmed."""
+    """``tracer.span(...)`` when armed; otherwise the bare
+    ``TraceAnnotation`` with a Span's no-op protocol — so hot loops wire
+    one context manager, a profiler capture holds the span with no tracer
+    armed, and a run with neither pays the annotation's enter and exit
+    (``PERF.md`` has the figure) and syncs nowhere."""
     if tracer is None:
-        yield _NULL_SPAN
-    else:
-        with tracer.span(name, cat=cat, **attrs) as sp:
-            yield sp
+        return _DisarmedSpan(name, **attrs)
+    return tracer.span(name, cat=cat, **attrs)
 
 
-class _NullSpan:
+class _DisarmedSpan(TraceAnnotation):
     __slots__ = ()
 
     def barrier(self, value) -> None:  # noqa: D401 - protocol stub
@@ -411,9 +416,6 @@ class _NullSpan:
 
     def annotate(self, **attrs) -> None:
         pass
-
-
-_NULL_SPAN = _NullSpan()
 
 
 # ---------------------------------------------------------------------------

@@ -804,7 +804,7 @@ class Engine:
                     jnp.asarray(2 * self.ticks + 1, jnp.int32))
                 t_ret = time.perf_counter()
                 sp.barrier(tok)
-            first = int(np.asarray(tok))  # device fetch = TTFT barrier
+                first = int(np.asarray(tok))  # device fetch = TTFT barrier
             t = time.perf_counter()
             req.tokens.append(first)
             req.ttft_s = (t - req.arrival_s
@@ -1302,7 +1302,9 @@ class Engine:
                 *self.decode_args(self.ticks))
             t_ret = time.perf_counter()
             sp.barrier(toks)
-        toks_host = np.asarray(toks)  # device fetch stops the clock
+            # inside the span, so that with no tracer armed (a null
+            # barrier) its annotation still covers the wait for the device
+            toks_host = np.asarray(toks)  # device fetch stops the clock
         t = time.perf_counter()
         tick_prefill = self._tick_prefill_s
         compute_s, barrier_s = t_ret - t0, t - t_ret
@@ -1371,8 +1373,8 @@ class Engine:
                 tables, lengths, xs, act, caps)
             t_ret = time.perf_counter()
             sp.barrier(ys)
-        xs_h = np.asarray(xs)
-        ys_h = np.asarray(ys)  # device fetch stops the clock
+            xs_h = np.asarray(xs)
+            ys_h = np.asarray(ys)  # device fetch stops the clock
         t = time.perf_counter()
         tick_prefill = self._tick_prefill_s
         compute_s, barrier_s = t_ret - t0, t - t_ret
@@ -1447,27 +1449,37 @@ class Engine:
 
         # the flight recorder's crash/stall dump carries the in-flight
         # request table while the loop runs (cleared on the way out)
+        from apex_tpu.monitor import tracing as tracing_mod
+
         flight_mod.set_inflight_provider(self._inflight_table)
         try:
             while not self.batcher.idle:
                 if max_ticks is not None and self.ticks >= max_ticks:
                     break
-                self._tick_prefill_s = 0.0
-                self._admit(journal)
-                # a 1-token request is complete straight out of prefill
-                self._retire_finished(journal, results,
-                                      time.perf_counter())
-                # one prefill chunk (if any slot is mid-prompt) rides
-                # along with the decode step — the long-prompt interleave
-                self._chunk_tick(journal)
-                if self.config.spec_k:
-                    self._spec_tick(journal)
-                else:
-                    self._decode_tick(journal)
-                self._retire_finished(journal, results,
-                                      time.perf_counter())
-                self.ticks += 1
-                self._slo_tick(journal)
+                # serve.prefill / serve.prefill_chunk / serve.decode /
+                # serve.spec are this span's children: its self time is
+                # the host's scheduling
+                with tracing_mod.maybe_span(
+                        tracing_mod.get_tracer(), "serve.tick",
+                        tick=self.ticks):
+                    self._tick_prefill_s = 0.0
+                    self._admit(journal)
+                    # a 1-token request is complete straight out of
+                    # prefill
+                    self._retire_finished(journal, results,
+                                          time.perf_counter())
+                    # one prefill chunk (if any slot is mid-prompt) rides
+                    # along with the decode step — the long-prompt
+                    # interleave
+                    self._chunk_tick(journal)
+                    if self.config.spec_k:
+                        self._spec_tick(journal)
+                    else:
+                        self._decode_tick(journal)
+                    self._retire_finished(journal, results,
+                                          time.perf_counter())
+                    self.ticks += 1
+                    self._slo_tick(journal)
                 if on_tick is not None:
                     on_tick(self)
         finally:

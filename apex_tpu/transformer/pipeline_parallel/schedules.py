@@ -667,18 +667,21 @@ def pipelined_loss_fn(
             per_loss = head_loss(params, h_loc, t_loc)
             # each stage contributes mean(slice)/S; the identity-backward
             # psum makes the sum the full-batch mean while routing each
-            # stage's head grads through its own slice only.
-            local = jnp.mean(per_loss) / S
+            # stage's head grads through its own slice only. (The mean is
+            # the head's own work: it carries the models' scope.)
+            with jax.named_scope("head"):
+                local = jnp.mean(per_loss) / S
         else:
             per_loss = head_loss(params, h_full, targets)
             # Only the last stage holds real outputs; mask then psum
             # (identity backward, Megatron cotangent convention) so
             # head/embedding grads attribute to their owning stage.
-            local = jnp.where(
-                s_idx == S - 1,
-                jnp.mean(per_loss),
-                jnp.zeros((), per_loss.dtype),
-            )
+            with jax.named_scope("head"):
+                local = jnp.where(
+                    s_idx == S - 1,
+                    jnp.mean(per_loss),
+                    jnp.zeros((), per_loss.dtype),
+                )
         if aux_sum is not None:
             # per-stage masked sums over live units; /M gives the
             # per-microbatch mean, matching the serial run_layers aux

@@ -17,6 +17,26 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+def keep_scope_names_in_cache_key() -> None:
+    """The step names its phases in op metadata (``jax.named_scope``:
+    ``optimizer_update``, ``layers``, ...) and device traces are cut by
+    those names. JAX leaves metadata out of the persistent cache's key by
+    default, so a cache warmed by another build of the same arithmetic
+    hands back an executable that carries that build's names. Keep the
+    metadata in the key, with the checkout's own path cut from the source
+    files so that a second checkout of the same tree still hits. Called
+    when ``apex_tpu`` is imported: any compile may land in a cache the
+    machine set up (``JAX_COMPILATION_CACHE_DIR``)."""
+    import re
+
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if not jax.config.jax_hlo_source_file_canonicalization_regex:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          re.escape(_CHECKOUT + os.sep))
+
+
 def enable_compile_cache() -> str:
     """Returns the cache directory in force. With
     ``JAX_COMPILATION_CACHE_DIR`` set JAX reads it itself and nothing is

@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu import amp, checkpoint
 from apex_tpu.models import GPTConfig, GPTModel
+from apex_tpu.monitor.tracing import maybe_span
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.parallel import collectives, mesh as mesh_lib
 from apex_tpu.parallel.distributed import (
@@ -843,20 +844,18 @@ def main(argv=None):
     first_step_seconds = None
     t0 = time.perf_counter()
     for i in range(start, start + args.steps):
-        toks, tgts = next_batch()
-        if journal is not None:
-            journal.step_start()
-        if tracer is not None:
-            from apex_tpu.monitor.tracing import maybe_span
-
-            tracer.step = i
+        # the profiler's own step view, and the program's span beside it:
+        # on the profiler's clock whether or not the file tracer is armed
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            toks, tgts = next_batch()
+            if journal is not None:
+                journal.step_start()
+            if tracer is not None:
+                tracer.step = i
             with maybe_span(tracer, "step", step=i) as sp:
                 params, opt_state, loss, metrics = train_step(
                     params, opt_state, shard(toks), shard(tgts))
-                sp.barrier(loss)
-        else:
-            params, opt_state, loss, metrics = train_step(
-                params, opt_state, shard(toks), shard(tgts))
+                sp.barrier(loss)  # disarmed: a no-op, so no sync
         step_log.append((loss, metrics["loss_scale"], metrics["found_inf"]))
         if journal is not None:
             # the journal's float(loss) IS the step's execution barrier;

@@ -133,6 +133,51 @@ class TestEngineTracing:
                 "req.decode_tick"} <= names, names
         assert all(r.get("request") for r in kids)
 
+    def test_tick_span_is_the_parent_of_the_phase_spans(self, served):
+        """One ``serve.tick`` per tick; prefill and decode are its
+        children, so its self time is the host's scheduling."""
+        phases = ("serve.prefill", "serve.prefill_chunk", "serve.decode",
+                  "serve.spec")
+        spans = [r for r in served["tr_v"].records
+                 if r.get("kind") == "span"
+                 and r["name"] in phases + ("serve.tick",)]
+        ticks = [r for r in spans if r["name"] == "serve.tick"]
+        assert len(ticks) == served["eng_v"].ticks > 0
+        assert [t["tick"] for t in ticks] == list(range(len(ticks)))
+        assert all(t["depth"] == 0 and t["cat"] == "host" for t in ticks)
+        # records land at exit: a tick's children come just before it
+        kids, seen = [], {p: 0 for p in phases}
+        for r in spans:
+            if r["name"] != "serve.tick":
+                assert r["depth"] == 1, r
+                kids.append(r)
+                seen[r["name"]] += 1
+                continue
+            assert sum(k["dur_s"] for k in kids) <= r["dur_s"]
+            assert all(k["ts"] >= r["ts"] - 1e-3 for k in kids)
+            kids = []
+        assert not kids, "a phase span outside every tick"
+        assert seen["serve.prefill"] == 3 and seen["serve.decode"] > 0
+
+    def test_tick_spans_reach_a_capture_with_no_tracer_armed(self, served):
+        from chipbench import harness
+
+        model, params = served["eng_d"].model, served["eng_d"].params
+        eng = Engine(model, params, ServeConfig(**SCFG))
+        with tracing.scoped(None), harness.Capture() as capture:
+            eng.run(make_requests())
+        host = [e for e in capture.events
+                if not e["plane"].startswith("/device:")]
+        ticks = [e for e in host if e["name"] == "serve.tick"]
+        decodes = [e for e in host if e["name"] == "serve.decode"]
+        assert len(ticks) == eng.ticks
+        assert sorted(e["stats"]["tick"] for e in ticks) == list(
+            range(eng.ticks))
+        assert decodes and all(any(
+            t["start"] <= k["start"]
+            and k["start"] + k["dur"] <= t["start"] + t["dur"]
+            for t in ticks) for k in decodes)
+
     def test_deterministic_sampling_and_histogram(self, served):
         roots = [r for r in served["tr_s"].records
                  if r.get("name") == "serve.request"]

@@ -1,0 +1,214 @@
+"""The step names its own phases: the scopes of ``CHANGES.md``'s table
+(PR 26) are a contract between the program and ``chipbench/metrics/``.
+
+On the CPU, at a tiny size, for the GPT step ``pretrain_gpt.main`` builds
+(O2, FusedAdam, microbatch ring) and the BERT + LAMB step the benchmark's
+adapter composes: every scope names instructions where its phase runs, every
+``chipbench/metrics/train.*.json`` that reads scopes finds something to read
+(a rename would end a traced chip run with exit code 4, after the chip time
+is spent), and the scopes change nothing but metadata in the compiled step.
+"""
+
+import contextlib
+import glob
+import os
+import re
+import sys
+
+import jax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "examples", "gpt"))
+
+from chipbench import manifest, trace_reduce  # noqa: E402
+from chipbench.readers import phase_time  # noqa: E402
+
+#: scopes of the model: forward, recompute (under ``layers``) and backward
+MODEL_SCOPES = ("layers", "attention_core", "layer_norm", "head")
+#: scopes of the update: after the gradient, so under no ``jvp(``
+UPDATE_SCOPES = ("amp_unscale", "optimizer_update", "amp_cast",
+                 "amp_scale_update")
+PROGRAMS = ("gpt", "bert")
+METRICS = sorted(
+    os.path.basename(f)[:-len(".json")]
+    for f in glob.glob(os.path.join(ROOT, "chipbench", "metrics", "*.json"))
+    if manifest.load_json(f)["reader"] == "phase_time")
+
+
+def token(scope: str) -> str:
+    """A scope as ``op_name`` carries it: ``/layers/`` inside a scanned
+    body, ``jvp(layers)`` where the gradient was taken around it."""
+    return rf"[/(]{scope}[/)]"
+
+
+def _gpt_text() -> str:
+    import pretrain_gpt
+
+    run = pretrain_gpt.main(
+        "--hidden 64 --layers 2 --heads 4 --seq 32 --vocab 256 "
+        "--micro-batch 1 --num-microbatches 2 --opt-level O2 "
+        "--steps 1".split())
+    toks, tgts = run["next_batch"]()
+    return run["train_step"].lower(
+        run["params"], run["opt_state"], toks, tgts).compile().as_text()
+
+
+def _bert_text() -> str:
+    import numpy as np
+
+    from apex_tpu import amp
+    from chipbench.programs import bert_lamb
+    from chipbench.references import train as ref_train
+    from chipbench.tests import tiny
+
+    cell = manifest.cell(tiny.tiny_bench(ROOT),
+                         "bert_tiny.pretrain_bert_tiny", ROOT)
+    cfg, mix = cell["config"], cell["mix"]
+    model, policy, mp_opt, step = bert_lamb.build(cfg, mix)
+
+    def state(key):
+        params = amp.cast_params(model.init(key), policy)
+        return params, mp_opt.init(params)
+
+    batch = ref_train.family(cfg["reference"]).make_batch(
+        cfg, mix, np.random.default_rng(0), mix["batch"])
+    return step.lower(
+        *jax.eval_shape(state, jax.random.PRNGKey(0)),
+        *(batch[k] for k in bert_lamb.Program.FEED)).compile().as_text()
+
+
+BUILD = {"gpt": _gpt_text, "bert": _bert_text}
+
+
+@pytest.fixture(scope="module")
+def texts(tmp_path_factory):
+    """Each program's compiled text as it is, and built again with
+    ``jax.named_scope`` a no-op."""
+    mp = pytest.MonkeyPatch()
+    # with the variable set the trainer's cache helper configures nothing,
+    # so the rest of the suite keeps running without a persistent cache
+    mp.setenv("JAX_COMPILATION_CACHE_DIR",
+              str(tmp_path_factory.mktemp("cache")))
+    try:
+        named = {p: BUILD[p]() for p in PROGRAMS}
+        mp.setattr(jax, "named_scope",
+                   lambda name: contextlib.nullcontext())
+        bare = {p: BUILD[p]() for p in PROGRAMS}
+    finally:
+        mp.undo()
+    return {"named": named, "bare": bare}
+
+
+@pytest.fixture(scope="module")
+def scopes(texts):
+    return {p: list(trace_reduce.hlo_scopes(t).values())
+            for p, t in texts["named"].items()}
+
+
+def _some(scopes, *patterns, none=()):
+    return [s for s in scopes
+            if all(re.search(p, s) for p in patterns)
+            and not any(re.search(p, s) for p in none)]
+
+
+@pytest.mark.parametrize("scope", MODEL_SCOPES)
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_model_scope_names_forward_backward_and_recompute(
+        scopes, program, scope):
+    mine = scopes[program]
+    assert _some(mine, token(scope), r"jvp\(", none=(r"transpose\(",)), \
+        f"{scope}: nothing in the forward pass"
+    assert _some(mine, token(scope), r"transpose\(",
+                 none=("rematted_computation",)), \
+        f"{scope}: nothing in the backward pass"
+    if scope != "head":      # the head is outside the checkpointed stack
+        assert _some(mine, token(scope), token("layers"),
+                     "/rematted_computation/"), \
+            f"{scope}: nothing in the recompute"
+
+
+@pytest.mark.parametrize("scope", UPDATE_SCOPES)
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_update_scope_names_instructions_outside_the_gradient(
+        scopes, program, scope):
+    mine = _some(scopes[program], token(scope))
+    assert mine, f"{scope}: names no instruction"
+    assert not _some(mine, r"jvp\("), f"{scope}: inside the gradient"
+
+
+def _reader_ctx(scopes):
+    ops = [{"scope": s, "dur": 2e-3} for s in scopes]
+    return {"ops": {"/device:TPU:0": ops},
+            "runs": {"/device:TPU:0": [(0.0, 1.0), (1.0, 2.0)]}}
+
+
+@pytest.mark.parametrize("metric", METRICS)
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_metric_file_finds_something_to_read(scopes, program, metric):
+    params = manifest.metric_file(ROOT, ["chipbench"], metric)["params"]
+    ctx = _reader_ctx(scopes[program])
+    assert trace_reduce.matching(ctx["ops"]["/device:TPU:0"],
+                                 params["since"]), \
+        "the scope that tells a program with names from one without"
+    got = phase_time.read(ctx, **params)
+    assert got is not None and got > 0, (metric, params)
+
+
+def test_the_metrics_that_read_scopes():
+    """Seven: ``amp_cast`` has no metric of its own, since the compiler
+    fuses the cast into the update (``PERF.md``, Findings, PR 26) and
+    ``train.optimizer_update_ms`` reads both scopes."""
+    assert METRICS == [
+        "train.amp_unscale_ms", "train.attention_proj_ms",
+        "train.layer_norm_ms", "train.lm_head_ms",
+        "train.optimizer_update_ms", "train.recompute_ms",
+        "train.unattributed_ms"]
+    update = manifest.metric_file(ROOT, ["chipbench"],
+                                  "train.optimizer_update_ms")
+    for scope in ("optimizer_update", "amp_cast"):
+        assert re.search(update["params"]["include"], f"jit(f)/{scope}/mul")
+
+
+@pytest.mark.parametrize("case,scopes_,want", [
+    ("found", ["jit(f)/layers/x", "jit(f)/optimizer_update/add"], 1.0),
+    # a program built before the scopes: the overlaid parent of PR 26
+    ("no name at all", ["jit(f)/jvp()/while/body/mul", ""], 0.0),
+    # the names are there and this one is not: renamed, so nothing
+    ("renamed", ["jit(f)/layers/x", "jit(f)/update/add"], None),
+])
+def test_phase_time_reader(case, scopes_, want):
+    got = phase_time.read(_reader_ctx(scopes_),
+                          include=token("optimizer_update"),
+                          since=token("layers"))
+    assert got == want, case
+
+
+def _instructions(text: str) -> list:
+    """The compiled text with nothing but what executes: metadata cut from
+    every instruction, and the tables of files, functions and stack frames
+    that head the module left out."""
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    return [line for line in text.splitlines()
+            if not re.match(r"^\d+ ", line)
+            and not line.startswith(("FileNames", "FunctionNames",
+                                     "FileLocations", "StackFrames"))]
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_scopes_change_nothing_but_metadata(texts, program):
+    named = _instructions(texts["named"][program])
+    bare = _instructions(texts["bare"][program])
+    assert len(named) == len(bare)
+    assert named == bare
+    for scope in MODEL_SCOPES + UPDATE_SCOPES:
+        assert not re.search(token(scope), texts["bare"][program])
+
+
+def test_every_scope_metric_is_in_the_manifest():
+    listed = {m["name"]: m for m in manifest.load(ROOT)["per_layer"]}
+    for metric in METRICS:
+        assert listed[metric]["source"] == "program_span"
+        assert listed[metric]["workloads"] == [
+            "gpt2_345m.pretrain_b8s1024", "bert_large.pretrain_b8s512"]
