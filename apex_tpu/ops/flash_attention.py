@@ -15,7 +15,20 @@ Layout: ``(batch, heads, seq, head_dim)`` — the reference's score layout
 
 Forward saves only O and the per-row logsumexp; backward recomputes scores
 blockwise (the fmha/FlashAttention memory plan) in two passes: one gridded
-over q-blocks for dQ, one over k-blocks for dK/dV.
+over q-blocks for dQ, one over k-blocks for dK/dV (on ``S^T = K Q^T``, so
+neither of its products transposes an operand).
+
+Tiles: the score tile's edges are derived from the shape and the masks
+(:func:`flash_tile_plan`) unless the caller gives ``block_q`` / ``block_k``.
+Causal or windowed attention takes the smallest edge whose computed tiles
+the resident kernels can unroll (512 at 1024 tokens: 3 of the square's 4
+tiles); tiles whose every score is masked are never computed, and only tiles
+the diagonal or the window's edge crosses pay mask arithmetic. Where the
+tile bounds are known at trace time (no ring ``offsets``, no
+contiguous-segment bounds) the kernels walk them with static bounds, one
+branch per outer block, so the scheduler overlaps one tile's softmax with
+the next tile's products; traced bounds keep a dynamic ``fori_loop``. Read
+on the chip: PERF.md Findings, PR 27.
 
 Masking: ``causal=True`` for the upper-triangular variant, and/or an additive
 ``bias`` broadcastable to ``(b, h, sq, sk)`` (the additive-mask path of
@@ -31,10 +44,11 @@ the padded total^2 — the entire point of the reference's packed kernel.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -74,11 +88,148 @@ def _supported(sq: int, sk: int, d: int) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# The tile plan: which (q block, k block) score tiles a call computes, which
+# of those need mask arithmetic, and whether the walk over them is known when
+# the kernel is traced.
+# ---------------------------------------------------------------------------
+
+# Tile edges the derived plan tries, smallest first, for shapes whose masks
+# leave whole tiles empty (causal, window): the first whose computed tiles
+# unroll in at most _MAX_STATIC_TILES. Unmasked shapes have nothing to skip
+# and take the largest. Read on the chip, PERF.md Findings, PR 27.
+_DERIVED_EDGES = (512, 1024)
+# The most tiles one kernel unrolls: past it the program text (and the
+# Mosaic compile) grows with the sequence, so the dynamic loop takes over.
+_MAX_STATIC_TILES = 10
+# The most scores one program's unrolled tiles hold: Mosaic gives every
+# unrolled tile its own VMEM temporaries, and one 1024 x 1024 tile is what
+# fits (two refuse to compile: the dK/dV pass at 2048 non-causal).
+_MAX_STATIC_AREA = 1024 * 1024
+
+
+def _tile_kinds(sq, sk, blk_q, blk_k, causal, window):
+    """``(nq, nk)`` array: how the causal diagonal and the window's edges cut
+    each score tile at unsharded positions. 0: every score masked, the tile
+    is never computed (it would have added ``exp(-1e30 - m) = 0`` exactly);
+    1: some masked, the tile pays :func:`_apply_pos_masks`; 2: none."""
+    q0 = np.arange(sq // blk_q)[:, None] * blk_q
+    k0 = np.arange(sk // blk_k)[None, :] * blk_k
+    q1, k1 = q0 + blk_q - 1, k0 + blk_k - 1
+    skip = np.zeros(np.broadcast_shapes(q0.shape, k0.shape), bool)
+    cut = skip.copy()
+    if causal:  # masked where k_pos > q_pos
+        skip |= k0 > q1
+        cut |= k1 > q0
+    if window is not None:  # masked where q_pos - k_pos >= window
+        skip |= q0 - k1 >= window
+        cut |= q1 - k0 >= window
+        if not causal:  # and where k_pos - q_pos >= window
+            skip |= k0 - q1 >= window
+            cut |= k1 - q0 >= window
+    return np.where(skip, 0, np.where(cut, 1, 2))
+
+
+def _unrolls(kinds, blk_q, blk_k) -> bool:
+    """Whether the resident kernels unroll the walk over these tiles: at
+    most ``_MAX_STATIC_TILES`` in a kernel and ``_MAX_STATIC_AREA`` scores
+    in one program (one q block's tiles, or one k block's)."""
+    live = kinds > 0
+    most = max(live.sum(0).max(), live.sum(1).max())
+    return bool(live.sum() <= _MAX_STATIC_TILES
+                and most * blk_q * blk_k <= _MAX_STATIC_AREA)
+
+
+def _static_rows(sq, sk, blk_q, blk_k, causal, window):
+    """The static walk of the resident kernels, ``(by_q, by_k)``: per outer
+    block (q blocks for the forward and dQ passes, k blocks for dK/dV) the
+    inner blocks it visits, each with whether the tile needs its position
+    mask. ``(None, None)`` where the walk does not unroll."""
+    kinds = _tile_kinds(sq, sk, blk_q, blk_k, causal, window)
+    if not _unrolls(kinds, blk_q, blk_k):
+        return None, None
+    return tuple(tuple(tuple((int(j), bool(row[j] == 1))
+                             for j in np.flatnonzero(row)) for row in kk)
+                 for kk in (kinds, kinds.T))
+
+
+class TilePlan(NamedTuple):
+    """What :func:`flash_tile_plan` derives: the tile edges, whether the
+    resident kernels walk the tiles with bounds known at trace time, and the
+    share of the ``sq x sk`` square's tiles that is computed at all."""
+    blk_q: int
+    blk_k: int
+    static: bool
+    share: float
+
+
+def flash_tile_plan(sq: int, sk: int, causal: bool = False,
+                    window: Optional[int] = None, *,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    has_segments: bool = False,
+                    contiguous_segments: bool = False) -> TilePlan:
+    """The tile plan of one attention call: a pure function of static shapes
+    and of which masks the call carries, so how often the causal skip
+    engages is known when the call is traced.
+
+    Causal or windowed shapes take the smallest edge of ``_DERIVED_EDGES``
+    whose computed tiles unroll within ``_MAX_STATIC_TILES``: at 1024 x 1024
+    causal, edge 512 computes 3 of 4 tiles where one 1024 tile computed the
+    whole square and masked half of it away. Unmasked shapes keep one
+    largest tile. An explicit ``block_q`` / ``block_k`` wins. Segment ids
+    need 128-aligned k blocks. Contiguous-segment bounds are traced values:
+    such a call keeps the dynamic loop and the largest edge, and ``share``
+    counts its position masks only. (So do ring attention's calls, whose
+    shard ``offsets`` are traced: ``transformer/ring.py`` picks their
+    edges.)"""
+    mult_k = _NUM_LANES if has_segments else 8
+    masked = causal or window is not None
+    dynamic = has_segments and contiguous_segments
+
+    def edges(e):
+        return (_pick_block(sq, block_q or e),
+                _pick_block(sk, block_k or e, mult=mult_k))
+
+    def kinds(blk):
+        return _tile_kinds(sq, sk, *blk, causal, window)
+
+    blk = edges(_DERIVED_EDGES[-1])
+    if masked and not dynamic and (block_q is None or block_k is None):
+        blk = next((edges(e) for e in _DERIVED_EDGES
+                    if _unrolls(kinds(edges(e)), *edges(e))), blk)
+    return TilePlan(*blk, not dynamic and _unrolls(kinds(blk), *blk),
+                    np.count_nonzero(kinds(blk)) / kinds(blk).size)
+
+
+def _rows_can_lose_every_key(b_ref, qs_ref, off_ref, window) -> bool:
+    """Whether a query row can have every key masked, so that ``exp`` of its
+    scores needs the guard against ``exp(-inf - -inf)``: only through a
+    bias, segment ids, a window, or a ring shard that lies wholly above the
+    diagonal. Causal alone always leaves key 0."""
+    return (b_ref is not None or qs_ref is not None or off_ref is not None
+            or window is not None)
+
+
+def _walk(rows, oi, row_fn):
+    """Run ``row_fn(i, tiles)`` for the outer block ``oi`` this program
+    holds. ``rows is None``: the dynamic loop, ``row_fn(oi, None)``. Else one
+    branch per outer block, each with its tiles unrolled, so a block's
+    bounds are constants and the scheduler sees all of its tiles at once."""
+    if rows is None:
+        row_fn(oi, None)
+    elif len(rows) == 1:
+        row_fn(0, rows[0])
+    else:
+        for i, tiles in enumerate(rows):
+            pl.when(oi == i)(functools.partial(row_fn, i, tiles))
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
 
-def _apply_pos_masks(s, causal, window, q_base, k_base):
+def _apply_pos_masks(s, causal, window, q_base, k_base, transposed=False):
     """Causal and/or sliding-window masking of a score block, in GLOBAL
     positions (``q_base``/``k_base`` are the block's first row/column
     positions including any ring-attention shard offset, so the window is
@@ -90,17 +241,25 @@ def _apply_pos_masks(s, causal, window, q_base, k_base):
     band ``[p-w+1, p+w-1]`` when not. No reference counterpart — the
     reference's fmha/fused-softmax kernels have no local-attention mode;
     this is the standard long-context pairing for the streamed kernels
-    (O(s·w) score work instead of O(s²))."""
+    (O(s·w) score work instead of O(s²)).
+
+    One vector subtraction gives every score's k index minus its q index
+    within the block (columns minus rows; rows minus columns for the
+    ``transposed`` block ``S^T`` of the dK/dV pass); the block's bases enter
+    as one scalar (a constant where the walk is static), so each mask is a
+    compare against a scalar and a select."""
     if not causal and window is None:
         return s
-    q_pos = q_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = k_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    q_dim, k_dim = (1, 0) if transposed else (0, 1)
+    rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, k_dim)
+           - jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim))
+    off = q_base - k_base  # k_pos - q_pos == rel - off
     if causal:
-        s = jnp.where(k_pos > q_pos, _NEG_INF, s)
+        s = jnp.where(rel > off, _NEG_INF, s)
     if window is not None:
-        s = jnp.where(q_pos - k_pos >= window, _NEG_INF, s)
+        s = jnp.where(rel <= off - window, _NEG_INF, s)
         if not causal:
-            s = jnp.where(k_pos - q_pos >= window, _NEG_INF, s)
+            s = jnp.where(rel >= off + window, _NEG_INF, s)
     return s
 
 
@@ -232,13 +391,15 @@ def _seg_mask_if_needed(s, qs_ref, ks_ref, kmm_ref, j_meta, j_slice, blk_k,
     """Apply the segment mask only on blocks that need it — the splash-
     attention full/partial block distinction: an interior block whose q and
     k segment ranges are the same single (non-pad) segment is fully valid,
-    so the mask (the dominant vector cost of the segment path) is skipped
-    via a real branch. ``kmm_ref`` holds per-k-block (min, max) ids in SMEM.
+    so the mask is skipped via a real branch. ``kmm_ref`` holds per-k-block
+    (min, max) ids in SMEM. The STREAMED kernels' only: in the resident
+    kernels the branch cost more than the mask it saves, taken or not (read
+    on the chip at one 512 x 512 tile, PERF.md Findings, PR 27), so they
+    mask every tile; nobody has read it in the streamed ones.
 
     ``j_meta`` indexes the per-block metadata (always the global k-block
-    number); ``j_slice`` indexes into ``ks_ref``, which holds the whole
-    sk in the resident layout (j_slice == j_meta) but only the current
-    block in the streamed layout (j_slice == 0)."""
+    number); ``j_slice`` indexes into ``ks_ref``, which holds only the
+    current block in the streamed layout (j_slice == 0)."""
     kmin = kmm_ref[0, 0, j_meta]
     kmax = kmm_ref[0, 1, j_meta]
     uniform_ok = (qmin == qmax) & (kmin == kmax) & (kmin == qmin)
@@ -252,26 +413,20 @@ def _seg_mask_if_needed(s, qs_ref, ks_ref, kmm_ref, j_meta, j_slice, blk_k,
     )
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, qs_ref, ks_ref, kmm_ref, bnd_ref,
+def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, qs_ref, ks_ref, bnd_ref,
                 off_ref, o_ref, lse_ref, *, scale, causal, blk_q, blk_k,
-                pad_id, window=None, lse_group=1):
+                pad_id, window=None, lse_group=1, rows=None):
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (blk_q, d)
     sk = k_ref.shape[2]
     d = q.shape[-1]
-    qi = pl.program_id(2)
-    nk = sk // blk_k
     # Global-position offsets of this q/k shard (ring attention over the
     # ``context`` axis passes the shard's start positions so causal masking
     # is correct across sequence shards; 0 for unsharded attention).
     q_off = off_ref[0] if off_ref is not None else 0
     k_off = off_ref[1] if off_ref is not None else 0
-    if qs_ref is not None:
-        # this q block's segment-id range, once per program
-        qmin = jnp.min(qs_ref[0])
-        qmax = jnp.max(qs_ref[0])
+    guard = _rows_can_lose_every_key(b_ref, qs_ref, off_ref, window)
 
-    def body(j, carry):
-        acc, m, l = carry
+    def tile(j, carry, q_base, k_base, masked):
         k = k_ref[0, 0, pl.ds(j * blk_k, blk_k), :].astype(jnp.float32)
         v = v_ref[0, 0, pl.ds(j * blk_k, blk_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(
@@ -280,48 +435,66 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, qs_ref, ks_ref, kmm_ref, bnd_ref,
         if b_ref is not None:
             s = s + b_ref[0, 0, :, pl.ds(j * blk_k, blk_k)].astype(jnp.float32)
         if qs_ref is not None:
-            s = _seg_mask_if_needed(s, qs_ref, ks_ref, kmm_ref, j, j, blk_k,
-                                    pad_id, qmin, qmax)
-        s = _apply_pos_masks(s, causal, window, q_off + qi * blk_q,
-                             k_off + j * blk_k)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # fully-masked rows keep m == -inf: exp(s - m) would be exp(0);
-        # zero their probabilities so l stays 0 and the output stays 0
-        p = jnp.where(m_new <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
+            s = _seg_mask(s, qs_ref[0], ks_ref, j, blk_k, pad_id)
+        if masked:
+            s = _apply_pos_masks(s, causal, window, q_base, k_base)
+        m_tile = jnp.max(s, axis=-1, keepdims=True)
+        m_new = m_tile if carry is None else jnp.maximum(carry[1], m_tile)
+        p = jnp.exp(s - m_new)
+        if guard:
+            # fully-masked rows keep m == -inf: exp(s - m) would be exp(0);
+            # zero their probabilities so l stays 0 and the output stays 0
+            p = jnp.where(m_new <= _NEG_INF / 2, 0.0, p)
+        l_new = jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = jax.lax.dot(p, v, preferred_element_type=jnp.float32)
+        if carry is not None:  # the first tile of a static row has no past
+            acc, m, l = carry
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + l_new
+            acc_new = acc * alpha + acc_new
         return acc_new, m_new, l_new
 
-    acc = jnp.zeros((blk_q, d), jnp.float32)
-    m0 = jnp.full((blk_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((blk_q, 1), jnp.float32)
-    lo = 0
-    if bnd_ref is not None:
-        # contiguous-segment block bounds (precomputed host-side): k blocks
-        # outside [lo, hi) cannot share a segment with this q block — the
-        # packed-varlen FLOP saving (sum len_i^2, not total^2)
-        lo = bnd_ref[0, 0, qi]
-        nk = jnp.minimum(nk, bnd_ref[0, 1, qi])
-    if causal:
-        # skip k-blocks strictly above the diagonal (fully masked): the
-        # triangular-work saving the reference's upper-triang kernel gets
-        # from its tiling (scaled_upper_triang_masked_softmax.h).
-        lim = (q_off - k_off + (qi + 1) * blk_q + blk_k - 1) // blk_k
-        nk = jnp.clip(lim, 0, nk)
-    lo, nk = _window_k_range(lo, nk, qi, blk_q, blk_k, q_off, k_off,
-                             causal, window)
-    acc, m, l = jax.lax.fori_loop(lo, nk, body, (acc, m0, l0))
-    # Fully-masked rows (padding segments, all -inf bias rows) have l == 0.
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
-    # lse rides in the dense (b, h, nq, blk_q) table layout (grouped rows;
-    # see _flash_fwd_stream's note — the (b, h, sq, 1) shape lane-pads
-    # 128x at the custom-call boundary)
-    lse_ref[0, 0, pl.ds(qi % lse_group, 1), :] = jnp.transpose(
-        m + jnp.log(l_safe), (1, 0))
+    def row(qi, tiles):
+        init = (jnp.zeros((blk_q, d), jnp.float32),
+                jnp.full((blk_q, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((blk_q, 1), jnp.float32))
+        if tiles is None:
+            lo, nk = 0, sk // blk_k
+            if bnd_ref is not None:
+                # contiguous-segment block bounds (precomputed host-side): k
+                # blocks outside [lo, hi) cannot share a segment with this q
+                # block — the packed-varlen FLOP saving (sum len_i^2, not
+                # total^2)
+                lo = bnd_ref[0, 0, qi]
+                nk = jnp.minimum(nk, bnd_ref[0, 1, qi])
+            if causal:
+                # skip k-blocks strictly above the diagonal (fully masked):
+                # the triangular-work saving the reference's upper-triang
+                # kernel gets from its tiling
+                # (scaled_upper_triang_masked_softmax.h).
+                lim = (q_off - k_off + (qi + 1) * blk_q + blk_k - 1) // blk_k
+                nk = jnp.clip(lim, 0, nk)
+            lo, nk = _window_k_range(lo, nk, qi, blk_q, blk_k, q_off, k_off,
+                                     causal, window)
+            carry = jax.lax.fori_loop(
+                lo, nk,
+                lambda j, c: tile(j, c, q_off + qi * blk_q,
+                                  k_off + j * blk_k, True), init)
+        else:
+            carry = None
+            for j, masked in tiles:
+                carry = tile(j, carry, qi * blk_q, j * blk_k, masked)
+        acc, m, l = init if carry is None else carry
+        # Fully-masked rows (padding segments, all -inf bias rows) have l == 0.
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
+        # lse rides in the dense (b, h, nq, blk_q) table layout (grouped rows;
+        # see _flash_fwd_stream's note — the (b, h, sq, 1) shape lane-pads
+        # 128x at the custom-call boundary)
+        lse_ref[0, 0, pl.ds(qi % lse_group, 1), :] = jnp.transpose(
+            m + jnp.log(l_safe), (1, 0))
+
+    _walk(rows, pl.program_id(2), row)
 
 
 # ---------------------------------------------------------------------------
@@ -330,27 +503,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, qs_ref, ks_ref, kmm_ref, bnd_ref,
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, b_ref, qs_ref, ks_ref, kmm_ref, bnd_ref, off_ref,
+    q_ref, k_ref, v_ref, b_ref, qs_ref, ks_ref, bnd_ref, off_ref,
     do_ref, lse_ref, delta_ref, dq_ref, db_ref,
     *, scale, causal, blk_q, blk_k, pad_id, b_bcast, h_bcast, dims,
-    window=None, lse_group=1,
+    window=None, lse_group=1, rows=None,
 ):
-    q = q_ref[0, 0].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32) * scale
     do = do_ref[0, 0].astype(jnp.float32)
     sk = k_ref.shape[2]
-    # dims maps logical (b, h, q) grid coordinates to program_id positions —
-    # _flash_bwd orders the grid so dbias revisits are *consecutive*.
-    qi = pl.program_id(dims["q"])
-    # dense (b, h, nq, blk_q) table layout (see _flash_fwd_stream)
-    lse = jnp.transpose(lse_ref[0, 0, pl.ds(qi % lse_group, 1), :], (1, 0))
-    delta = jnp.transpose(delta_ref[0, 0, pl.ds(qi % lse_group, 1), :],
-                          (1, 0))
-    nk = sk // blk_k
     q_off = off_ref[0] if off_ref is not None else 0
     k_off = off_ref[1] if off_ref is not None else 0
-    if qs_ref is not None:
-        qmin = jnp.min(qs_ref[0])
-        qmax = jnp.max(qs_ref[0])
+    guard = _rows_can_lose_every_key(b_ref, qs_ref, off_ref, window)
 
     if db_ref is not None:
         # A bias broadcast over batch/heads maps several grid steps onto the
@@ -375,120 +538,167 @@ def _bwd_dq_kernel(
         else:
             db_ref[0, 0] = jnp.zeros_like(db_ref[0, 0])
 
-    def body(j, dq):
-        k = k_ref[0, 0, pl.ds(j * blk_k, blk_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * blk_k, blk_k), :].astype(jnp.float32)
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if b_ref is not None:
-            s = s + b_ref[0, 0, :, pl.ds(j * blk_k, blk_k)].astype(jnp.float32)
-        if qs_ref is not None:
-            s = _seg_mask_if_needed(s, qs_ref, ks_ref, kmm_ref, j, j, blk_k,
-                                    pad_id, qmin, qmax)
-        s = _apply_pos_masks(s, causal, window, q_off + qi * blk_q,
-                             k_off + j * blk_k)
-        # fully-masked rows carry lse == -inf; exp(s - lse) would be exp(0)
-        p = jnp.where(lse <= _NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        if db_ref is not None:
-            cur = db_ref[0, 0, :, pl.ds(j * blk_k, blk_k)]
-            db_ref[0, 0, :, pl.ds(j * blk_k, blk_k)] = cur + ds
-        return dq + scale * jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+    def row(qi, tiles):
+        # dense (b, h, nq, blk_q) table layout (see _flash_fwd_stream)
+        lse = jnp.transpose(lse_ref[0, 0, pl.ds(qi % lse_group, 1), :],
+                            (1, 0))
+        delta = jnp.transpose(delta_ref[0, 0, pl.ds(qi % lse_group, 1), :],
+                              (1, 0))
 
-    lo = 0
-    if bnd_ref is not None:
-        lo = bnd_ref[0, 0, qi]
-        nk = jnp.minimum(nk, bnd_ref[0, 1, qi])
-    if causal:
-        lim = (q_off - k_off + (qi + 1) * blk_q + blk_k - 1) // blk_k
-        nk = jnp.clip(lim, 0, nk)
-    lo, nk = _window_k_range(lo, nk, qi, blk_q, blk_k, q_off, k_off,
-                             causal, window)
-    dq = jax.lax.fori_loop(lo, nk, body, jnp.zeros_like(q))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+        def tile(j, dq, q_base, k_base, masked):
+            k = k_ref[0, 0, pl.ds(j * blk_k, blk_k), :].astype(jnp.float32)
+            v = v_ref[0, 0, pl.ds(j * blk_k, blk_k), :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if b_ref is not None:
+                s = s + b_ref[0, 0, :, pl.ds(j * blk_k, blk_k)].astype(
+                    jnp.float32)
+            if qs_ref is not None:
+                s = _seg_mask(s, qs_ref[0], ks_ref, j, blk_k, pad_id)
+            if masked:
+                s = _apply_pos_masks(s, causal, window, q_base, k_base)
+            p = jnp.exp(s - lse)
+            if guard:
+                # fully-masked rows carry lse == -inf; exp(s - lse) would be
+                # exp(0)
+                p = jnp.where(lse <= _NEG_INF / 2, 0.0, p)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - delta)
+            if db_ref is not None:
+                cur = db_ref[0, 0, :, pl.ds(j * blk_k, blk_k)]
+                db_ref[0, 0, :, pl.ds(j * blk_k, blk_k)] = cur + ds
+            part = jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+            return part if dq is None else dq + part
+
+        if tiles is None:
+            lo, nk = 0, sk // blk_k
+            if bnd_ref is not None:
+                lo = bnd_ref[0, 0, qi]
+                nk = jnp.minimum(nk, bnd_ref[0, 1, qi])
+            if causal:
+                lim = (q_off - k_off + (qi + 1) * blk_q + blk_k - 1) // blk_k
+                nk = jnp.clip(lim, 0, nk)
+            lo, nk = _window_k_range(lo, nk, qi, blk_q, blk_k, q_off, k_off,
+                                     causal, window)
+            dq = jax.lax.fori_loop(
+                lo, nk,
+                lambda j, c: tile(j, c, q_off + qi * blk_q,
+                                  k_off + j * blk_k, True),
+                jnp.zeros(q.shape, jnp.float32))
+        else:
+            dq = None
+            for j, masked in tiles:
+                dq = tile(j, dq, qi * blk_q, j * blk_k, masked)
+            dq = jnp.zeros(q.shape, jnp.float32) if dq is None else dq
+        # the scores' scale, once for the block and not once a tile
+        dq_ref[0, 0] = (scale * dq).astype(dq_ref.dtype)
+
+    # dims maps logical (b, h, q) grid coordinates to program_id positions —
+    # _flash_bwd orders the grid so dbias revisits are *consecutive*.
+    _walk(rows, pl.program_id(dims["q"]), row)
 
 
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, b_ref, qs_ref, ks_ref, qmm_ref, kmm_ref, bnd_ref,
+    q_ref, k_ref, v_ref, b_ref, ks_ref, qs_ref, bnd_ref,
     off_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, scale, causal, blk_q, blk_k, pad_id, window=None,
+    *, scale, causal, blk_q, blk_k, pad_id, window=None, rows=None,
 ):
+    """dK and dV of one k block, in the TRANSPOSED domain: the scores are
+    computed as ``S^T = K Q^T`` (k along rows), so ``dV = P^T dO`` and
+    ``dK = dS^T Q`` are plain products with no transposed operand, and lse
+    and delta are read as the rows the dense tables store. (As ``P`` and
+    ``dS`` with q along rows, both products contract their left operand's
+    rows: two ``(blk_q, blk_k)`` transposes a tile.) Segment ids arrive the
+    other way round for it: k ids lane-replicated ``(blk_k, 128)``, q ids
+    sublane-replicated ``(8, sq)``."""
     k = k_ref[0, 0].astype(jnp.float32)  # (blk_k, d)
     v = v_ref[0, 0].astype(jnp.float32)
     sq = q_ref.shape[2]
-    ki = pl.program_id(2)
-    nq = sq // blk_q
     q_off = off_ref[0] if off_ref is not None else 0
     k_off = off_ref[1] if off_ref is not None else 0
-    if qs_ref is not None:
-        # this k block's segment-id range, once per program (SMEM metadata)
-        kmin = kmm_ref[0, 0, ki]
-        kmax = kmm_ref[0, 1, ki]
+    guard = _rows_can_lose_every_key(b_ref, qs_ref, off_ref, window)
 
-    def seg_mask_dkv(s, i):
-        q_ids = jnp.tile(qs_ref[0, pl.ds(i * blk_q, blk_q), :],
-                         (1, blk_k // _NUM_LANES))
-        k_ids = ks_ref[0, 0:1, pl.ds(ki * blk_k, blk_k)]
-        valid = q_ids == k_ids
-        if pad_id is not None:
-            valid = valid & (k_ids != pad_id)
-        return jnp.where(valid, s, _NEG_INF)
-
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, 0, pl.ds(i * blk_q, blk_q), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.ds(i * blk_q, blk_q), :].astype(jnp.float32)
-        # dense (b, h, nq, blk_q) tables, full-resident here (sq·4 bytes —
-        # 64x less VMEM than the lane-padded (sq, 1) windows they replace)
-        lse = jnp.transpose(lse_ref[0, 0, pl.ds(i, 1), :], (1, 0))
-        delta = jnp.transpose(delta_ref[0, 0, pl.ds(i, 1), :], (1, 0))
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (blk_q, blk_k)
-        if b_ref is not None:
-            s = s + b_ref[0, 0, pl.ds(i * blk_q, blk_q), :].astype(jnp.float32)
-        if qs_ref is not None:
-            qmin = qmm_ref[0, 0, i]
-            qmax = qmm_ref[0, 1, i]
-            uniform_ok = (qmin == qmax) & (kmin == kmax) & (kmin == qmin)
+    def row(ki, tiles):
+        def seg_mask_dkv(st, i):
+            # whole lane tiles where the q block has them (a plain vector
+            # compare), else one column broadcast over the lanes
+            k_ids = (jnp.tile(ks_ref[0], (1, blk_q // _NUM_LANES))
+                     if blk_q % _NUM_LANES == 0 else ks_ref[0][:, :1])
+            q_ids = qs_ref[0, 0:1, pl.ds(i * blk_q, blk_q)]
+            valid = k_ids == q_ids
             if pad_id is not None:
-                uniform_ok = uniform_ok & (qmin != pad_id)
-            s = jax.lax.cond(uniform_ok, lambda s: s,
-                             lambda s: seg_mask_dkv(s, i), s)
-        s = _apply_pos_masks(s, causal, window, q_off + i * blk_q,
-                             k_off + ki * blk_k)
-        # fully-masked rows carry lse == -inf; exp(s - lse) would be exp(0)
-        p = jnp.where(lse <= _NEG_INF / 2, 0.0, jnp.exp(s - lse))  # (blk_q, blk_k)
-        dv_new = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        dk_new = dk + scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return dk_new, dv_new
+                valid = valid & (k_ids != pad_id)
+            return jnp.where(valid, st, _NEG_INF)
 
-    dk0 = jnp.zeros_like(k)
-    dv0 = jnp.zeros_like(v)
-    # Under causal masking, q-blocks entirely left of this k-block's diagonal
-    # contribute nothing — start at the first intersecting block.
-    start = jnp.clip((k_off - q_off + ki * blk_k) // blk_q, 0, nq) if causal else 0
-    if bnd_ref is not None:
-        # contiguous-segment bounds over q blocks for this k block
-        start = jnp.maximum(start, bnd_ref[0, 0, ki])
-        nq = jnp.minimum(nq, bnd_ref[0, 1, ki])
-    start, nq = _window_q_range(start, nq, ki, blk_q, blk_k, q_off, k_off,
-                                causal, window)
-    dk, dv = jax.lax.fori_loop(start, nq, body, (dk0, dv0))
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+        def tile(i, carry, q_base, k_base, masked):
+            # on q the scale rides into both of its products: the scores,
+            # and dK = dS^T (scale q)
+            q = q_ref[0, 0, pl.ds(i * blk_q, blk_q), :].astype(
+                jnp.float32) * scale
+            do = do_ref[0, 0, pl.ds(i * blk_q, blk_q), :].astype(jnp.float32)
+            # dense (b, h, nq, blk_q) tables, full-resident here (sq·4 bytes
+            # — 64x less VMEM than the lane-padded (sq, 1) windows they
+            # replace); a q block's lse is a row, as S^T wants it
+            lse = lse_ref[0, 0, pl.ds(i, 1), :]  # (1, blk_q)
+            delta = delta_ref[0, 0, pl.ds(i, 1), :]
+            st = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (blk_k, blk_q)
+            if b_ref is not None:
+                st = st + jnp.transpose(
+                    b_ref[0, 0, pl.ds(i * blk_q, blk_q), :].astype(
+                        jnp.float32), (1, 0))
+            if qs_ref is not None:
+                st = seg_mask_dkv(st, i)
+            if masked:
+                st = _apply_pos_masks(st, causal, window, q_base, k_base,
+                                      transposed=True)
+            pt = jnp.exp(st - lse)  # (blk_k, blk_q)
+            if guard:
+                # fully-masked rows carry lse == -inf; exp(s - lse) would be
+                # exp(0)
+                pt = jnp.where(lse <= _NEG_INF / 2, 0.0, pt)
+            dv_part = jax.lax.dot(pt, do, preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(
+                v, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dst = pt * (dpt - delta)
+            dk_part = jax.lax.dot(dst, q, preferred_element_type=jnp.float32)
+            if carry is None:  # the first tile of a static walk
+                return dk_part, dv_part
+            return carry[0] + dk_part, carry[1] + dv_part
+
+        init = (jnp.zeros(k.shape, jnp.float32),
+                jnp.zeros(v.shape, jnp.float32))
+        if tiles is None:
+            nq = sq // blk_q
+            # Under causal masking, q-blocks entirely left of this k-block's
+            # diagonal contribute nothing — start at the first intersecting
+            # block.
+            start = (jnp.clip((k_off - q_off + ki * blk_k) // blk_q, 0, nq)
+                     if causal else 0)
+            if bnd_ref is not None:
+                # contiguous-segment bounds over q blocks for this k block
+                start = jnp.maximum(start, bnd_ref[0, 0, ki])
+                nq = jnp.minimum(nq, bnd_ref[0, 1, ki])
+            start, nq = _window_q_range(start, nq, ki, blk_q, blk_k, q_off,
+                                        k_off, causal, window)
+            carry = jax.lax.fori_loop(
+                start, nq,
+                lambda i, c: tile(i, c, q_off + i * blk_q,
+                                  k_off + ki * blk_k, True), init)
+        else:
+            carry = None
+            for i, masked in tiles:
+                carry = tile(i, carry, i * blk_q, ki * blk_k, masked)
+        dk, dv = init if carry is None else carry
+        dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+    _walk(rows, pl.program_id(2), row)
 
 
 # ---------------------------------------------------------------------------
@@ -856,20 +1066,20 @@ def _flash_fwd(q, k, v, bias, offsets, q_seg=None, kv_seg=None, *,
         args.append(bias)
     if q_seg is not None:
         qs, ks = _seg_layouts(q_seg, kv_seg)
-        bounds_q, _, _, kmm = _seg_metadata(q_seg, kv_seg, blk_q, blk_k,
-                                            pad_id)
         in_specs += _seg_specs(blk_q, sk)
         args += [qs, ks]
-        in_specs.append(_smem_pair_spec(sk // blk_k))
-        args.append(kmm)
         if contiguous:
             in_specs.append(_smem_pair_spec(sq // blk_q))
-            args.append(bounds_q)
+            args.append(_seg_metadata(q_seg, kv_seg, blk_q, blk_k,
+                                      pad_id)[0])
     if offsets is not None:
         in_specs.append(_offsets_spec())
         args.append(offsets)
     has_bias, has_off = bias is not None, offsets is not None
     has_seg, has_bnd = q_seg is not None, q_seg is not None and contiguous
+    # traced bounds (ring offsets, contiguous-segment ranges): dynamic loop
+    rows = None if has_off or has_bnd else _static_rows(
+        sq, sk, blk_q, blk_k, causal, window)[0]
 
     def kern(*refs):
         refs = list(refs)
@@ -879,16 +1089,15 @@ def _flash_fwd(q, k, v, bias, offsets, q_seg=None, kv_seg=None, *,
         i += has_bias
         qsr = refs[i] if has_seg else None
         ksr = refs[i + 1] if has_seg else None
-        kmmr = refs[i + 2] if has_seg else None
-        i += 3 * has_seg
+        i += 2 * has_seg
         bndr = refs[i] if has_bnd else None
         i += has_bnd
         offr = refs[i] if has_off else None
         i += has_off
         orf, lr = refs[i], refs[i + 1]
-        _fwd_kernel(qr, kr, vr, br, qsr, ksr, kmmr, bndr, offr, orf, lr,
+        _fwd_kernel(qr, kr, vr, br, qsr, ksr, bndr, offr, orf, lr,
                     scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
-                    pad_id=pad_id, window=window, lse_group=lse_g)
+                    pad_id=pad_id, window=window, lse_group=lse_g, rows=rows)
 
     o, lse = pl.pallas_call(
         kern,
@@ -1217,7 +1426,8 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
     has_bnd = has_seg and contiguous
     if has_seg:
         qs_l, ks_l = _seg_layouts(q_seg, kv_seg)
-        bounds_q, bounds_k, qmm, kmm = _seg_metadata(
+    if has_bnd:
+        bounds_q, bounds_k, _, _ = _seg_metadata(
             q_seg, kv_seg, blk_q, blk_k, pad_id)
 
     # dQ pass: grid over (b, h, q-blocks), reordered so dbias accumulation
@@ -1259,8 +1469,6 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
     if has_seg:
         in_specs += _seg_specs(blk_q, sk, reorder=reorder)
         args += [qs_l, ks_l]
-        in_specs.append(_smem_pair_spec(sk // blk_k, reorder=reorder))
-        args.append(kmm)
         if has_bnd:
             in_specs.append(_smem_pair_spec(sq // blk_q, reorder=reorder))
             args.append(bounds_q)
@@ -1270,6 +1478,9 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
     in_specs += [qspec, lblk, lblk]
     args += [do, lse, delta]
     has_bias, has_off = bias is not None, offsets is not None
+    # traced bounds (ring offsets, contiguous-segment ranges): dynamic loop
+    rows_q, rows_k = (None, None) if has_off or has_bnd else _static_rows(
+        sq, sk, blk_q, blk_k, causal, window)
 
     def dq_kern(*refs):
         refs = list(refs)
@@ -1279,19 +1490,19 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
         i += has_bias
         qsr = refs[i] if has_seg else None
         ksr = refs[i + 1] if has_seg else None
-        kmmr = refs[i + 2] if has_seg else None
-        i += 3 * has_seg
+        i += 2 * has_seg
         bndr = refs[i] if has_bnd else None
         i += has_bnd
         offr = refs[i] if has_off else None
         i += has_off
         dor, lr, dr, dqr = refs[i:i + 4]
         dbr = refs[i + 4] if has_bias else None
-        _bwd_dq_kernel(qr, kr, vr, br, qsr, ksr, kmmr, bndr, offr, dor, lr,
+        _bwd_dq_kernel(qr, kr, vr, br, qsr, ksr, bndr, offr, dor, lr,
                        dr, dqr, dbr,
                        scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
                        pad_id=pad_id, b_bcast=b_bcast, h_bcast=h_bcast,
-                       dims=dims, window=window, lse_group=lse_g)
+                       dims=dims, window=window, lse_group=lse_g,
+                       rows=rows_q)
 
     out_specs = [qspec]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
@@ -1331,20 +1542,18 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
         in_specs2.append(bspec2)
         args2.append(bias)
     if has_seg:
-        # this pass streams q: q ids arrive FULL, bounds indexed by k block
+        # the pass works on S^T: k ids lane-replicated per k block, q ids
+        # sublane-replicated and whole; bounds indexed by k block
+        ks_t, qs_t = _seg_layouts(kv_seg, q_seg)
         in_specs2 += [
-            pl.BlockSpec((1, sq, _NUM_LANES),
+            pl.BlockSpec((1, blk_k, _NUM_LANES),
+                         lambda bi, hi, ki: (bi, ki, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, _NUM_SUBLANES, sq),
                          lambda bi, hi, ki: (bi, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, _NUM_SUBLANES, sk),
-                         lambda bi, hi, ki: (bi, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 2, sq // blk_q), lambda bi, hi, ki: (bi, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 2, sk // blk_k), lambda bi, hi, ki: (bi, 0, 0),
-                         memory_space=pltpu.SMEM),
         ]
-        args2 += [qs_l, ks_l, qmm, kmm]
+        args2 += [ks_t, qs_t]
         if has_bnd:
             in_specs2.append(pl.BlockSpec(
                 (1, 2, sk // blk_k), lambda bi, hi, ki: (bi, 0, 0),
@@ -1362,20 +1571,18 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
         i = 3
         br = refs[i] if has_bias else None
         i += has_bias
-        qsr = refs[i] if has_seg else None
-        ksr = refs[i + 1] if has_seg else None
-        qmmr = refs[i + 2] if has_seg else None
-        kmmr = refs[i + 3] if has_seg else None
-        i += 4 * has_seg
+        ksr = refs[i] if has_seg else None
+        qsr = refs[i + 1] if has_seg else None
+        i += 2 * has_seg
         bndr = refs[i] if has_bnd else None
         i += has_bnd
         offr = refs[i] if has_off else None
         i += has_off
         dor, lr, dr, dkr, dvr = refs[i:i + 5]
-        _bwd_dkv_kernel(qr, kr, vr, br, qsr, ksr, qmmr, kmmr, bndr, offr,
+        _bwd_dkv_kernel(qr, kr, vr, br, ksr, qsr, bndr, offr,
                         dor, lr, dr, dkr, dvr,
                         scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
-                        pad_id=pad_id, window=window)
+                        pad_id=pad_id, window=window, rows=rows_k)
 
     dk, dv = pl.pallas_call(
         dkv_kern,
@@ -1446,9 +1653,10 @@ _WARNED_PACKED_OPT_IN = False
 def _resident_vmem_bytes(sq, sk, d, blk_q, blk_k, itemsize, has_bias,
                          has_seg):
     """Dominant per-program VMEM residency of the resident layout, for the
-    fwd/dQ passes (whole K+V) and the dK/dV pass (whole Q/dO + the
-    lane-replicated q-id tile — the ADVICE r3 medium: residency scales
-    with TOTAL tokens, not max_seqlen, on the packed path).
+    fwd/dQ passes (whole K+V) and the dK/dV pass (whole Q/dO — residency
+    scales with TOTAL tokens, not max_seqlen, on the packed path; its
+    segment ids are one lane-replicated k-id block and the whole
+    sublane-replicated q ids, the pass working on S^T).
 
     VMEM tiles pad the MINOR dim to the 128-lane vreg width: a head_dim
     of 32 occupies 128 lanes — observed live: a d=32, s=8192 resident
@@ -1461,7 +1669,7 @@ def _resident_vmem_bytes(sq, sk, d, blk_q, blk_k, itemsize, has_bias,
     seg_fwd = (blk_q * _NUM_LANES + _NUM_SUBLANES * sk) * 4 if has_seg else 0
     fwd = (2 * sk * d_eff * itemsize
            + (blk_q * sk * 4 if has_bias else 0) + seg_fwd)
-    seg_dkv = (sq * _NUM_LANES + _NUM_SUBLANES * sk) * 4 if has_seg else 0
+    seg_dkv = (blk_k * _NUM_LANES + _NUM_SUBLANES * sq) * 4 if has_seg else 0
     dkv = (3 * sq * d_eff * itemsize  # q, do (+ dq-pass K/V ≈ fwd term)
            + 2 * sq * 4  # lse + delta dense tables
            + (sq * blk_k * 4 if has_bias else 0) + seg_dkv)
@@ -1572,8 +1780,8 @@ def flash_attention(
     causal: bool = False,
     scale: Optional[float] = None,
     window: Optional[int] = None,
-    block_q: int = 1024,
-    block_k: int = 1024,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     impl: str = "auto",
     stream: str = "auto",
 ) -> jax.Array:
@@ -1612,6 +1820,13 @@ def flash_attention(
         no local-attention mode; this is the standard long-context
         pairing for the streamed kernels. Composes with ``causal``,
         ``segment_ids``, ``bias``, and streaming.
+      block_q, block_k: the score tile's edges (each the largest divisor of
+        its sequence at or under the value). Default None: derived from the
+        shape and the masks by :func:`flash_tile_plan` — causal or windowed
+        attention takes the smallest edge whose unmasked tiles the resident
+        kernels can walk with static bounds (512 at 1024 tokens: 3 of the
+        square's 4 tiles are computed, and only the 2 on the diagonal are
+        masked), everything else one tile of up to 1024.
       impl: 'auto' | 'pallas' | 'xla'.
       stream: 'auto' | 'never' | 'always' — streamed kernels move the
         K/V loop into the Pallas grid so VMEM residency is block-bounded
@@ -1636,8 +1851,10 @@ def flash_attention(
             f"sq={sq}, sk={sk}, d={d} is outside the kernel's envelope "
             "(8-aligned sequences, head_dim >= 8)")
     global _WARNED_PACKED_OPT_IN
-    blk_q = _pick_block(sq, block_q)
-    blk_k = _pick_block(sk, block_k)
+    blk_q, blk_k, _, _ = flash_tile_plan(
+        sq, sk, causal, window, block_q=block_q, block_k=block_k,
+        has_segments=segment_ids is not None,
+        contiguous_segments=contiguous_segments)
     if segment_ids is not None:
         q_seg, kv_seg = segment_ids
         if q_seg.shape != (b, sq) or kv_seg.shape != (b, sk):
@@ -1680,7 +1897,6 @@ def flash_attention(
                         "skipping (cost sum(len_i^2) instead of total^2)",
                         stacklevel=2)
         # the lane-replicated kernel layout needs 128-aligned k blocks
-        blk_k = _pick_block(sk, block_k, mult=_NUM_LANES)
         if use == "pallas" and (blk_k % _NUM_LANES or sk % blk_k):
             use = _pallas_unsupported(
                 "flash_attention", impl,

@@ -11,7 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.ops.flash_attention import flash_attention, mha_reference
+from apex_tpu.ops import flash_attention as fa
+from apex_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_tile_plan,
+    mha_reference,
+)
 
 B, H, SQ, D = 2, 4, 128, 32
 
@@ -681,3 +686,159 @@ def test_bias_past_crossover_keeps_resident_kernel(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+# ---------------------------------------------------------------------------
+# The tile plan derived from the shape (PR 27): causal attention at GPT's
+# 1024 tokens is computed as a triangle of tiles, walked with static bounds
+# and masked only where the diagonal or the window's edge crosses a tile.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("case,args,kw,want", [
+    # the GPT cell: 2 x 16 heads of 1024 x 1024 causal
+    ("gpt cell", (1024, 1024, True), {}, dict(static=True, most=0.75)),
+    # the BERT cell: one 512 x 512 tile under segment ids, nothing to skip
+    ("bert cell", (512, 512, False), dict(has_segments=True),
+     dict(blk=(512, 512), static=True, share=1.0)),
+    ("explicit blocks win", (1024, 1024, True),
+     dict(block_q=1024, block_k=1024),
+     dict(blk=(1024, 1024), static=True, share=1.0)),
+    ("one explicit edge, the other derived", (1024, 1024, True),
+     dict(block_q=256), dict(blk=(256, 512), static=True, share=0.75)),
+    ("unmasked keeps one largest tile", (1024, 1024, False), {},
+     dict(blk=(1024, 1024), static=True, share=1.0)),
+    ("window counts the band", (1024, 1024, True, 256), {},
+     dict(blk=(512, 512), static=True, share=0.75)),
+    # traced bounds keep the dynamic loop and the largest edge
+    ("a band both ways", (1024, 1024, False, 200), {},
+     dict(blk=(512, 512), static=True, share=1.0)),
+    ("contiguous segments", (1024, 1024, True),
+     dict(has_segments=True, contiguous_segments=True),
+     dict(blk=(1024, 1024), static=False)),
+    # too many tiles to unroll: the dynamic loop at the largest edge
+    ("long causal", (8192, 8192, True), {},
+     dict(blk=(1024, 1024), static=False, share=36 / 64)),
+    # two unrolled 1024 tiles a program do not fit VMEM
+    ("2048 unmasked", (2048, 2048, False), {},
+     dict(blk=(1024, 1024), static=False, share=1.0)),
+    ("2048 causal", (2048, 2048, True), {},
+     dict(blk=(512, 512), static=True, share=10 / 16)),
+])
+def test_flash_tile_plan(case, args, kw, want):
+    plan = flash_tile_plan(*args, **kw)
+    if "blk" in want:
+        assert (plan.blk_q, plan.blk_k) == want["blk"], plan
+    assert plan.static == want["static"], plan
+    if "share" in want:
+        assert plan.share == pytest.approx(want["share"]), plan
+    if "most" in want:
+        assert plan.share <= want["most"] and plan.blk_q < 1024, plan
+
+
+@pytest.mark.parametrize("sq,sk,blk_q,blk_k,causal,window", [
+    (1024, 1024, 512, 512, True, None),
+    (1024, 1024, 256, 256, True, None),
+    (512, 1024, 128, 256, True, None),
+    (1024, 1024, 256, 256, True, 300),
+    (1024, 1024, 256, 128, False, 200),
+    (1024, 512, 256, 128, True, 129),
+])
+def test_tile_kinds_agree_with_the_dense_mask(sq, sk, blk_q, blk_k, causal,
+                                              window):
+    """A tile is skipped only if every score in it is masked, and runs
+    without mask arithmetic only if none is."""
+    dense = np.asarray(fa._dense_pos_masks(
+        jnp.zeros((sq, sk)), jnp.arange(sq)[:, None], jnp.arange(sk)[None, :],
+        causal, window)) < 0
+    tiles = dense.reshape(sq // blk_q, blk_q, sk // blk_k, blk_k)
+    kinds = fa._tile_kinds(sq, sk, blk_q, blk_k, causal, window)
+    np.testing.assert_array_equal(kinds == 0, tiles.all(axis=(1, 3)))
+    np.testing.assert_array_equal(kinds == 2, ~tiles.any(axis=(1, 3)))
+    by_q, by_k = fa._static_rows(sq, sk, blk_q, blk_k, causal, window)
+    if by_q is not None:
+        walked = {(i, j, m) for i, r in enumerate(by_q) for j, m in r}
+        assert walked == {(i, j, m) for j, r in enumerate(by_k)
+                          for i, m in r}
+        assert walked == {(i, j, kinds[i, j] == 1)
+                          for i, j in zip(*np.nonzero(kinds))}
+
+
+def _cell_qkv(dtype, s=1024, d=64, h=2):
+    ks = jax.random.split(jax.random.PRNGKey(27), 4)
+    q, k, v, w = (jax.random.normal(kk, (1, h, s, d), jnp.float32)
+                  for kk in ks)
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), w
+
+
+def _out_and_grads(fn, q, k, v, w, *extra):
+    """Output and gradients of ``sum(fn(...) * w)`` in q, k, v, extras."""
+    def loss(*a):
+        o = fn(*a)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    (_, o), g = jax.value_and_grad(
+        loss, argnums=tuple(range(3 + len(extra))), has_aux=True)(
+            q, k, v, *extra)
+    return (o,) + g
+
+
+@pytest.mark.parametrize("dtype,tol_o,tol_g", [
+    (jnp.float32, 2e-5, 1e-4), (jnp.bfloat16, 3e-2, 3e-2)])
+def test_derived_plan_matches_reference_at_gpt_shape(dtype, tol_o, tol_g):
+    """Causal s=1024, d=64 through the derived edge (a static triangle of
+    tiles): float32 inputs keep float32 operands and the file's float32
+    tolerances; bf16 inputs feed the MXU bf16 and hold its 3e-2."""
+    q, k, v, w = _cell_qkv(dtype)
+    assert flash_tile_plan(1024, 1024, True).share <= 0.75
+    got = _out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, impl="pallas"),
+        q, k, v, w)
+    ref = _out_and_grads(
+        lambda q, k, v: mha_reference(q, k, v, causal=True), q, k, v, w)
+    assert got[0].dtype == dtype
+    for a, b, tol in zip(got, ref, (tol_o, tol_g, tol_g, tol_g)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol)
+
+
+def test_derived_plan_equals_one_tile_plan():
+    """Skipping a tile whose every score is masked changes nothing: the
+    triangle of 512-tiles gives what one masked 1024-tile gives."""
+    q, k, v, w = _cell_qkv(jnp.float32, h=1)
+    derived = _out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, impl="pallas"),
+        q, k, v, w)
+    one = _out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, impl="pallas",
+                                        block_q=1024, block_k=1024),
+        q, k, v, w)
+    for a, b in zip(derived, one):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["window", "bias"])
+def test_derived_plan_masks_only_where_an_edge_crosses(case):
+    """Causal + window and causal + dense bias through the derived plan:
+    tiles off the diagonal and inside the band run with no mask arithmetic,
+    tiles outside the band are skipped, and the result is the reference's."""
+    q, k, v, w = _cell_qkv(jnp.float32, h=1)
+    if case == "window":
+        kinds = fa._tile_kinds(1024, 1024, 512, 512, True, 300)
+        assert sorted(kinds.ravel()) == [0, 1, 1, 1]  # band crosses (1, 0)
+        kw, extra = dict(causal=True, window=300), ()
+    else:
+        bias = 0.5 * jax.random.normal(jax.random.PRNGKey(3),
+                                       (1, 1, 1024, 1024), jnp.float32)
+        kw, extra = dict(causal=True), (bias,)
+    got = _out_and_grads(
+        lambda q, k, v, *b: flash_attention(q, k, v, *b, impl="pallas",
+                                            **kw), q, k, v, w, *extra)
+    ref = _out_and_grads(
+        lambda q, k, v, *b: mha_reference(q, k, v, *b, **kw),
+        q, k, v, w, *extra)
+    for a, b, tol in zip(got, ref, (2e-5,) + (1e-4,) * 4):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=tol, atol=tol)
