@@ -596,7 +596,14 @@ class TransformerBase:
         When the model's layers emit aux losses (``_aux_init`` not None),
         they accumulate in the scan carry and the caller MUST pass
         ``return_aux=True`` — silently discarding router losses would turn
-        the MoE balancing knobs into no-ops."""
+        the MoE balancing knobs into no-ops. A model with
+        ``aux_per_layer = True`` gets what each layer's ``_layer_aux``
+        returned stacked over the layers instead (counters, which a sum
+        over layers would blur).
+
+        ``h`` is whatever the model's ``_layer_aux`` carries from layer to
+        layer: one stream, or a pytree of them (models/instella.py carries
+        the stream now and as it stood one sub-block ago)."""
         n = jax.tree.leaves(layers)[0].shape[0]
         keys = None if dropout_key is None else jax.random.split(dropout_key, n)
         aux0 = self._aux_init()
@@ -633,12 +640,16 @@ class TransformerBase:
                 h = drive(layers, h)
                 return (h, None) if return_aux else h
 
+        per_layer = getattr(self, "aux_per_layer", False)
+
         def body(carry, xs):
             h, acc = carry
             p, k = xs
             if chunk_meta is not None:
                 p = gather_chunked_tree(p, chunk_meta)
             h, aux = self._layer_aux(p, h, k, attn_bias)
+            if per_layer:
+                return (h, acc), aux
             if acc is not None:
                 acc = jax.tree.map(
                     jnp.add, acc,
@@ -658,12 +669,17 @@ class TransformerBase:
             # adjoints avoid entirely — measured 230 -> 188 ms (PERF_NOTES
             # r5). Same math, same order, same tree; compile time grows
             # O(depth).
-            carry = (h, aux0)
+            carry, each = (h, aux0), []
             for i in range(n):
                 xs = (jax.tree.map(lambda v: v[i], layers),
                       None if keys is None else keys[i])
-                carry, _ = body(carry, xs)
+                carry, y = body(carry, xs)
+                each.append(y)
             h, aux = carry
+            if per_layer:
+                aux = jax.tree.map(lambda *ys: jnp.stack(ys), *each)
             return (h, aux) if return_aux else h
-        (h, aux), _ = lax.scan(body, (h, aux0), (layers, keys))
+        (h, aux), each = lax.scan(body, (h, aux0), (layers, keys))
+        if per_layer:
+            aux = each
         return (h, aux) if return_aux else h

@@ -10,7 +10,8 @@ Design (TPU-first, the GShard/Switch dense-dispatch recipe):
   static under jit.
 - **Capacity**: each expert processes at most C = ceil(top_k · N · cf / E)
   tokens; over-capacity tokens fall through (their combine weight is 0),
-  the standard Switch behavior.
+  the standard Switch behavior. (:class:`DroplessExperts`, at the end of
+  this module, is the layer that drops nothing.)
 - **Expert parallelism**: experts shard over a mesh axis. Inside
   ``shard_map`` with tokens sharded on the *same* axis (the standard MoE
   mapping: the data shards are the expert shards),
@@ -66,6 +67,18 @@ def _pmean_value_local_grad(v: jax.Array, axis: str) -> jax.Array:
 
 class MoEMLP:
     """Drop-in MoE replacement for the transformer FFN block.
+
+    **This router drops tokens.** Each expert takes at most its capacity
+    ``C``; an assignment over it is lost (its combine weight is 0, and
+    ``dropped_fraction`` counts it). That keeps the ``(N, E, C)`` one-hot
+    dispatch dense and static, which is what ``--moe-experts`` and
+    ``examples/moe/`` train with. :class:`DroplessExperts`, below in this
+    module, loses no assignment (sorted dispatch, grouped products over the
+    rows filled) and is what ``models/instella.py`` trains with. The two
+    share no code: this one's experts are biased GeLU MLPs run as two dense
+    ``(E, C, d)`` einsums and its statistics are the softmax router's
+    balance and z losses; that one's are gated SiLU experts run as ragged
+    products over one sorted buffer, and its statistics are counters.
 
     Args:
       hidden_size / ffn_hidden_size: per-expert FFN dims.
@@ -428,3 +441,223 @@ class MoEMLP:
         with _comm("psum", ax, out):
             out = lax.psum(out, ax)
         return out.reshape(shape)
+
+
+# -- routed experts without dropped tokens ------------------------------------
+#
+# ``MoEMLP`` above keeps its shapes static with a capacity per expert and
+# loses what goes over it. The layer below loses nothing: it sorts the
+# assignments by expert, gathers their tokens into one buffer and runs the
+# experts as grouped products over the rows actually filled
+# (``lax.ragged_dot``, which XLA:TPU compiles to its grouped-matmul call and
+# whose time follows the group sizes, not the buffer).
+
+
+def _collect_rows(buf: jax.Array, slots: jax.Array) -> jax.Array:
+    """``(C, d)`` rows to ``(N, d)``: token ``n`` gets the sum of the rows
+    ``slots[n, :]`` names; ``C`` names no row. Gathers only, one choice
+    after another, so one gathered ``(N, d)`` is alive at a time."""
+    c = buf.shape[0]
+    by_choice = slots.T
+
+    def add(j, acc):
+        idx = lax.dynamic_index_in_dim(by_choice, j, keepdims=False)
+        rows = jnp.take(buf, jnp.minimum(idx, c - 1), axis=0)
+        return acc + jnp.where((idx < c)[:, None],
+                               rows.astype(jnp.float32), 0.0)
+
+    acc = lax.fori_loop(
+        0, slots.shape[1], add,
+        jnp.zeros((slots.shape[0], buf.shape[1]), jnp.float32))
+    return acc.astype(buf.dtype)
+
+
+@jax.custom_vjp
+def spread_rows(x: jax.Array, tok: jax.Array, slots: jax.Array) -> jax.Array:
+    """``(N, d)`` to ``(C, d)``: buffer row ``r`` is ``x[tok[r]]``. ``slots``
+    ``(N, k)`` is the same partial permutation read from the other side
+    (the buffer row of each of a token's ``k`` choices, ``C`` for none), and
+    only the backward pass reads it: the transpose of a gather by a
+    permutation is the gather by its inverse, so no scatter-add runs."""
+    return jnp.take(x, tok, axis=0)
+
+
+def _spread_fwd(x, tok, slots):
+    return jnp.take(x, tok, axis=0), (tok, slots)
+
+
+def _spread_bwd(res, g):
+    _, slots = res
+    return _collect_rows(g, slots), None, None
+
+
+spread_rows.defvjp(_spread_fwd, _spread_bwd)
+
+
+@jax.custom_vjp
+def collect_rows(buf: jax.Array, tok: jax.Array, slots: jax.Array) -> jax.Array:
+    """The transpose of :func:`spread_rows`: ``(C, d)`` back to ``(N, d)``,
+    a token's rows summed."""
+    return _collect_rows(buf, slots)
+
+
+def _collect_fwd(buf, tok, slots):
+    return _collect_rows(buf, slots), (tok, slots)
+
+
+def _collect_bwd(res, g):
+    tok, _ = res
+    return jnp.take(g, tok, axis=0), None, None
+
+
+collect_rows.defvjp(_collect_fwd, _collect_bwd)
+
+
+class DroplessExperts:
+    """The routed experts of a DeepSeek-V3-style layer, for a layer that
+    holds a share of them (one rank of an expert-parallel group), with no
+    token dropped.
+
+    The router scores every token against all ``num_experts`` with a sigmoid
+    in float32, chooses the ``top_k`` largest of score + selection bias (the
+    ``noaux_tc`` bias, a buffer: no gradient reaches it), and weights each
+    choice by ``routed_scaling_factor * p_i / sum of the chosen p``
+    (DeepSeek-V3 ``MoEGate``, arXiv:2412.19437 §2.1.2). The layer is told
+    which experts it holds, ``first_held`` on, ``held`` of them, and returns
+    only their terms of the sum: on one chip that partial result is the
+    layer's output, under expert parallelism it is what the exchange would
+    sum. Gated SiLU experts, no biases. Shared experts are the caller's (they
+    see every token and need no routing).
+
+    Shapes are static, so the assignments to held experts go into a buffer
+    of ``BUFFER_FACTOR`` times their number under an even router (never more
+    than the worst case, ``min(top_k, held)`` a token). Every assignment
+    that fits is computed whatever the imbalance between experts, and the
+    products' time follows the rows filled, not the buffer; if more arrive
+    than the buffer holds, ``stats["overflow"]`` counts them and the caller
+    must skip the step (``pretrain_instella`` does, and the driver counts it
+    failed): never a silent loss.
+
+    The selection bias is a held buffer here: nothing moves it. (Moving it
+    as ``noaux_tc`` does in training, 0.001 a step against each expert's
+    load, was tried on the chip and changed nothing that could be measured:
+    a share trained alone at a full learning rate swings thirty times
+    faster, ``PERF.md``, Findings, PR 28.)
+
+    ``apply`` returns ``(out, stats)`` with the counters ``assignments`` (to
+    held experts), ``max_load_over_mean`` (the fullest held expert over the
+    mean) and ``overflow``.
+    """
+
+    #: rows of the buffer over the assignments an even router makes to the
+    #: held experts. On the chip a layer's held experts took up to 1.9
+    #: times that within 56 steps of a seeded state (``PERF.md``, Findings,
+    #: PR 28); four leaves twice that room, at 5% of the step
+    BUFFER_FACTOR = 4
+
+    def __init__(self, hidden_size: int, ffn_hidden_size: int,
+                 num_experts: int, top_k: int, *,
+                 held: Optional[int] = None, first_held: int = 0,
+                 routed_scaling_factor: float = 1.0,
+                 params_dtype: Any = jnp.float32, init_method=None,
+                 bias_std: float = 0.0):
+        held = num_experts if held is None else held
+        if not 0 < top_k <= num_experts:
+            raise ValueError(f"top_k ({top_k}) must be in [1, {num_experts}]")
+        if first_held < 0 or first_held + held > num_experts or held < 1:
+            raise ValueError(
+                f"experts {first_held}..{first_held + held - 1} are not "
+                f"among the {num_experts} the router scores")
+        self.hidden, self.ffn = hidden_size, ffn_hidden_size
+        self.num_experts, self.top_k = num_experts, top_k
+        self.held, self.first_held = held, first_held
+        self.scaling = routed_scaling_factor
+        self.params_dtype = params_dtype
+        self.init_method = init_method or tp.scaled_normal(0.02)
+        self.bias_std = bias_std
+
+    def init(self, key) -> Params:
+        kr, kb, kg, ku, kd = jax.random.split(key, 5)
+        d, f = self.hidden, self.ffn
+
+        def per_expert(k, shape):
+            return jax.vmap(lambda kk: self.init_method(
+                kk, shape, self.params_dtype))(jax.random.split(k, self.held))
+
+        return {
+            "router": {
+                "kernel": self.init_method(kr, (d, self.num_experts),
+                                           self.params_dtype),
+                "bias": (self.bias_std * jax.random.normal(
+                    kb, (self.num_experts,))).astype(self.params_dtype)},
+            "experts": {"gate": per_expert(kg, (d, f)),
+                        "up": per_expert(ku, (d, f)),
+                        "down": per_expert(kd, (f, d))},
+        }
+
+    def buffer_rows(self, n_tokens: int) -> int:
+        worst = n_tokens * min(self.top_k, self.held)
+        want = math.ceil(self.BUFFER_FACTOR * n_tokens * self.top_k
+                         * self.held / self.num_experts)
+        return min(worst, -(-want // 128) * 128)
+
+    def route(self, router: Params, x2d: jax.Array):
+        """``(chosen, weights)``, both ``(N, top_k)``: the experts each
+        token chose, of all ``num_experts``, and their weights."""
+        scores = jax.nn.sigmoid(jnp.matmul(
+            x2d.astype(jnp.float32), router["kernel"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        bias = lax.stop_gradient(router["bias"].astype(jnp.float32))
+        _, chosen = lax.top_k(scores + bias, self.top_k)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = self.scaling * picked / (
+            jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+        return chosen, weights
+
+    def apply(self, params: Params, h: jax.Array) -> Tuple[jax.Array, Dict]:
+        with jax.named_scope("moe"):
+            shape = h.shape
+            x = h.reshape(-1, shape[-1])
+            n, k, held = x.shape[0], self.top_k, self.held
+            rows = self.buffer_rows(n)
+            with jax.named_scope("moe_route"):
+                chosen, weights = self.route(params["router"], x)
+            with jax.named_scope("moe_dispatch"):
+                # sort the n * k assignments by held expert; those to
+                # experts held elsewhere sort last and stay out
+                local = chosen.reshape(-1) - self.first_held
+                key = jnp.where((local >= 0) & (local < held), local, held)
+                order = jnp.argsort(key, stable=True)
+                rank = jnp.argsort(order)          # the inverse permutation
+                counts = jnp.sum(
+                    key[:, None] == jnp.arange(held, dtype=key.dtype)[None],
+                    axis=0, dtype=jnp.int32)
+                ends = jnp.minimum(jnp.cumsum(counts), rows)
+                sizes = jnp.diff(ends, prepend=0)
+                filled = ends[-1]
+                picked = order[:rows]              # assignment of each row
+                tok = picked // k
+                slots = jnp.where(rank < filled, rank, rows).astype(jnp.int32)
+                xb = spread_rows(x, tok, slots.reshape(n, k))
+                wb = spread_rows(weights.reshape(-1, 1), picked,
+                                 slots.reshape(-1, 1))
+            e = params["experts"]
+            with jax.named_scope("moe_experts"):
+                dt = x.dtype
+                act = jax.nn.silu(lax.ragged_dot(xb, e["gate"].astype(dt),
+                                                 sizes))
+                act = act * lax.ragged_dot(xb, e["up"].astype(dt), sizes)
+                yb = lax.ragged_dot(act, e["down"].astype(dt), sizes)
+            with jax.named_scope("moe_combine"):
+                # rows past the filled ones hold whatever the product left
+                live = (jnp.arange(rows) < filled)[:, None]
+                yb = jnp.where(live, yb.astype(jnp.float32) * wb, 0.0)
+                out = collect_rows(yb.astype(dt), tok, slots.reshape(n, k))
+            total = jnp.sum(counts)
+            stats = {
+                "assignments": total.astype(jnp.float32),
+                "max_load_over_mean": jnp.max(counts) * held
+                / jnp.maximum(total, 1).astype(jnp.float32),
+                "overflow": (total - filled).astype(jnp.float32),
+            }
+            return out.reshape(shape), stats

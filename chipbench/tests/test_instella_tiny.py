@@ -1,0 +1,74 @@
+"""The expert model's tiny cell under the train driver (run with
+``JAX_PLATFORMS=cpu python -m pytest chipbench/tests``): a sound run is
+correct; the control (the reference with every linear layer's product in
+int8, put in the program's place) reads over the limits of the leaves that
+routing does not decide and of the weights' change; both planted faults
+read not correct."""
+
+import pytest
+
+from chipbench import compare, manifest
+from chipbench.drivers import train
+from chipbench.references import train as ref_train
+from chipbench.tests import tiny_instella
+from chipbench.tests.test_correct import _HalfBatch, _program
+
+
+def test_manifest_with_the_tiny_cell_has_no_problem_of_form():
+    assert manifest.problems(tiny_instella.bench(), manifest.ROOT) == []
+
+
+def test_sound_run_is_correct():
+    line = tiny_instella.run(seed=11)
+    assert line["correct"] is True, line["checks"]
+    assert line["attempted"] > 0 and line["failed"] == 0
+    assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
+    assert {"grad_sample_diff.dense_parts", "grad_diff_over.dense_parts"} \
+        <= set(line["checks"])
+
+
+def test_int8_control_reads_over_the_limits():
+    cell = tiny_instella.cell()
+    cfg, mix = cell["config"], cell["mix"]
+    limits = manifest.limits(manifest.ROOT, cell)
+    groups = manifest.part_groups(manifest.ROOT, cell)
+    for seed in (1, 2):
+        pool = train.make_pool(cfg, mix, seed, mix["batch"])[:train.FOLLOWED]
+        ref = ref_train.follow(cfg["reference"], cfg, mix, seed, pool,
+                               steps=train.FOLLOWED)
+        low = ref_train.follow(cfg["reference"], cfg, mix, seed, pool,
+                               steps=train.FOLLOWED, precision="int8")
+        readings = compare.train_readings(low, ref, groups)
+        for name in ("grad_sample_diff.dense_parts",
+                     "grad_diff_over.dense_parts", "update_norm_gap"):
+            assert readings[name] > limits[name], (seed, name, readings)
+
+
+class _Unchanged:
+    """A step that computes its loss and returns its state as it was. The
+    trainer's step donates its state, so the state handed back is a copy
+    made before the call."""
+
+    def __init__(self, program):
+        import jax
+        import jax.numpy as jnp
+
+        self._p = program
+        inner = program.step
+
+        def step(params, opt_state, *batch):
+            kept = jax.tree.map(jnp.copy, (params, opt_state))
+            _, _, loss, metrics = inner(params, opt_state, *batch)
+            return (*kept, loss, metrics)
+
+        self.step = step
+
+    def __getattr__(self, name):
+        return getattr(self._p, name)
+
+
+@pytest.mark.parametrize("fault", [_Unchanged, _HalfBatch])
+def test_fault_under_the_driver_reads_not_correct(fault):
+    broken = fault(_program(tiny_instella.cell()))
+    line = tiny_instella.run(seed=13, program=broken)
+    assert line["correct"] is False, line["checks"]

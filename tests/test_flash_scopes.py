@@ -45,31 +45,58 @@ def _op_names(jaxpr, prefix=""):
             yield from _op_names(sub, f"{here}/{name}")
 
 
+def _gpt():
+    """A tiny GPT: 2 layers, scanned, full recompute."""
+    model = GPTModel(GPTConfig(
+        vocab_size=256, hidden_size=64, num_layers=2, num_attention_heads=4,
+        max_seq_len=32, axis=None, hidden_dropout=0.0,
+        attention_impl="pallas", remat=True))
+    return model, lambda p, t: model.loss(p, t, t)
+
+
+def _instella():
+    """A tiny expert model: one dense layer and two expert layers, each
+    stack a scan of its own, full recompute."""
+    from apex_tpu.models import InstellaConfig, InstellaModel
+
+    model = InstellaModel(InstellaConfig(
+        vocab_size=256, hidden_size=64, num_layers=3, num_dense_layers=1,
+        num_attention_heads=4, qk_nope_head_dim=12, qk_rope_head_dim=4,
+        v_head_dim=16, kv_lora_rank=32, ffn_hidden_size=160,
+        moe_ffn_hidden_size=24, num_experts=16, experts_held=4, top_k=3,
+        max_seq_len=32, attention_impl="pallas"))
+    return model, lambda p, t: model.loss(p, t, t)[0]
+
+
 @pytest.fixture(scope="module")
 def pallas_calls():
-    """``op_name`` of every ``pallas_call`` in the gradient of a tiny GPT
-    (2 layers, scanned, full recompute: forward, recompute and backward)."""
-    cfg = GPTConfig(vocab_size=256, hidden_size=64, num_layers=2,
-                    num_attention_heads=4, max_seq_len=32, axis=None,
-                    hidden_dropout=0.0, attention_impl="pallas", remat=True)
-    model = GPTModel(cfg)
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
-    jaxpr = jax.make_jaxpr(
-        jax.grad(lambda p, t: model.loss(p, t, t)))(params, toks)
-    return [n for n in _op_names(jaxpr.jaxpr) if n.endswith("/pallas_call")]
+    """``op_name`` of every ``pallas_call`` in the gradient of each tiny
+    model: forward, recompute and backward."""
+    out = {}
+    for name, build in (("gpt", _gpt), ("instella", _instella)):
+        model, loss = build()
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(params, toks)
+        out[name] = [n for n in _op_names(jaxpr.jaxpr)
+                     if n.endswith("/pallas_call")]
+    return out
 
 
-@pytest.mark.parametrize("metric,calls", [
-    ("train.flash_fwd_roofline", 2),   # first forward and recompute
-    ("train.flash_bwd_roofline", 2),   # the dQ pass and the dK/dV pass
+@pytest.mark.parametrize("model,metric,calls", [
+    ("gpt", "train.flash_fwd_roofline", 2),   # first forward and recompute
+    ("gpt", "train.flash_bwd_roofline", 2),   # the dQ and the dK/dV pass
+    # the same, in each of the two stacks
+    ("instella", "train.flash_fwd_roofline", 4),
+    ("instella", "train.flash_bwd_roofline", 4),
 ])
 def test_flash_kernels_keep_the_names_the_benchmark_reads(
-        pallas_calls, metric, calls):
+        pallas_calls, model, metric, calls):
     from chipbench import manifest
 
     pattern = manifest.metric_file(ROOT, ["chipbench"],
                                    metric)["params"]["pattern"]
+    pallas_calls = pallas_calls[model]
     mine = [n for n in pallas_calls if re.search(pattern, n)]
     assert len(mine) == calls, (pattern, pallas_calls)
     for name in mine:

@@ -2,8 +2,10 @@
 (PR 26) are a contract between the program and ``chipbench/metrics/``.
 
 On the CPU, at a tiny size, for the GPT step ``pretrain_gpt.main`` builds
-(O2, FusedAdam, microbatch ring) and the BERT + LAMB step the benchmark's
-adapter composes: every scope names instructions where its phase runs, every
+(O2, FusedAdam, microbatch ring), the BERT + LAMB step the benchmark's
+adapter composes and the expert model's step ``pretrain_instella.build``
+makes (PR 28: a second block under the same contract, with scopes of its
+own inside it): every scope names instructions where its phase runs, every
 ``chipbench/metrics/train.*.json`` that reads scopes finds something to read
 (a rename would end a traced chip run with exit code 4, after the chip time
 is spent), and the scopes change nothing but metadata in the compiled step.
@@ -30,7 +32,15 @@ MODEL_SCOPES = ("layers", "attention_core", "layer_norm", "head")
 #: scopes of the update: after the gradient, so under no ``jvp(``
 UPDATE_SCOPES = ("amp_unscale", "optimizer_update", "amp_cast",
                  "amp_scale_update")
-PROGRAMS = ("gpt", "bert")
+#: the second block's own scopes, all inside the scanned, checkpointed stack
+INSTELLA_SCOPES = ("attention", "attn_latent", "rope", "attn_gate", "mlp",
+                   "moe_shared", "moe", "moe_route", "moe_dispatch",
+                   "moe_experts", "moe_combine")
+PROGRAMS = ("gpt", "bert", "instella")
+#: the cell of the benchmark that runs each program
+CELLS = {"gpt": "gpt2_345m.pretrain_b8s1024",
+         "bert": "bert_large.pretrain_b8s512",
+         "instella": "instella_moe_16b_a3b.pretrain_b8s4096"}
 METRICS = sorted(
     os.path.basename(f)[:-len(".json")]
     for f in glob.glob(os.path.join(ROOT, "chipbench", "metrics", "*.json"))
@@ -79,7 +89,31 @@ def _bert_text() -> str:
         *(batch[k] for k in bert_lamb.Program.FEED)).compile().as_text()
 
 
-BUILD = {"gpt": _gpt_text, "bert": _bert_text}
+def _instella_text() -> str:
+    import numpy as np
+
+    from apex_tpu import amp
+    from chipbench.programs import pretrain_instella
+    from chipbench.references import train as ref_train
+    from chipbench.tests import tiny_instella
+
+    cell = tiny_instella.cell(ROOT)
+    cfg, mix = cell["config"], cell["mix"]
+    model, policy, mp_opt, step = pretrain_instella.build(cfg, mix, ROOT)
+
+    def state(key):
+        params = amp.cast_params(model.init(key), policy)
+        return params, mp_opt.init(params)
+
+    batch = ref_train.family(cfg["reference"]).make_batch(
+        cfg, mix, np.random.default_rng(0), mix["batch"])
+    return step.lower(
+        *jax.eval_shape(state, jax.random.PRNGKey(0)),
+        *(batch[k] for k in pretrain_instella.Program.FEED)
+    ).compile().as_text()
+
+
+BUILD = {"gpt": _gpt_text, "bert": _bert_text, "instella": _instella_text}
 
 
 @pytest.fixture(scope="module")
@@ -113,8 +147,9 @@ def _some(scopes, *patterns, none=()):
             and not any(re.search(p, s) for p in none)]
 
 
-@pytest.mark.parametrize("scope", MODEL_SCOPES)
-@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("program,scope", [
+    (p, s) for p in PROGRAMS for s in MODEL_SCOPES
+] + [("instella", s) for s in INSTELLA_SCOPES])
 def test_model_scope_names_forward_backward_and_recompute(
         scopes, program, scope):
     mine = scopes[program]
@@ -144,8 +179,15 @@ def _reader_ctx(scopes):
             "runs": {"/device:TPU:0": [(0.0, 1.0), (1.0, 2.0)]}}
 
 
-@pytest.mark.parametrize("metric", METRICS)
-@pytest.mark.parametrize("program", PROGRAMS)
+def _listed(metric: str) -> list:
+    """The programs whose cell the manifest lists under the metric."""
+    listed = next(m for m in manifest.load(ROOT)["per_layer"]
+                  if m["name"] == metric)["workloads"]
+    return [p for p in PROGRAMS if CELLS[p] in listed]
+
+
+@pytest.mark.parametrize("program,metric", [
+    (p, m) for m in METRICS for p in _listed(m)])
 def test_metric_file_finds_something_to_read(scopes, program, metric):
     params = manifest.metric_file(ROOT, ["chipbench"], metric)["params"]
     ctx = _reader_ctx(scopes[program])
@@ -156,15 +198,23 @@ def test_metric_file_finds_something_to_read(scopes, program, metric):
     assert got is not None and got > 0, (metric, params)
 
 
+#: PR 26's seven, which every cell reports
+SHARED = ["train.amp_unscale_ms", "train.attention_proj_ms",
+          "train.layer_norm_ms", "train.lm_head_ms",
+          "train.optimizer_update_ms", "train.recompute_ms",
+          "train.unattributed_ms"]
+#: PR 28's two, which read scopes only the expert model's block has
+INSTELLA_ONLY = ["train.attn_latent_ms", "train.moe_route_ms"]
+
+
 def test_the_metrics_that_read_scopes():
-    """Seven: ``amp_cast`` has no metric of its own, since the compiler
-    fuses the cast into the update (``PERF.md``, Findings, PR 26) and
-    ``train.optimizer_update_ms`` reads both scopes."""
-    assert METRICS == [
-        "train.amp_unscale_ms", "train.attention_proj_ms",
-        "train.layer_norm_ms", "train.lm_head_ms",
-        "train.optimizer_update_ms", "train.recompute_ms",
-        "train.unattributed_ms"]
+    """Seven that every cell reports: ``amp_cast`` has no metric of its own,
+    since the compiler fuses the cast into the update (``PERF.md``,
+    Findings, PR 26) and ``train.optimizer_update_ms`` reads both scopes.
+    Two more for the expert model's cell alone (its third,
+    ``train.moe_experts_ms``, also reads the grouped-product calls by name
+    and has a reader of its own)."""
+    assert METRICS == sorted(SHARED + INSTELLA_ONLY)
     update = manifest.metric_file(ROOT, ["chipbench"],
                                   "train.optimizer_update_ms")
     for scope in ("optimizer_update", "amp_cast"):
@@ -201,8 +251,16 @@ def test_scopes_change_nothing_but_metadata(texts, program):
     named = _instructions(texts["named"][program])
     bare = _instructions(texts["bare"][program])
     assert len(named) == len(bare)
+    if program == "instella":
+        # the two builds number a few of this step's instructions apart
+        # (%call.17 against %call.20, the same call of the same
+        # computation): compared with the numbers off
+        number = re.compile(r"(%[A-Za-z_][\w\-]*?)[.\d]*\b")
+        named = [number.sub(r"\1", line) for line in named]
+        bare = [number.sub(r"\1", line) for line in bare]
     assert named == bare
-    for scope in MODEL_SCOPES + UPDATE_SCOPES:
+    mine = INSTELLA_SCOPES if program == "instella" else ()
+    for scope in MODEL_SCOPES + UPDATE_SCOPES + mine:
         assert not re.search(token(scope), texts["bare"][program])
 
 
@@ -210,5 +268,38 @@ def test_every_scope_metric_is_in_the_manifest():
     listed = {m["name"]: m for m in manifest.load(ROOT)["per_layer"]}
     for metric in METRICS:
         assert listed[metric]["source"] == "program_span"
-        assert listed[metric]["workloads"] == [
-            "gpt2_345m.pretrain_b8s1024", "bert_large.pretrain_b8s512"]
+        assert listed[metric]["workloads"] == (
+            list(CELLS.values()) if metric in SHARED
+            else [CELLS["instella"]])
+
+
+@pytest.mark.parametrize("metric", [
+    "train.moe_grouped_roofline", "train.moe_experts_ms",
+    "train.flash_fwd_roofline", "train.flash_bwd_roofline"])
+def test_the_expert_models_kernel_metrics_find_their_instructions(
+        scopes, metric):
+    """The grouped products' metrics read a scope of the compiled step and,
+    by name, the call XLA:TPU makes of ``ragged_dot`` (which keeps no
+    ``op_name``); the flash rooflines read kernel names the CPU's text does
+    not carry (``tests/test_flash_scopes.py`` holds those)."""
+    from chipbench.readers import moe_experts_time
+
+    params = manifest.metric_file(ROOT, ["chipbench"], metric)["params"]
+    if metric.startswith("train.moe_"):
+        assert _some(scopes["instella"], params["scope"], r"jvp\(")
+        assert _some(scopes["instella"], params["scope"], r"transpose\(")
+        ops = [{"name": "%ragged-dot-none.5 = bf16[98304,1408] custom-call",
+                "scope": "", "dur": 2e-3},
+               {"name": "%fusion.7", "scope": "jit(f)/moe/moe_experts/mul",
+                "dur": 1e-3},
+               {"name": "%fusion.8", "scope": "jit(f)/moe/moe_route/top_k",
+                "dur": 4e-3}]
+        assert len(moe_experts_time.products(ops, **params)) == 2
+        ctx = {"ops": {"d": ops}, "runs": {"d": [(0.0, 1.0), (1.0, 2.0)]}}
+        assert moe_experts_time.read(ctx, **params) == pytest.approx(1.5)
+        assert moe_experts_time.read(
+            {"ops": {"d": ops[2:]}, "runs": ctx["runs"]}, **params) is None
+    else:
+        assert CELLS["instella"] in next(
+            m for m in manifest.load(ROOT)["per_layer"]
+            if m["name"] == metric)["workloads"]
