@@ -451,66 +451,192 @@ class MoEMLP:
 # experts as grouped products over the rows actually filled
 # (``lax.ragged_dot``, which XLA:TPU compiles to its grouped-matmul call and
 # whose time follows the group sizes, not the buffer).
+#
+# The rows are moved the same way: every mover below walks the rows that
+# hold an assignment, ``MOVE_ROWS`` at a trip of a loop whose trip count is
+# read from ``filled`` at run time, so its time follows the load as the
+# products' does. Two orders of the same rows serve the two directions: the
+# buffer's own (by expert, then token), in which a row is gathered from its
+# token, and the token's (by token), in which the rows of one token lie side
+# by side and are summed by shifted adds, no scatter-add.
+
+#: rows a trip of a mover's loop moves (fewer where the buffer is smaller)
+MOVE_ROWS = 2048
 
 
-def _collect_rows(buf: jax.Array, slots: jax.Array) -> jax.Array:
-    """``(C, d)`` rows to ``(N, d)``: token ``n`` gets the sum of the rows
-    ``slots[n, :]`` names; ``C`` names no row. Gathers only, one choice
-    after another, so one gathered ``(N, d)`` is alive at a time."""
-    c = buf.shape[0]
-    by_choice = slots.T
+def _chunk(rows: int) -> int:
+    """Rows a trip moves over a buffer of ``rows``."""
+    return min(rows, MOVE_ROWS)
 
-    def add(j, acc):
-        idx = lax.dynamic_index_in_dim(by_choice, j, keepdims=False)
-        rows = jnp.take(buf, jnp.minimum(idx, c - 1), axis=0)
-        return acc + jnp.where((idx < c)[:, None],
-                               rows.astype(jnp.float32), 0.0)
 
-    acc = lax.fori_loop(
-        0, slots.shape[1], add,
-        jnp.zeros((slots.shape[0], buf.shape[1]), jnp.float32))
-    return acc.astype(buf.dtype)
+def _trips(filled: jax.Array, rows: int) -> jax.Array:
+    """Trips of a mover's loop over a buffer of ``rows`` of which the first
+    ``filled`` hold an assignment."""
+    return (filled + _chunk(rows) - 1) // _chunk(rows)
+
+
+def _window(i, rows: int) -> jax.Array:
+    """Where trip ``i`` starts: the last window is moved back to end with
+    the buffer (every mover writes a row the same whichever window it
+    falls in, so rows moved twice are moved right)."""
+    return jnp.minimum(i * _chunk(rows), rows - _chunk(rows))
+
+
+def _zeros_here(shape, dtype, filled: jax.Array) -> jax.Array:
+    """Zeros for a mover's loop to write into, made where the layer runs and
+    under its scope. A constant made in a scanned layer is lifted out of
+    the scan when its gradient is taken, and a loop that writes into what
+    every layer shares first copies it (1.2 ms a buffer, under no scope);
+    one the compiler can fold it makes again under the loop's name alone.
+    ``filled`` is never negative, which only the run knows."""
+    return jnp.broadcast_to((filled < 0).astype(dtype), shape)
+
+
+def row_plan(tok: jax.Array, slots: jax.Array, filled: jax.Array) -> Dict:
+    """The partial permutation between ``N`` tokens and ``C`` buffer rows,
+    read from both sides, for :func:`spread_rows` and :func:`collect_rows`.
+    ``tok`` ``(C,)``: the token of each buffer row; ``slots`` ``(N, k)``:
+    the buffer row of each of a token's choices, ``C`` for none; the first
+    ``filled`` rows hold an assignment. The token's side is one sort of the
+    filled rows by token: ``by_token[j]`` is the buffer row that is ``j``-th
+    in that order, ``token_of[j]`` its token (``N`` past the filled ones and
+    in the ``reach`` entries of padding a window reads past its end), and
+    ``head[n]`` where token ``n``'s rows start, ``C`` if it has none."""
+    rows, (n, k) = tok.shape[0], slots.shape
+    key = jnp.where(jnp.arange(rows) < filled, tok, n).astype(jnp.int32)
+    token_of, by_token = lax.sort(
+        (key, jnp.arange(rows, dtype=jnp.int32)), num_keys=2, is_stable=False)
+    held = jnp.sum(slots < rows, axis=1, dtype=jnp.int32)
+    head = jnp.where(held > 0, jnp.cumsum(held) - held, rows)
+    return {"tok": tok.astype(jnp.int32), "filled": filled, "head": head,
+            "by_token": jnp.pad(by_token, (0, k)),
+            "token_of": jnp.pad(token_of, (0, k), constant_values=n)}
+
+
+def _gather_rows(src, plan, weights=None, other=None):
+    """``(N, d)`` to ``(C, d)``: buffer row ``r`` is ``src[tok[r]]`` for
+    the filled rows, zero past the last trip. With ``weights`` ``(C,)`` and
+    ``other`` ``(C, d)`` a filled row is scaled by its weight in float32,
+    and the row's product with ``other``'s row comes back beside it
+    (``collect_rows``' two gradients, from one visit of the row)."""
+    tok, filled = plan["tok"], plan["filled"]
+    rows, d = tok.shape[0], src.shape[1]
+    chunk = _chunk(rows)
+
+    def move(i, carry):
+        out, dots = carry
+        lo = _window(i, rows)
+        got = jnp.take(src, lax.dynamic_slice(tok, (lo,), (chunk,)), axis=0,
+                       mode="clip")
+        if weights is not None:
+            live = lo + jnp.arange(chunk) < filled
+            got32 = got.astype(jnp.float32)
+            theirs = lax.dynamic_slice(other, (lo, 0), (chunk, d))
+            dots = lax.dynamic_update_slice(dots, jnp.where(live, jnp.sum(
+                got32 * theirs.astype(jnp.float32), axis=1), 0.0), (lo,))
+            w = lax.dynamic_slice(weights, (lo,), (chunk,))
+            got = jnp.where(live[:, None], got32 * w[:, None],
+                            0.0).astype(src.dtype)
+        return lax.dynamic_update_slice(out, got, (lo, 0)), dots
+
+    return lax.fori_loop(
+        0, _trips(filled, rows), move,
+        (_zeros_here((rows, d), src.dtype, filled),
+         _zeros_here((rows,), jnp.float32, filled)))
+
+
+def _sum_rows(buf, plan, weights=None):
+    """``(C, d)`` to ``(N, d)``: token ``n`` gets the sum of the filled
+    rows that are its own, each times its weight if ``weights`` ``(C,)`` is
+    given (in float32, rounded to the buffer's type as a row of its own,
+    then summed in float32 and rounded once). The rows are visited in the
+    token's order, so a token's rows are a run of at most ``reach`` and one
+    pass of shifted adds sums every run at its head; a token then reads its
+    head, or the zero row past the last."""
+    by_token, token_of, head = plan["by_token"], plan["token_of"], plan["head"]
+    rows, d = plan["tok"].shape[0], buf.shape[1]
+    chunk, reach = _chunk(rows), by_token.shape[0] - rows
+
+    def move(i, heads):
+        lo = _window(i, rows)
+        idx = lax.dynamic_slice(by_token, (lo,), (chunk + reach,))
+        t = lax.dynamic_slice(token_of, (lo,), (chunk + reach,))
+        got = jnp.take(buf, idx, axis=0, mode="clip")
+        if weights is not None:
+            w = jnp.take(weights, idx, mode="clip")
+            got = (got.astype(jnp.float32) * w[:, None]).astype(buf.dtype)
+        acc = got[:chunk].astype(jnp.float32)
+        for s in range(1, reach):
+            acc += jnp.where((t[s:s + chunk] == t[:chunk])[:, None],
+                             got[s:s + chunk].astype(jnp.float32), 0.0)
+        return lax.dynamic_update_slice(heads, acc.astype(buf.dtype), (lo, 0))
+
+    heads = lax.fori_loop(
+        0, _trips(plan["filled"], rows), move,
+        _zeros_here((rows + 1, d), buf.dtype, plan["filled"]))
+    return jnp.take(heads, head, axis=0, mode="clip")
 
 
 @jax.custom_vjp
-def spread_rows(x: jax.Array, tok: jax.Array, slots: jax.Array) -> jax.Array:
-    """``(N, d)`` to ``(C, d)``: buffer row ``r`` is ``x[tok[r]]``. ``slots``
-    ``(N, k)`` is the same partial permutation read from the other side
-    (the buffer row of each of a token's ``k`` choices, ``C`` for none), and
-    only the backward pass reads it: the transpose of a gather by a
-    permutation is the gather by its inverse, so no scatter-add runs."""
-    return jnp.take(x, tok, axis=0)
+def spread_rows(x: jax.Array, plan: Dict) -> jax.Array:
+    """``(N, d)`` to ``(C, d)``: buffer row ``r`` is ``x[tok[r]]``. The
+    transpose of a gather by a permutation is the sum over its inverse, so
+    the backward pass is :func:`collect_rows`' forward, unweighted."""
+    return _gather_rows(x, plan)[0]
 
 
-def _spread_fwd(x, tok, slots):
-    return jnp.take(x, tok, axis=0), (tok, slots)
+def _spread_fwd(x, plan):
+    return _gather_rows(x, plan)[0], plan
 
 
-def _spread_bwd(res, g):
-    _, slots = res
-    return _collect_rows(g, slots), None, None
+def _spread_bwd(plan, g):
+    return _sum_rows(g, plan), None
 
 
 spread_rows.defvjp(_spread_fwd, _spread_bwd)
 
 
 @jax.custom_vjp
-def collect_rows(buf: jax.Array, tok: jax.Array, slots: jax.Array) -> jax.Array:
-    """The transpose of :func:`spread_rows`: ``(C, d)`` back to ``(N, d)``,
-    a token's rows summed."""
-    return _collect_rows(buf, slots)
+def collect_rows(buf: jax.Array, weights: jax.Array, plan: Dict) -> jax.Array:
+    """The transpose of :func:`spread_rows`, weighted: ``(C, d)`` back to
+    ``(N, d)``, a token's rows each times its weight ``(C,)`` and summed."""
+    return _sum_rows(buf, plan, weights)
 
 
-def _collect_fwd(buf, tok, slots):
-    return _collect_rows(buf, slots), (tok, slots)
+def _collect_fwd(buf, weights, plan):
+    return _sum_rows(buf, plan, weights), (buf, weights, plan)
 
 
 def _collect_bwd(res, g):
-    tok, _ = res
-    return jnp.take(g, tok, axis=0), None, None
+    buf, weights, plan = res
+    return (*_gather_rows(g, plan, weights, buf), None)
 
 
 collect_rows.defvjp(_collect_fwd, _collect_bwd)
+
+
+@jax.custom_vjp
+def sort_with(key: jax.Array, values: jax.Array):
+    """``(order, values[order])`` for the stable sort of ``key`` (told as
+    the sort by key, then place: no two compare equal). The values ride
+    through the sort, and their gradient rides back through the sort of
+    ``order``, so neither way is a gather by the element."""
+    at = jnp.arange(key.shape[0], dtype=jnp.int32)
+    _, order, carried = lax.sort((key, at, values), num_keys=2,
+                                 is_stable=False)
+    return order, carried
+
+
+def _sort_with_fwd(key, values):
+    order, carried = sort_with.fun(key, values)
+    return (order, carried), order
+
+
+def _sort_with_bwd(order, g):
+    return None, lax.sort((order, g[1]), num_keys=1, is_stable=False)[1]
+
+
+sort_with.defvjp(_sort_with_fwd, _sort_with_bwd)
 
 
 class DroplessExperts:
@@ -533,10 +659,10 @@ class DroplessExperts:
     of ``BUFFER_FACTOR`` times their number under an even router (never more
     than the worst case, ``min(top_k, held)`` a token). Every assignment
     that fits is computed whatever the imbalance between experts, and the
-    products' time follows the rows filled, not the buffer; if more arrive
-    than the buffer holds, ``stats["overflow"]`` counts them and the caller
-    must skip the step (``pretrain_instella`` does, and the driver counts it
-    failed): never a silent loss.
+    products' and the row movers' time follows the rows filled, not the
+    buffer; if more arrive than the buffer holds, ``stats["overflow"]``
+    counts them and the caller must skip the step (``pretrain_instella``
+    does, and the driver counts it failed): never a silent loss.
 
     The selection bias is a held buffer here: nothing moves it. (Moving it
     as ``noaux_tc`` does in training, 0.001 a step against each expert's
@@ -546,7 +672,9 @@ class DroplessExperts:
 
     ``apply`` returns ``(out, stats)`` with the counters ``assignments`` (to
     held experts), ``max_load_over_mean`` (the fullest held expert over the
-    mean) and ``overflow``.
+    mean), ``overflow`` and ``rows_moved`` (the rows of ``hidden_size`` the
+    forward movers touched, from their own trip counts: about twice the
+    assignments plus the tokens, whatever the buffer).
     """
 
     #: rows of the buffer over the assignments an even router makes to the
@@ -614,33 +742,42 @@ class DroplessExperts:
             jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
         return chosen, weights
 
+    def place(self, chosen: jax.Array, weights: jax.Array):
+        """Where each assignment goes: ``(plan, wb, counts)`` for the
+        ``(N, top_k)`` experts chosen and their weights. The assignments are
+        sorted by held expert (those to experts held elsewhere sort last and
+        stay out); ``plan`` is :func:`row_plan`'s, ``wb`` ``(C,)`` the
+        weight of each buffer row, ``counts`` the assignments to each held
+        expert, whether or not the buffer holds them all."""
+        n, k = chosen.shape
+        held, rows = self.held, self.buffer_rows(n)
+        local = chosen.reshape(-1) - self.first_held
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order, carried = sort_with(key, weights.reshape(-1))
+        # the inverse permutation: place of each assignment in the order
+        rank = lax.sort((order, jnp.arange(n * k, dtype=jnp.int32)),
+                        num_keys=1, is_stable=False)[1]
+        counts = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=key.dtype)[None],
+            axis=0, dtype=jnp.int32)
+        filled = jnp.minimum(jnp.sum(counts), rows)
+        slots = jnp.where(rank < filled, rank, rows).astype(jnp.int32)
+        plan = row_plan(order[:rows] // k, slots.reshape(n, k), filled)
+        return plan, carried[:rows], counts
+
     def apply(self, params: Params, h: jax.Array) -> Tuple[jax.Array, Dict]:
         with jax.named_scope("moe"):
             shape = h.shape
             x = h.reshape(-1, shape[-1])
-            n, k, held = x.shape[0], self.top_k, self.held
-            rows = self.buffer_rows(n)
+            n, rows = x.shape[0], self.buffer_rows(x.shape[0])
             with jax.named_scope("moe_route"):
                 chosen, weights = self.route(params["router"], x)
             with jax.named_scope("moe_dispatch"):
-                # sort the n * k assignments by held expert; those to
-                # experts held elsewhere sort last and stay out
-                local = chosen.reshape(-1) - self.first_held
-                key = jnp.where((local >= 0) & (local < held), local, held)
-                order = jnp.argsort(key, stable=True)
-                rank = jnp.argsort(order)          # the inverse permutation
-                counts = jnp.sum(
-                    key[:, None] == jnp.arange(held, dtype=key.dtype)[None],
-                    axis=0, dtype=jnp.int32)
-                ends = jnp.minimum(jnp.cumsum(counts), rows)
-                sizes = jnp.diff(ends, prepend=0)
-                filled = ends[-1]
-                picked = order[:rows]              # assignment of each row
-                tok = picked // k
-                slots = jnp.where(rank < filled, rank, rows).astype(jnp.int32)
-                xb = spread_rows(x, tok, slots.reshape(n, k))
-                wb = spread_rows(weights.reshape(-1, 1), picked,
-                                 slots.reshape(-1, 1))
+                plan, wb, counts = self.place(chosen, weights)
+                filled = plan["filled"]
+                sizes = jnp.diff(jnp.minimum(jnp.cumsum(counts), rows),
+                                 prepend=0)
+                xb = spread_rows(x, plan)
             e = params["experts"]
             with jax.named_scope("moe_experts"):
                 dt = x.dtype
@@ -649,15 +786,17 @@ class DroplessExperts:
                 act = act * lax.ragged_dot(xb, e["up"].astype(dt), sizes)
                 yb = lax.ragged_dot(act, e["down"].astype(dt), sizes)
             with jax.named_scope("moe_combine"):
-                # rows past the filled ones hold whatever the product left
-                live = (jnp.arange(rows) < filled)[:, None]
-                yb = jnp.where(live, yb.astype(jnp.float32) * wb, 0.0)
-                out = collect_rows(yb.astype(dt), tok, slots.reshape(n, k))
+                # rows past the filled ones hold whatever the product
+                # left: no mover visits them
+                out = collect_rows(yb, wb, plan)
             total = jnp.sum(counts)
             stats = {
                 "assignments": total.astype(jnp.float32),
-                "max_load_over_mean": jnp.max(counts) * held
+                "max_load_over_mean": jnp.max(counts) * self.held
                 / jnp.maximum(total, 1).astype(jnp.float32),
                 "overflow": (total - filled).astype(jnp.float32),
+                "rows_moved": (
+                    _trips(filled, rows) * (2 * _chunk(rows) + self.top_k)
+                    + n).astype(jnp.float32),
             }
             return out.reshape(shape), stats

@@ -158,7 +158,10 @@ def test_model_scope_names_forward_backward_and_recompute(
     assert _some(mine, token(scope), r"transpose\(",
                  none=("rematted_computation",)), \
         f"{scope}: nothing in the backward pass"
-    if scope != "head":      # the head is outside the checkpointed stack
+    # the head is outside the checkpointed stack, and its recompute stops
+    # at the grouped products: what combine's backward reads (the
+    # products' rows, the weights, the plan) is made under other scopes
+    if scope not in ("head", "moe_combine"):
         assert _some(mine, token(scope), token("layers"),
                      "/rematted_computation/"), \
             f"{scope}: nothing in the recompute"
@@ -171,6 +174,58 @@ def test_update_scope_names_instructions_outside_the_gradient(
     mine = _some(scopes[program], token(scope))
     assert mine, f"{scope}: names no instruction"
     assert not _some(mine, r"jvp\("), f"{scope}: inside the gradient"
+
+
+#: what a row mover of the dropless layer compiles to
+MOVER_OPS = ("gather", "scatter", "dynamic-slice", "dynamic-update-slice",
+             "while", "sort", "custom-call")
+
+
+def test_the_dropless_movers_stay_under_dispatch_and_combine(texts):
+    """``train.moe_route_ms`` cuts by scope, so a mover that loses its scope
+    is time renamed, not saved: under ``moe`` every gather, slice, loop,
+    sort and custom call (the loops' bodies and the transposed passes
+    included) names ``moe_dispatch`` or ``moe_combine`` (the router's own
+    gather ``moe_route``), and each mover's loop is where its pass runs."""
+    inner = "|".join(token(s) for s in (
+        "moe_route", "moe_dispatch", "moe_combine", "moe_experts"))
+    found = []
+    for line in texts["named"]["instella"].splitlines():
+        m = re.search(r"\s(%s)\(.*op_name=\"([^\"]+)\"" % "|".join(MOVER_OPS),
+                      line)
+        if m and re.search(token("moe"), m.group(2)):
+            assert re.search(inner, m.group(2)), line
+            found.append(m.groups())
+    # and whatever is the size of the buffer is a mover's or a product's
+    # (the zeros a loop writes into: a constant would lose the scope)
+    from chipbench.programs import pretrain_instella
+    from chipbench.tests import tiny_instella
+
+    cell = tiny_instella.cell(ROOT)
+    model = pretrain_instella.build(cell["config"], cell["mix"], ROOT)[0]
+    rows = model.experts.buffer_rows(cell["mix"]["batch"] * cell["mix"]["seq"])
+    sized = re.compile(
+        r"= \w+\[(%d|%d),%d\]\S* ([\w\-]+)\(.*op_name=\"([^\"]+)\"" % (
+            rows, rows + 1, model.cfg.hidden_size))
+    # (the CPU's compiler widens the products' operands to float32 under
+    # the stack's name alone: its own ``convert``s are no mover's)
+    sizes = [m.group(3) for m in map(sized.search,
+                                     texts["named"]["instella"].splitlines())
+             if m and m.group(2) != "convert"]
+    assert len(sizes) > 20
+    assert not _some(sizes, "^", none=(inner,)), _some(
+        sizes, "^", none=(inner,))
+    loops = [name for op, name in found if op == "while"]
+    bodies = [name for op, name in found
+              if op == "gather" and "/while/body/" in name]
+    for some in (loops, bodies):
+        for scope in ("moe_dispatch", "moe_combine"):
+            mine = _some(some, token(scope))
+            assert _some(mine, r"jvp\(", none=(r"transpose\(",)), scope
+            assert _some(mine, r"transpose\(",
+                         none=("rematted_computation",)), scope
+            if scope == "moe_dispatch":   # the recompute stops at the products
+                assert _some(mine, "/rematted_computation/"), scope
 
 
 def _reader_ctx(scopes):
