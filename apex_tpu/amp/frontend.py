@@ -154,8 +154,6 @@ class MixedPrecisionOptimizer:
         log_group_norms: bool = False,
         zero_axis: Optional[str] = None,
         zero_level: int = 2,
-        dcn_axis: Optional[str] = None,
-        dcn_wire: Optional[str] = "int8",
         gather_dtype: Optional[Any] = None,
         reduce_dtype: Optional[str] = None,
         stochastic_rounding: bool = False,
@@ -254,45 +252,6 @@ class MixedPrecisionOptimizer:
                 "transposes (optimizers.distributed.gather_leaf AD), not "
                 "in apply_gradients — quantize at level 1/2, or use "
                 "gather_dtype for the JIT gathers")
-        #: mesh axis of the slow inter-island (DCN) tier under ZeRO
-        #: (parallel/hierarchy.py): with ``dcn_axis`` set the masters and
-        #: moments shard over the COMBINED ``(dcn_axis, zero_axis)`` group
-        #: — flat chunk index of rank ``(d, i)`` is ``d * n_ici + i`` —
-        #: and every bulk collective runs hierarchically: intra-island
-        #: reduce/gather on the fast ICI links, exactly ONE
-        #: ``1/n_ici``-sized exchange across DCN. ``dcn_wire`` (default
-        #: "int8" — EQuARX's deployment point: quantize exactly where the
-        #: slow tier binds) moves the inter-island GRAD hop at 1 B/elem
-        #: with the same error-feedback residual contract as
-        #: ``reduce_dtype`` (the intra-island stage stays exact fp32);
-        #: ``dcn_wire=None`` keeps the whole decomposition exact —
-        #: bit-identical, values AND grads, to the flat tuple-axis
-        #: collectives (tests/test_hierarchy.py pins it).
-        self.dcn_axis = dcn_axis
-        self.dcn_wire = canon_wire_dtype(dcn_wire) if dcn_axis else None
-        if dcn_axis is not None:
-            if zero_axis is None:
-                raise ValueError(
-                    "dcn_axis only applies with zero_axis set: it names "
-                    "the slow tier of the hierarchical ZeRO collectives "
-                    "(parallel/hierarchy.py)")
-            if self.zero_level >= 3:
-                raise ValueError(
-                    "dcn_axis does not compose with zero_level=3: the "
-                    "per-layer JIT gathers ride the single-axis chunk "
-                    "drive (models/_transformer.run_layers) — shard the "
-                    "working params over the island-internal axis and "
-                    "keep dcn for the optimizer tiers at levels 1/2")
-            if self.reduce_dtype is not None:
-                raise ValueError(
-                    "reduce_dtype does not compose with dcn_axis: the "
-                    "grad wire is per TIER there — dcn_wire quantizes "
-                    "the inter-island hop while the intra-island stage "
-                    "stays exact fp32 (quantizing the fast links buys "
-                    "nothing, EQuARX's observation)")
-            from apex_tpu.monitor.comms import register_dcn_axis
-
-            register_dcn_axis(dcn_axis)
         #: int8-only uniform dither before the round (zero-mean per-element
         #: error) — an option on top of, not a substitute for, the
         #: error-feedback residual. Carries a per-rank PRNG key in
@@ -339,46 +298,20 @@ class MixedPrecisionOptimizer:
             return jax.tree.map(lambda _: False, params)
         return self._zero_sharded
 
-    def _zero_group(self) -> Tuple[str, ...]:
-        """The mesh axes the optimizer state shards over: ``(dcn, zero)``
-        on a two-tier mesh — lax tuple-axis order, first name most
-        significant, matching the hier_* chunk layout — else
-        ``(zero,)``."""
-        if self.dcn_axis is not None:
-            return (self.dcn_axis, self.zero_axis)
-        return (self.zero_axis,)
-
-    def _zero_group_size(self):
-        """Total shard count across the group (n_dcn * n_ici)."""
-        n = lax.axis_size(self.zero_axis)
-        if self.dcn_axis is not None:
-            n = n * lax.axis_size(self.dcn_axis)
-        return n
-
-    def _zero_group_index(self):
-        """This rank's flat chunk index in the group:
-        ``d * n_ici + i`` (hierarchy.py's equivalence contract)."""
-        idx = lax.axis_index(self.zero_axis)
-        if self.dcn_axis is not None:
-            idx = (lax.axis_index(self.dcn_axis)
-                   * lax.axis_size(self.zero_axis) + idx)
-        return idx
-
     def _chunk_tree(self, params, dtype=None):
         """This rank's per-leaf ZeRO state: a 1-D chunk of every
         zero-axis-REPLICATED leaf (stacked-aware at level 3); leaves
         SHARDED over the zero axis (expert params, levels 1/2) pass
         through as their local shard — already 1/n of the global leaf.
         Must run inside shard_map (or an axis_env trace) binding the
-        zero axis (and ``dcn_axis`` when set — the chunk index flattens
-        the two-tier group)."""
+        zero axis."""
         from apex_tpu.optimizers.distributed import (
             local_chunk,
             local_chunk_stacked,
         )
 
-        n = self._zero_group_size()
-        idx = self._zero_group_index()
+        n = lax.axis_size(self.zero_axis)
+        idx = lax.axis_index(self.zero_axis)
 
         def chunk(p, st, sh):
             if dtype is not None:
@@ -396,24 +329,17 @@ class MixedPrecisionOptimizer:
         and every ``reduce_dtype=None`` trace — is bit-identical to the
         unquantized path). Must run inside shard_map (or an axis_env
         trace) binding the zero axis, like :meth:`init`."""
-        if self.reduce_dtype is None and self.dcn_wire is None:
+        if self.reduce_dtype is None:
             return None
         from apex_tpu.optimizers.distributed import chunk_size
 
-        n = self._zero_group_size()
-        # the residual covers the QUANTIZED wire only: the flat quantized
-        # reduce sends to all n ranks; the hierarchical form quantizes
-        # just the DCN hop, whose payload is n_dcn chunks (the island-
-        # reduced rows) — 1/n_ici the flat residual's bytes
-        wire_ranks = (lax.axis_size(self.dcn_axis)
-                      if self.dcn_wire is not None else n)
+        n = lax.axis_size(self.zero_axis)
         # zero-axis-SHARDED leaves (MoE experts) have no reduce wire —
         # their grads never leave the rank — so they carry an EMPTY
         # residual leaf (structure preserved, zero bytes)
         err = jax.tree.map(
             lambda p, sh: jnp.zeros(
-                (0,) if sh else (chunk_size(p.size, n) * wire_ranks,),
-                jnp.float32),
+                (0,) if sh else (chunk_size(p.size, n) * n,), jnp.float32),
             model_params, self._sharded_tree(model_params))
         residual = {"err": err}
         if self.stochastic_rounding:
@@ -505,12 +431,10 @@ class MixedPrecisionOptimizer:
                 from apex_tpu.parallel import collectives as _coll
 
                 # each rank unscaled a DIFFERENT local grad: the skip
-                # decision must agree along the shard axis (the whole
-                # two-tier group when dcn_axis is set) or the chunks diverge
+                # decision must agree along the shard axis or the chunks
+                # diverge
                 found_inf = _coll.pmax(
-                    found_inf.astype(jnp.float32),
-                    self._zero_group() if self.dcn_axis is not None
-                    else self.zero_axis) > 0
+                    found_inf.astype(jnp.float32), self.zero_axis) > 0
             if found_inf_reducer is not None:
                 found_inf = found_inf_reducer(found_inf)
 
@@ -579,37 +503,10 @@ class MixedPrecisionOptimizer:
         from apex_tpu.optimizers.distributed import gather_leaf, scatter_chunk
 
         axis = self.zero_axis
-        n = self._zero_group_size()
+        n = lax.axis_size(axis)
         sharded = self._sharded_tree(grads32)
         new_residual = state.residual
-        if self.dcn_axis is not None:
-            # two-tier path (parallel/hierarchy.py): the scatter factors
-            # into intra-island psum_scatter -> ONE inter-island exchange
-            # of the 1/n_ici shard — exact fp32 when dcn_wire is None
-            # (bit-identical to the flat tuple-axis scatter), or the
-            # 1-byte quantized wire with the error-feedback residual
-            # telescoping across steps. Sharded (expert) leaves pass
-            # through with their empty residual, as on the flat path.
-            from apex_tpu.parallel.hierarchy import hier_scatter_chunk
-
-            dcn = self.dcn_axis
-            if self.dcn_wire is not None:
-                err_tree = state.residual["err"]
-                leaves, treedef = jax.tree.flatten(grads32)
-                err_leaves = treedef.flatten_up_to(err_tree)
-                sh_leaves = treedef.flatten_up_to(sharded)
-                pairs = [(g, e) if sh else hier_scatter_chunk(
-                    g, dcn, axis, wire_dtype=self.dcn_wire, residual=e)
-                    for g, e, sh in zip(leaves, err_leaves, sh_leaves)]
-                g_chunks = treedef.unflatten([c / n for c, _ in pairs])
-                new_residual = {"err": treedef.unflatten(
-                    [e for _, e in pairs])}
-            else:
-                g_chunks = jax.tree.map(
-                    lambda g, sh: (g if sh else hier_scatter_chunk(
-                        g, dcn, axis)[0]) / n,
-                    grads32, sharded)
-        elif self.reduce_dtype is not None:
+        if self.reduce_dtype is not None:
             # quantized reduce-scatter (parallel/quantize.py): encoded
             # all_to_all + fp32 decode-then-accumulate — SUM semantics
             # identical to scatter_chunk, 1 B/elem on the wire. The
@@ -660,32 +557,20 @@ class MixedPrecisionOptimizer:
             stepped_master = optax.apply_updates(state.master, updates)
             new_master = keep(stepped_master, state.master)
             new_inner = keep(stepped_inner, state.inner)
-        if self.reduce_dtype is not None or self.dcn_wire is not None:
+        if self.reduce_dtype is not None:
             new_residual = dict(
                 new_residual,
                 err=keep(new_residual["err"], state.residual["err"]))
 
         # all-gather the updated params; with gather_dtype the payload is
         # compressed on the wire, then stored back in each param's dtype.
-        # On the two-tier mesh the gather decomposes too: ONE 1/n_ici-
-        # sized inter-island hop, then the intra-island rebuild — same
-        # bits as the flat gather (the payload is cast exactly once).
         # Sharded (expert) leaves never gather: the stepped local master
         # IS the new local shard — just the dtype copy-out.
-        if self.dcn_axis is not None:
-            from apex_tpu.parallel.hierarchy import hier_gather_chunk
-
-            def _gather(c, p):
-                return hier_gather_chunk(
-                    c, p.shape, p.dtype, self.dcn_axis, axis,
-                    gather_dtype=self.gather_dtype)
-        else:
-            def _gather(c, p):
-                return gather_leaf(c, p.shape, p.dtype, axis,
-                                   gather_dtype=self.gather_dtype)
         with jax.named_scope("amp_cast"):
             new_model = jax.tree.map(
-                lambda c, p, sh: c.astype(p.dtype) if sh else _gather(c, p),
+                lambda c, p, sh: (c.astype(p.dtype) if sh else
+                                  gather_leaf(c, p.shape, p.dtype, axis,
+                                              gather_dtype=self.gather_dtype)),
                 new_master, model_params, sharded)
 
         with jax.named_scope("amp_scale_update"):
@@ -696,16 +581,16 @@ class MixedPrecisionOptimizer:
         }
         if self.log_grad_norm:
             # norm of the REDUCED gradient, from this rank's chunks: the
-            # per-leaf shard-psum (the whole zero group + the param's own
-            # sharded axes) reproduces tree_l2norm on the full tree under
-            # hybrid meshes too (chunk padding contributes exact zeros)
+            # per-leaf shard-psum (zero axis + the param's own sharded
+            # axes) reproduces tree_l2norm on the full tree under hybrid
+            # meshes too (chunk padding contributes exact zeros)
             metrics["grad_norm"] = jnp.sqrt(sharded_tree_sumsq(
-                g_chunks, self._zero_group(), self._zero_norm_axes))
+                g_chunks, axis, self._zero_norm_axes))
         if self.log_group_norms:
             from apex_tpu.monitor.diagnose import group_grad_norms
 
             metrics["grad_norm_by_group"] = group_grad_norms(
-                g_chunks, psum_axis=self._zero_group(),
+                g_chunks, psum_axis=axis,
                 extra_axes=self._zero_norm_axes)
         return (new_model,
                 MPOptState(new_inner, new_master, new_scaler, new_residual),
@@ -774,9 +659,6 @@ class MixedPrecisionOptimizer:
         if self.zero_axis is None:
             raise ValueError("zero_abstract_state requires zero_axis")
         n = mesh.shape[self.zero_axis]
-        if self.dcn_axis is not None:
-            # two-tier: chunks shard over the COMBINED (dcn, zero) group
-            n *= mesh.shape[self.dcn_axis]
         leaves, treedef = jax.tree.flatten(model_params)
         if param_specs is None:
             spec_leaves = [None] * len(leaves)
@@ -799,17 +681,6 @@ class MixedPrecisionOptimizer:
                 for ax in _spec_axis_names(entry):
                     if ax == self.zero_axis:
                         over_zero = True
-                    if self.dcn_axis is not None and ax in (
-                            self.zero_axis, self.dcn_axis):
-                        raise ValueError(
-                            f"param of shape {tuple(p.shape)} is sharded "
-                            f"over {ax!r}: the two-tier optimizer "
-                            f"(dcn_axis) requires every param replicated "
-                            f"over BOTH group axes — expert-axis-sharded "
-                            f"MoE params compose with the single-tier "
-                            f"zero_axis only (their grads never cross "
-                            f"the island boundary the hierarchical "
-                            f"reduction covers)")
                     shape[d] //= mesh.shape[ax]
             if over_zero:
                 if len(shape) < 2:
@@ -859,18 +730,14 @@ class MixedPrecisionOptimizer:
         chunks = treedef.unflatten(list(structs))
         scaler = _scaler_from_policy(self.policy, **self._scaler_kwargs)
         residual = None
-        if self.reduce_dtype is not None or self.dcn_wire is not None:
+        if self.reduce_dtype is not None:
             # error-feedback state: per-rank flat fp32 leaves in the chunk
-            # layout (one chunk per QUANTIZED-wire destination — all n for
-            # the flat reduce, n_dcn for the hierarchical DCN hop),
-            # mirroring _init_residual exactly; sharded (expert) leaves
-            # have no wire and carry an empty leaf
-            wire_ranks = (mesh.shape[self.dcn_axis]
-                          if self.dcn_wire is not None else n)
+            # layout (n chunks concatenated — this rank's send error per
+            # destination), mirroring _init_residual exactly; sharded
+            # (expert) leaves have no wire and carry an empty leaf
             residual = {"err": treedef.unflatten([
-                jax.ShapeDtypeStruct(
-                    (0,) if fl else (st.shape[0] * wire_ranks,),
-                    jnp.float32)
+                jax.ShapeDtypeStruct((0,) if fl else (st.shape[0] * n,),
+                                     jnp.float32)
                 for st, fl in zip(structs, flags)])}
             if self.stochastic_rounding:
                 residual["key"] = jax.ShapeDtypeStruct((2,), jnp.uint32)

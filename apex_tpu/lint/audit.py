@@ -21,13 +21,6 @@ CPU mesh:
                        dispatch wire) under ``value_and_grad`` at dp=8,
                        with the ``moe-dispatch`` tripwire armed
                        (ISSUE 15);
-- ``pod``            — the two-tier pod-scale ZeRO apply program
-                       (``MixedPrecisionOptimizer(zero_axis=...,
-                       dcn_axis=..., dcn_wire="int8")`` over a
-                       ``{"dcn": 2, "data": 4}`` island layout) with the
-                       ``flat-dcn-collective`` tripwire armed: every bulk
-                       collective touching the DCN tier must be a
-                       single-axis hierarchy stage (ISSUE 19);
 - ``serve_prefill``/``serve_decode`` — the serving engine's two
                        shape-stable jitted programs over the paged cache;
 - ``plan``           — the auto-parallelism planner's loop closed: a
@@ -326,38 +319,6 @@ def _build_moe():
     return jax.value_and_grad(loss_fn), (local,)
 
 
-def _build_pod():
-    """The two-tier pod apply program (ISSUE 19): the hierarchical ZeRO
-    step — ``MixedPrecisionOptimizer(zero_axis="data", dcn_axis="dcn",
-    dcn_wire="int8")``, chunk init + staged scatter + Adam update +
-    staged gather — traced mesh-free under ``axes={"dcn": 2, "data": 4}``
-    (tests/test_hierarchy.py's bit-match step). The ``flat-dcn-collective``
-    tripwire pins that every bulk collective touching the DCN tier is a
-    single-axis hierarchy stage; only the scalar overflow/scale
-    collectives may span both tiers in one primitive."""
-    import jax.numpy as jnp
-
-    from apex_tpu import amp
-    from apex_tpu.optimizers import FusedAdam
-
-    mp = amp.MixedPrecisionOptimizer(
-        FusedAdam(lr=1e-3), amp.get_policy("O2"), zero_axis="data",
-        dcn_axis="dcn", dcn_wire="int8")
-    params = {"w": jnp.zeros((64, 64), jnp.float32),
-              "b": jnp.zeros((256,), jnp.float32)}
-
-    def step(p, gw, gb):
-        st = mp.init(p)
-        # scaled grads: each rank's own slice (leading dim sharded)
-        g = {"w": gw[0] * st.scaler.loss_scale,
-             "b": gb[0] * st.scaler.loss_scale}
-        new_p, _new_st, metrics = mp.apply_gradients(st, p, g)
-        return new_p, metrics["loss_scale"]
-
-    return step, (params, jnp.zeros((1, 64, 64), jnp.float32),
-                  jnp.zeros((1, 256), jnp.float32))
-
-
 def _build_plan():
     """The planner's loop closed: search the tiny spec under a ZeRO-3
     constraint (every other knob free), then build the winner's claimed
@@ -410,7 +371,7 @@ def run_audit(programs: Optional[Iterable[str]] = None,
     from apex_tpu.lint import trace as lint_trace
 
     known = {"dense", "zero", "zero3_prefetch", "zerobubble", "moe",
-             "pod", "serve_prefill", "serve_decode", "plan"}
+             "serve_prefill", "serve_decode", "plan"}
     wanted = set(programs) if programs else None
     if wanted is not None and wanted - known:
         # a typo'd CI subset must never audit 0 programs and exit green
@@ -469,19 +430,6 @@ def run_audit(programs: Optional[Iterable[str]] = None,
             tripwires=[
                 ("moe-dispatch", lambda ir: lint_trace.moe_dispatch_hazards(
                     ir, expert_axis="data", wire_dtype="int8")),
-            ]))
-    if want("pod"):
-        fn, args = _build_pod()
-        record("pod", audit_step_program(
-            fn, *args, label="pod", axes={"dcn": 2, "data": 4},
-            options=opts,
-            tripwires=[
-                # the staged DCN hops carry 1/n_ici of the 4096-elem w
-                # leaf by construction — 1024 keeps them in the bulk
-                # census (a flat regression of any chunk stage flags)
-                ("flat-dcn-collective", lambda ir: lint_trace.
-                 flat_dcn_collective_hazards(ir, dcn_axis="dcn",
-                                             min_bulk_elems=1024)),
             ]))
     if want("plan"):
         step = _build_plan()
@@ -595,7 +543,7 @@ def main(argv=None) -> int:
                     "programs (one JSON verdict line; exit 0 iff clean)")
     p.add_argument("--programs", type=str, default=None,
                    help="comma-separated subset (dense,zero,"
-                        "zero3_prefetch,zerobubble,moe,pod,serve_prefill,"
+                        "zero3_prefetch,zerobubble,moe,serve_prefill,"
                         "serve_decode,plan)")
     p.add_argument("--hbm-check", action="store_true",
                    help="add the 110M-class static-vs-monitor.hbm "

@@ -131,15 +131,16 @@ class EqnNode:
 
 def eqn_source(eqn) -> Optional[Tuple[str, int]]:
     """Lazy source provenance of one equation (user frame file:line)."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        fr = source_info_util.user_frame(eqn.source_info)
-        if fr is None:
-            return None
-        return (str(fr.file_name), int(fr.start_line))
-    except Exception:  # noqa: BLE001 - provenance is best-effort
+    try:
+        traceback = eqn.source_info.traceback
+    except AttributeError:  # an equation built with no source info
         return None
+    fr = source_info_util.user_frame(traceback)
+    if fr is None:
+        return None
+    return (str(fr.file_name), int(fr.start_line))
 
 
 def _shard_map_axis_sizes(eqn) -> Dict[str, int]:

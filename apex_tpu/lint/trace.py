@@ -31,14 +31,6 @@ on chip (PERF_NOTES.md, CLAUDE.md gotchas):
   (``MixedPrecisionOptimizer(zero_axis=...)``): the optimizer's
   psum_scatter IS that reduction, so the surviving all-reduce silently
   double-counts the averaging; same tripwire shape as ``sp-regression``.
-- ``flat-dcn-collective`` (:func:`flat_dcn_collective_hazards`) -- a
-  BULK collective binding a DCN-tier axis TOGETHER with another mesh
-  axis in one primitive: the flat tuple-axis group ships the FULL
-  payload across the slow inter-island tier, where the hierarchical
-  decomposition (parallel/hierarchy.py: intra-island reduce -> one
-  1/n_ici inter-island exchange -> intra-island broadcast) keeps all
-  but the pre-reduced shard on ICI. Scalar collectives over the joined
-  axes (the global loss pmean, found_inf pmax) are exempt.
 - ``zero3-bulk-gather`` (:func:`zero3_gather_hazards`) -- a MODEL-SIZED
   ``all_gather`` result on the zero axis in a fully-sharded (ZeRO-3) step:
   params must stay 1/n chunks gathered just-in-time per layer
@@ -492,100 +484,6 @@ def zero_redundancy_hazards(fn, *args,
         "hazard": bool(n_psum),
         "census": census,
         "bulk_psums": n_psum,
-        "findings": findings,
-    }
-
-
-# ---------------------------------------------------------------------------
-# flat-DCN collective tripwire (ISSUE 19)
-# ---------------------------------------------------------------------------
-
-
-def flat_dcn_census(jaxpr, dcn_axis: str = "dcn",
-                    min_bulk_elems: int = 1 << 12) -> Dict[str, Any]:
-    """Count collectives carrying ``dcn_axis`` in a jaxpr, split into FLAT
-    traffic (a bulk primitive binding the DCN axis jointly with at least
-    one other axis — the tuple-axis group that moves the full payload
-    across the slow tier), STAGED traffic (bulk primitives binding the
-    DCN axis ALONE — the inter-island hop of a hierarchical
-    decomposition, already pre-reduced to 1/n_ici), and the rest (scalar
-    payloads: the global loss pmean and found_inf pmax legitimately span
-    both tiers in one primitive — 4 bytes cross the DCN either way)."""
-    flat: Counter = Counter()
-    staged: Counter = Counter()
-    other: Counter = Counter()
-    for eqn in iter_eqns(jaxpr):
-        name = eqn.primitive.name
-        if name not in ("psum", "pmean", "pmax", "pmin", "all_gather",
-                        "reduce_scatter", "all_to_all"):
-            continue
-        names = _eqn_axis_names(eqn)
-        if dcn_axis not in names:
-            continue
-        sizes = [int(getattr(_aval_of(v), "size", 0) or 0)
-                 for v in list(eqn.invars) + list(eqn.outvars)
-                 if _aval_of(v) is not None]
-        if not sizes or max(sizes) < min_bulk_elems:
-            other[name] += 1
-        elif len(names) >= 2:
-            flat[name] += 1
-        else:
-            staged[name] += 1
-    return {"flat": dict(flat), "staged": dict(staged),
-            "other": dict(other)}
-
-
-def flat_dcn_collective_hazards(fn, *args,
-                                dcn_axis: str = "dcn",
-                                axes: Optional[Dict[str, int]] = None,
-                                min_bulk_elems: int = 1 << 12,
-                                **kwargs) -> Dict[str, Any]:
-    """Verify a two-tier (pod-scale) step staged its DCN-spanning bulk
-    collectives hierarchically.
-
-    Traces ``fn(*args)`` under ``axes`` (name -> size bindings, e.g.
-    ``{"dcn": 2, "data": 4}``; omit when ``fn`` binds its own axes via
-    shard_map) and censuses collectives carrying ``dcn_axis``. A BULK
-    primitive (>= ``min_bulk_elems`` elements in any operand or result)
-    that binds the DCN axis TOGETHER with another mesh axis is a finding:
-    lax runs the tuple-axis group as one flat collective, so the full
-    payload crosses the inter-island DCN links — the hierarchical
-    decomposition (``parallel/hierarchy.py``: intra-island reduce on the
-    ICI axis, ONE 1/n_ici-sized exchange on the DCN axis, intra-island
-    broadcast) exists so the slow tier only ever carries the pre-reduced
-    shard. Each hierarchy stage binds a single axis, so staged programs
-    land in ``census["staged"]`` and pass. Scalar collectives over the
-    joined axes (global loss pmean, found_inf pmax) are exempt under
-    ``census["other"]`` — 4 bytes cross the DCN either way.
-
-    Returns ``{hazard, census, flat_collectives, findings}`` — call-site
-    counts per trace, like :func:`zero_redundancy_hazards`.
-    """
-    jaxpr = _ir.trace_ir(fn, *args, axes=axes, **kwargs)
-    census = flat_dcn_census(
-        jaxpr, dcn_axis, min_bulk_elems=min_bulk_elems)
-    n_flat = sum(census["flat"].values())
-    findings = []
-    if n_flat:
-        verbs = ", ".join(f"{v} x{n}"
-                          for v, n in sorted(census["flat"].items()))
-        findings.append({
-            "rule": "flat-dcn-collective",
-            "message": (
-                f"step jaxpr carries {n_flat} bulk collective(s) binding "
-                f"the '{dcn_axis}' DCN axis jointly with another mesh "
-                f"axis ({verbs}) -- one flat tuple-axis group ships the "
-                f"FULL payload across the slow inter-island tier; stage "
-                f"it hierarchically (parallel/hierarchy.py: intra-island "
-                f"reduce, 1/n_ici inter-island exchange, intra-island "
-                f"broadcast), e.g. hier_psum/hier_scatter_chunk or "
-                f"MixedPrecisionOptimizer(dcn_axis=...)"),
-            "verb": "flat", "extra": n_flat,
-        })
-    return {
-        "hazard": bool(n_flat),
-        "census": census,
-        "flat_collectives": n_flat,
         "findings": findings,
     }
 

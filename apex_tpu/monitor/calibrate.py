@@ -30,8 +30,7 @@ Pure host-side stdlib (+ ``utils/io`` for the atomic write): no jax
 import, safe inside ``peak_spec`` on any platform.
 
 No reference-file citation: NVIDIA Apex has no cost-model layer; this
-is the calibration substrate ROADMAP items 2/3 (DCN tier model,
-auto-parallelism planner) read from.
+is the calibration substrate the auto-parallelism planner reads from.
 """
 
 from __future__ import annotations
@@ -45,10 +44,9 @@ ENV_CALIBRATION = "APEX_TPU_CALIBRATION"
 SCHEMA_VERSION = 1
 
 #: keys a calibration file may carry, all optional: peak FLOP/s, ICI
-#: bytes/s, DCN bytes/s and HBM bytes/s denominators (absolute units,
-#: not GB/s).
+#: bytes/s and HBM bytes/s denominators (absolute units, not GB/s).
 FITTED_KEYS = ("peak_flops", "peak_ici_bytes_per_sec",
-               "peak_dcn_bytes_per_sec", "peak_hbm_bytes_per_sec")
+               "peak_hbm_bytes_per_sec")
 
 # one-entry (path, mtime) cache: peak_spec may resolve once per journal
 # record arming; re-stat instead of re-parse when the file is unchanged
@@ -216,12 +214,6 @@ def fit(records: Sequence[Dict[str, Any]],
       bytes over the non-compute residual of the wall (clamped to at
       least ``min_comm_frac`` of the wall so a compute-saturated record
       can't fit an infinite wire).
-    - ``peak_dcn_bytes_per_sec``: the median achieved slow-tier wire
-      bytes/s on two-tier pod runs — ``predicted.dcn_bytes_per_step``
-      (the CommAccount DCN-tier census, parallel/hierarchy.py) over the
-      measured exposed DCN seconds (``timeline.tiers.dcn_s`` p50, armed
-      by ``journal.set_step_comm(dcn_bytes_per_step=...)``). An armed
-      calibration file feeds this straight into ``tracing.dcn_spec``.
     - ``peak_hbm_bytes_per_sec``: the median achieved bytes/s when
       records carry ``predicted.bytes_per_step`` (jaxpr operand+result
       totals — a pre-fusion upper bound, flagged by the journal's
@@ -232,7 +224,6 @@ def fit(records: Sequence[Dict[str, Any]],
     """
     ach_flops: List[float] = []
     ach_ici: List[float] = []
-    ach_dcn: List[float] = []
     ach_hbm: List[float] = []
     for rec in records:
         if rec.get("kind") != "run":
@@ -261,15 +252,6 @@ def fit(records: Sequence[Dict[str, Any]],
                 compute_s = flops / max(ach_flops[-1], 1e-30)
                 residual = max(wall - compute_s, min_comm_frac * wall)
             ach_ici.append(comm / residual)
-        # slow-tier wire: predicted DCN bytes over the MEASURED exposed
-        # DCN seconds (the per-link-class anatomy stamp) — the direct
-        # achieved-bandwidth read, no residual attribution needed
-        dcn_bytes = predicted.get("dcn_bytes_per_step")
-        dcn_s = (((measured.get("timeline") or {}).get("tiers") or {})
-                 .get("dcn_s") or {}).get("p50")
-        if isinstance(dcn_bytes, (int, float)) and dcn_bytes > 0 \
-                and isinstance(dcn_s, (int, float)) and dcn_s > 0:
-            ach_dcn.append(dcn_bytes / dcn_s)
     out: Dict[str, Any] = {"source": "calibrated",
                            "n_records": {}}
     f = _median(ach_flops)
@@ -280,10 +262,6 @@ def fit(records: Sequence[Dict[str, Any]],
     if i is not None:
         out["peak_ici_bytes_per_sec"] = round(i, 1)
         out["n_records"]["peak_ici_bytes_per_sec"] = len(ach_ici)
-    d = _median(ach_dcn)
-    if d is not None:
-        out["peak_dcn_bytes_per_sec"] = round(d, 1)
-        out["n_records"]["peak_dcn_bytes_per_sec"] = len(ach_dcn)
     h = _median(ach_hbm)
     if h is not None:
         out["peak_hbm_bytes_per_sec"] = round(h, 1)

@@ -31,26 +31,6 @@ AxisNames = Union[str, Tuple[str, ...]]
 # single-threaded per process; nested contexts both observe a call.
 _ACTIVE: List["CommAccount"] = []
 
-#: mesh axis names modeled as the slow inter-host DCN tier (the two-tier
-#: topology of parallel/hierarchy.py). Everything else is ICI. A payload
-#: whose axis label CONTAINS a DCN axis — including a "+"-joined tuple
-#: label like "dcn+data", the flat-collective-spanning-tiers hazard — is
-#: booked on the DCN tier: its wire crosses the slow links.
-DCN_AXES = {"dcn"}
-
-
-def register_dcn_axis(name: str) -> None:
-    """Tag a mesh axis as riding the DCN tier (parallel/hierarchy.py's
-    island axis registers itself; custom pod layouts add their own)."""
-    DCN_AXES.add(str(name))
-
-
-def axis_tier(label: AxisNames) -> str:
-    """``"dcn"`` if any component of the (possibly "+"-joined) axis label
-    is a registered DCN axis, else ``"ici"``."""
-    parts = _axis_label(label).split("+")
-    return "dcn" if any(p in DCN_AXES for p in parts) else "ici"
-
 
 def _axis_label(axis: AxisNames) -> str:
     if isinstance(axis, (tuple, list)):
@@ -129,28 +109,13 @@ class CommAccount:
             row["calls"] += 1
         return out
 
-    def by_tier(self) -> Dict[str, Dict[str, int]]:
-        """``{"ici"|"dcn": {"bytes", "calls"}}`` — the link-class rollup
-        of the two-tier topology (parallel/hierarchy.py): every record
-        whose axis label touches a registered DCN axis books on the slow
-        tier. The per-tier wire-byte claims of the pod evidence read
-        straight off this table."""
-        out: Dict[str, Dict[str, int]] = {}
-        for r in self.records:
-            row = out.setdefault(axis_tier(r["axis"]),
-                                 {"bytes": 0, "calls": 0})
-            row["bytes"] += r["bytes"]
-            row["calls"] += 1
-        return out
-
     def total_bytes(self) -> int:
         return sum(r["bytes"] for r in self.records)
 
     def summary(self) -> Dict[str, Any]:
         return {"total_bytes": self.total_bytes(),
                 "by_axis": self.by_axis(), "by_verb": self.by_verb(),
-                "by_verb_dtype": self.by_verb_dtype(),
-                "by_tier": self.by_tier()}
+                "by_verb_dtype": self.by_verb_dtype()}
 
 
 @contextlib.contextmanager

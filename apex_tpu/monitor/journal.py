@@ -191,8 +191,7 @@ class MetricsJournal:
 
     # -- step-anatomy arming (monitor/tracing.py) ---------------------------
     def set_step_comm(self, comm_bytes_per_step: float,
-                      *, dcn_bytes_per_step: float = 0.0,
-                      platform: Optional[str] = None) -> None:
+                      *, platform: Optional[str] = None) -> None:
         """Arm per-record step-anatomy fields: once set, every
         :meth:`step_end` record with a wall time also carries
         ``compute_frac``/``comm_frac``/``stall_frac`` (summing to 1.0)
@@ -200,21 +199,11 @@ class MetricsJournal:
         step_anatomy`` from this per-step collective payload total
         (``monitor.comms`` accounting of the step trace), the armed
         step costs (:meth:`set_step_costs`) and the ICI bandwidth table
-        (``APEX_TPU_PEAK_ICI_GBPS``-calibratable). Host-side only.
-
-        On a two-tier pod mesh pass the slow-tier payload separately as
-        ``dcn_bytes_per_step`` (``CommAccount.by_tier()['dcn']``): step
-        records then also carry the per-link-class split ``ici_s`` /
-        ``dcn_s`` (priced via ``tracing.dcn_spec`` —
-        ``APEX_TPU_PEAK_DCN_GBPS``-calibratable) that ``report``'s tiers
-        section and ``report compare --dcn-threshold`` consume."""
+        (``APEX_TPU_PEAK_ICI_GBPS``-calibratable). Host-side only."""
         from apex_tpu.monitor import tracing as _tracing  # lazy: stay light
 
         self._step_comm = {"bytes": float(comm_bytes_per_step),
                            "ici": _tracing.ici_spec(platform)}
-        if dcn_bytes_per_step:
-            self._step_comm["dcn_bytes"] = float(dcn_bytes_per_step)
-            self._step_comm["dcn"] = _tracing.dcn_spec(platform)
 
     def set_bubble_fraction(self, measured: float,
                             expected: Optional[float] = None) -> None:
@@ -393,12 +382,10 @@ class MetricsJournal:
                 an = _tracing.step_anatomy(
                     wall_s=wall_s, flops=flops, spec=spec,
                     comm_bytes=self._step_comm["bytes"],
-                    ici=self._step_comm["ici"],
-                    dcn_bytes=self._step_comm.get("dcn_bytes"),
-                    dcn=self._step_comm.get("dcn"))
+                    ici=self._step_comm["ici"])
                 for k in ("compute_s", "comm_s",
                           "compute_frac", "comm_frac", "stall_frac",
-                          "overlap_fraction", "ici_s", "dcn_s"):
+                          "overlap_fraction"):
                     if k in an:
                         rec[k] = an[k]
             except Exception:  # noqa: BLE001 - telemetry must not raise

@@ -320,17 +320,6 @@ def analyze(
                 if isinstance(r.get(key), (int, float))]
         if vals:
             tl[f"{key}_mean"] = round(sum(vals) / len(vals), 4)
-    # per-link-class exposed comm (two-tier pod meshes: set_step_comm's
-    # dcn_bytes_per_step arms ici_s/dcn_s stamps on every step record) —
-    # `report compare --dcn-threshold` gates the dcn_s_p50 column
-    tiers: Dict[str, Any] = {}
-    for key in ("ici_s", "dcn_s"):
-        vals = [r[key] for r in steps
-                if isinstance(r.get(key), (int, float))]
-        if vals:
-            tiers[key] = _dist(vals)
-    if tiers:
-        tl["tiers"] = tiers
     if tl:
         out["timeline"] = tl
 
@@ -577,11 +566,6 @@ def render(analysis: Dict[str, Any], file=None) -> None:
               if k in tl]
         if fr:
             parts.append("anatomy " + "/".join(fr))
-        tiers = tl.get("tiers") or {}
-        if tiers:
-            parts.append("exposed comm " + " ".join(
-                f"{k[:-2]} p50 {tiers[k].get('p50')}s"
-                for k in ("ici_s", "dcn_s") if k in tiers))
         p("timeline: " + "; ".join(parts))
     osb = analysis.get("opt_state_bytes")
     if osb:
@@ -687,7 +671,6 @@ def compare(
     loss_threshold: Optional[float] = None,
     bubble_threshold: Optional[float] = None,
     overlap_threshold: Optional[float] = None,
-    dcn_threshold: Optional[float] = None,
     max_alerts: Optional[int] = None,
 ) -> Dict[str, Any]:
     """Compare run B against baseline A; ``regressed`` iff B is worse.
@@ -719,13 +702,6 @@ def compare(
     ZeRO-3 double-buffered gathers whose win IS the overlap fraction,
     ``models/_transformer._prefetched_zero3_drive``), sharing the same
     :func:`must_not_drop` predicate as throughput.
-
-    ``dcn_threshold`` tunes the per-tier exposed-comm gate (defaults to
-    ``threshold`` when journals carry ``dcn_s`` stamps — two-tier pod
-    journals armed via ``set_step_comm(dcn_bytes_per_step=...)``): B's
-    exposed DCN seconds p50 must not GROW past it (+1 ms slack) — the
-    machine gate for hierarchical-collective work
-    (``parallel/hierarchy.py``), sharing :func:`must_not_grow`.
 
     Serving journals (``kind="request"`` records from ``apex_tpu.serve``)
     gate symmetrically: B must still serve requests when A did, TTFT/ITL
@@ -851,19 +827,6 @@ def compare(
           ((rb.get("timeline") or {}).get("overlap_fraction") or {}).get("p50"),
           worse=must_not_drop(
               threshold if overlap_threshold is None else overlap_threshold))
-    # per-tier exposed comm (two-tier pod meshes, set_step_comm's
-    # dcn_bytes_per_step arm): the DCN leg is the scarce wire — a
-    # candidate whose exposed dcn_s GROWS past the tolerance regressed
-    # the hierarchical decomposition (e.g. a flat cross-island reduce
-    # slipped back in). 1 ms absolute slack for timer noise.
-    check("dcn_s_p50",
-          (((ra.get("timeline") or {}).get("tiers") or {})
-           .get("dcn_s") or {}).get("p50"),
-          (((rb.get("timeline") or {}).get("tiers") or {})
-           .get("dcn_s") or {}).get("p50"),
-          worse=must_not_grow(
-              threshold if dcn_threshold is None else dcn_threshold,
-              slack=0.001))
     # serving latency gates (kind="request" journals from the serve
     # engine): TTFT/ITL p50 must not GROW past the threshold — the same
     # machine gate training throughput gets, pointed at the latency-shaped
@@ -991,11 +954,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                             "overlap fraction (defaults to --threshold "
                             "when journals carry overlap_fraction stamps "
                             "— the structural-prefetch gate)")
-        p.add_argument("--dcn-threshold", type=float, default=None,
-                       help="max fractional GROWTH in exposed DCN comm "
-                            "seconds p50 (defaults to --threshold when "
-                            "journals carry dcn_s stamps — the two-tier "
-                            "pod hierarchical-collective gate)")
         p.add_argument("--max-alerts", type=int, default=None,
                        help="arm the health-alert gate: the candidate's "
                             "derived alert count (monitor/health.py rules "
@@ -1014,7 +972,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                       loss_threshold=args.loss_threshold,
                       bubble_threshold=args.bubble_threshold,
                       overlap_threshold=args.overlap_threshold,
-                      dcn_threshold=args.dcn_threshold,
                       max_alerts=args.max_alerts)
         if args.json or args.format == "json":
             print(json.dumps(res))

@@ -56,16 +56,6 @@ that the monitor pieces stay importable and functional:
    walker and the verdict is clean — same contract as
    ``python -m apex_tpu.lint.audit`` over the full program set;
 
-8c. pod (ISSUE 19): the two-tier wire layer — ``tracing.dcn_spec``
-   resolves the modeled DCN row (env-overridable), ``step_anatomy``
-   splits exposed comm into ``ici_s``/``dcn_s`` without moving the
-   fraction invariant, the ``flat-dcn-collective`` trace analyzer flags
-   a bulk collective binding the DCN axis jointly with another axis
-   while the hierarchical single-axis stages (``parallel/hierarchy.py``)
-   and scalar loss/overflow collectives pass, and the ``pod`` canonical
-   audit program (the hierarchical ZeRO apply with the int8 DCN wire)
-   audits clean;
-
 9. tracing: nested spans round-trip with depths and strict-JSON
    non-finite handling; a torn trace file still parses; the analytic
    bubble floors and the step-anatomy fraction invariant (compute +
@@ -1068,75 +1058,6 @@ def _check_plan() -> dict:
             "winner_zero": result["winner"]["candidate"]["zero_level"]}
 
 
-def _check_pod() -> dict:
-    """Pod-scale two-tier wire (ISSUE 19): the modeled DCN row resolves
-    (and honors its env override), step-anatomy splits exposed comm per
-    link class without moving the fraction invariant, the flat-DCN
-    tripwire flags the tuple-axis bulk collective while the hierarchical
-    stages and scalar collectives pass, and the ``pod`` canonical audit
-    program (hierarchical ZeRO apply, int8 DCN wire) audits clean."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    from apex_tpu.lint import audit as lint_audit
-    from apex_tpu.lint import trace as lint_trace
-    from apex_tpu.monitor import tracing
-    from apex_tpu.parallel import hierarchy
-
-    # the modeled DCN row: table-resolved, env-overridable
-    saved = os.environ.pop(tracing.ENV_PEAK_DCN_GBPS, None)
-    try:
-        spec = tracing.dcn_spec("tpu v4")
-        assert spec["dcn_bytes_per_sec"] > 0, spec
-        assert spec["source"].startswith("table"), spec
-        os.environ[tracing.ENV_PEAK_DCN_GBPS] = "2"
-        over = tracing.dcn_spec("tpu v4")
-        assert over["dcn_bytes_per_sec"] == 2e9, over
-        assert "env" in over["source"], over
-    finally:
-        os.environ.pop(tracing.ENV_PEAK_DCN_GBPS, None)
-        if saved is not None:
-            os.environ[tracing.ENV_PEAK_DCN_GBPS] = saved
-
-    # tiered anatomy: ici_s + dcn_s == exposed comm, invariant unmoved
-    an = tracing.step_anatomy(
-        wall_s=0.1, flops=1e6, comm_bytes=5e8, dcn_bytes=5e8,
-        spec={"peak_flops": 1e12, "peak_hbm_bytes_per_sec": 1e12,
-              "source": "test"},
-        ici={"ici_bytes_per_sec": 1e10, "source": "test"},
-        dcn={"dcn_bytes_per_sec": 1e9, "source": "test"})
-    assert abs(an["ici_s"] + an["dcn_s"]
-               - an["exposed_comm_s"]) < 1e-9, an
-    assert an["dcn_s"] > an["ici_s"], an  # the slow tier dominates
-    assert abs(an["compute_frac"] + an["comm_frac"]
-               + an["stall_frac"] - 1.0) < 1e-6, an
-
-    # the flat-DCN tripwire: one tuple-axis bulk collective ships the
-    # full payload across the slow tier; the hierarchical single-axis
-    # stages pass, the scalar loss/overflow collectives are exempt
-    big = jnp.ones((256, 64), jnp.float32)
-    axes = {"dcn": 2, "data": 4}
-    flat = lint_trace.flat_dcn_collective_hazards(
-        lambda g: lax.psum(g, ("dcn", "data"))
-        + lax.pmax(jnp.sum(g), ("dcn", "data")), big, axes=axes)
-    assert flat["hazard"] and flat["flat_collectives"] == 1, flat
-    assert flat["findings"][0]["rule"] == "flat-dcn-collective", flat
-    assert flat["census"]["other"].get("pmax") == 1, flat
-    staged = lint_trace.flat_dcn_collective_hazards(
-        lambda g: hierarchy.hier_psum(g, "dcn", "data"), big, axes=axes)
-    assert not staged["hazard"], staged
-    assert staged["census"]["staged"], staged
-
-    # the canonical pod program (hierarchical ZeRO apply, int8 DCN wire)
-    verdict = lint_audit.run_audit(programs=("pod",))
-    assert verdict["all_ok"], verdict
-    trip = verdict["programs"]["pod"]["tripwires"]["flat-dcn-collective"]
-    assert not trip["hazard"], trip
-    return {"ok": True, "dcn_source": spec["source"],
-            "dcn_s": an["dcn_s"],
-            "flat_rule": flat["findings"][0]["rule"]}
-
-
 def run() -> dict:
     """In-process smoke (no platform mutation — safe under any backend)."""
     results = {}
@@ -1153,7 +1074,6 @@ def run() -> dict:
                      ("lint", _check_lint),
                      ("audit", _check_audit),
                      ("plan", _check_plan),
-                     ("pod", _check_pod),
                      ("tracing", _check_tracing),
                      ("serve", _check_serve),
                      ("reqtrace", _check_reqtrace)):

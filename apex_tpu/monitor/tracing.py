@@ -58,7 +58,6 @@ from apex_tpu.monitor.journal import (
 
 ENV_TRACE = "APEX_TPU_TRACE"
 ENV_PEAK_ICI_GBPS = "APEX_TPU_PEAK_ICI_GBPS"
-ENV_PEAK_DCN_GBPS = "APEX_TPU_PEAK_DCN_GBPS"
 
 #: platform substring -> aggregate per-chip ICI bytes/s (public datasheet
 #: interconnect numbers, decimal GB/s; same matching rule as
@@ -76,28 +75,6 @@ ICI_SPECS = {
     "cpu": 10e9,
 }
 _ICI_FALLBACK = 300e9  # v4-class, flagged source="fallback"
-
-#: platform substring -> per-chip DCN (inter-island / inter-host network)
-#: bytes/s — the SLOW tier of a two-tier pod mesh
-#: (``parallel/hierarchy.py``). Order-of-magnitude datasheet numbers
-#: (per-host NICs divided across the host's chips); the point of the row
-#: is the RATIO to ``ICI_SPECS`` — one to two orders of magnitude —
-#: which is what makes the hierarchical decomposition and the int8 DCN
-#: wire price in (EQuARX's deployment regime). Same calibration
-#: precedence as the ICI row: env ``APEX_TPU_PEAK_DCN_GBPS``, outranked
-#: by an armed ``APEX_TPU_CALIBRATION`` file.
-DCN_SPECS = {
-    "v6e": 3.125e9,   # 200 Gb/s host NIC / 8 chips
-    "v6": 3.125e9,
-    "v5p": 6.25e9,    # 200 Gb/s / 4 chips
-    "v5e": 1.5625e9,  # 100 Gb/s / 8 chips
-    "v5 lite": 1.5625e9,
-    "v4": 3.125e9,    # 100 Gb/s / 4 chips
-    "v3": 3.125e9,
-    "v2": 1.5625e9,
-    "cpu": 1e9,
-}
-_DCN_FALLBACK = 3.125e9  # 100 Gb/s NIC / 4 chips, flagged source="fallback"
 
 #: schedules with known analytic bubble floors (ROADMAP item 5's menu)
 SCHEDULES = ("gpipe", "1f1b", "interleaved", "zero-bubble")
@@ -507,42 +484,6 @@ def ici_spec(platform: Optional[str] = None) -> Dict[str, Any]:
     return {"platform": plat, "ici_bytes_per_sec": bw, "source": source}
 
 
-def dcn_spec(platform: Optional[str] = None) -> Dict[str, Any]:
-    """Resolve ``{platform, dcn_bytes_per_sec, source}`` — the slow-tier
-    wire-speed denominator for inter-island (DCN) comm seconds on a
-    two-tier pod mesh. Mirror of :func:`ici_spec` with its own table
-    (``DCN_SPECS``), env knob (``APEX_TPU_PEAK_DCN_GBPS``, decimal GB/s)
-    and calibration key (``peak_dcn_bytes_per_sec``) — an armed
-    ``APEX_TPU_CALIBRATION`` file outranks the env, same precedence."""
-    from apex_tpu.monitor import mfu as _mfu
-
-    plat = (platform or _mfu._detect_platform()).lower()
-    bw, source = None, None
-    for key, b in DCN_SPECS.items():
-        if key in plat:
-            bw, source = b, f"table:{key}"
-            break
-    if bw is None:
-        bw, source = _DCN_FALLBACK, "fallback"
-    try:
-        env = os.environ.get(ENV_PEAK_DCN_GBPS)
-        if env:
-            bw, source = float(env) * 1e9, "env"
-    except ValueError:
-        pass  # malformed override: keep the table row
-    try:
-        from apex_tpu.monitor import calibrate as _calibrate
-
-        cal = _calibrate.active()
-    except Exception:  # noqa: BLE001 - calibration is best-effort
-        cal = None
-    if cal:
-        cd = cal.get("peak_dcn_bytes_per_sec")
-        if isinstance(cd, (int, float)) and cd > 0:
-            bw, source = float(cd), "calibrated"
-    return {"platform": plat, "dcn_bytes_per_sec": bw, "source": source}
-
-
 def modeled_step_seconds(
     *,
     flops: float,
@@ -550,10 +491,8 @@ def modeled_step_seconds(
     bubble_fraction: float = 0.0,
     hidden_comm_bytes: float = 0.0,
     overhead_s: float = 0.0,
-    dcn_bytes: float = 0.0,
     spec: Optional[Dict[str, Any]] = None,
     ici: Optional[Dict[str, Any]] = None,
-    dcn: Optional[Dict[str, Any]] = None,
     platform: Optional[str] = None,
 ) -> Dict[str, Any]:
     """Compose one modeled step time from the analytic legs — the
@@ -570,14 +509,6 @@ def modeled_step_seconds(
     16) calibrates every planner prediction with no extra wiring.
     Returns the decomposition, never just the total, so consumers can
     stamp ``compute_s``/``exposed_comm_s`` provenance.
-
-    ``dcn_bytes`` prices the SLOW tier of a two-tier pod mesh
-    (``parallel/hierarchy.py``): that payload divides by
-    :func:`dcn_spec`'s bandwidth instead and lands as its own
-    always-exposed leg (``dcn_comm_s`` — the inter-island exchange is
-    one blocking hop, outside the overlap budget). ``comm_bytes`` stays
-    the ICI-tier payload; per-tier keys appear only when a DCN payload
-    is priced, so single-tier consumers are byte-identical.
     """
     from apex_tpu.monitor import mfu as _mfu
 
@@ -588,14 +519,9 @@ def modeled_step_seconds(
     comm_s = float(comm_bytes) / bw if bw > 0 else 0.0
     hidden_s = min(float(hidden_comm_bytes) / bw, compute_s) if bw > 0 else 0.0
     exposed_s = max(comm_s - hidden_s, 0.0)
-    dcn_s = 0.0
-    if dcn_bytes:
-        dcn = dcn or dcn_spec(platform)
-        dbw = dcn.get("dcn_bytes_per_sec") or 0.0
-        dcn_s = float(dcn_bytes) / dbw if dbw > 0 else 0.0
     bub = min(max(float(bubble_fraction), 0.0), 0.99)
-    step_s = compute_s / (1.0 - bub) + exposed_s + dcn_s + float(overhead_s)
-    out = {
+    step_s = compute_s / (1.0 - bub) + exposed_s + float(overhead_s)
+    return {
         "step_seconds": step_s,
         "compute_s": compute_s,
         "comm_s": comm_s,
@@ -606,10 +532,6 @@ def modeled_step_seconds(
         "peak_source": spec.get("source"),
         "ici_source": ici.get("source"),
     }
-    if dcn_bytes:
-        out["dcn_comm_s"] = dcn_s
-        out["dcn_source"] = dcn.get("source")
-    return out
 
 
 def overlap_fraction(wall_s: float, compute_s: float,
@@ -633,11 +555,8 @@ def step_anatomy(
     comm_s: Optional[float] = None,
     flops: Optional[float] = None,
     comm_bytes: Optional[float] = None,
-    dcn_s: Optional[float] = None,
-    dcn_bytes: Optional[float] = None,
     spec: Optional[Dict[str, Any]] = None,
     ici: Optional[Dict[str, Any]] = None,
-    dcn: Optional[Dict[str, Any]] = None,
 ) -> Dict[str, Any]:
     """Decompose one measured step/window into compute vs exposed-comm vs
     host-stall seconds.
@@ -650,14 +569,7 @@ def step_anatomy(
     ``compute_frac + comm_frac + stall_frac == 1.0`` per window by
     construction (tests pin the invariant), and ``overlap_fraction``
     reports how much of the cheaper component hid under the other.
-
-    On a two-tier pod mesh, ``dcn_s`` (measured) or ``dcn_bytes``
-    (modeled via :func:`dcn_spec`) adds the slow-tier leg: total comm
-    becomes ICI + DCN, and the output gains ``ici_s``/``dcn_s`` — the
-    per-LINK-CLASS comm seconds — with the exposed time split
-    pro-rata, so ``report`` can attribute exposed comm per tier. The
-    fraction invariant is unchanged. Single-tier calls (no dcn args)
-    are byte-identical to before."""
+    """
     out: Dict[str, Any] = {"wall_s": round(wall_s, 6)}
     if wall_s <= 0:
         return out
@@ -671,14 +583,6 @@ def step_anatomy(
         ici = ici or ici_spec()
         comm_s = float(comm_bytes) / float(ici["ici_bytes_per_sec"])
         out["comm_source"] = f"wire_model/{ici['source']}"
-    if dcn_s is None and dcn_bytes is not None:
-        dcn = dcn or dcn_spec()
-        dcn_s = float(dcn_bytes) / float(dcn["dcn_bytes_per_sec"])
-        out["dcn_source"] = f"wire_model/{dcn['source']}"
-    tiered = dcn_s is not None
-    ici_part = max(float(comm_s or 0.0), 0.0)
-    if tiered:
-        comm_s = ici_part + max(float(dcn_s), 0.0)
     compute_s = min(max(float(compute_s or 0.0), 0.0), wall_s)
     comm_s = min(max(float(comm_s or 0.0), 0.0), wall_s)
     lo = min(compute_s, comm_s)
@@ -694,12 +598,6 @@ def step_anatomy(
         "comm_frac": round(exposed_comm_s / wall_s, 4),
         "stall_frac": round(stall_s / wall_s, 4),
     })
-    if tiered:
-        # per-link-class attribution: the exposed seconds split in the
-        # tiers' modeled proportions (both tiers clip together above)
-        share = max(float(dcn_s), 0.0) / max(ici_part + float(dcn_s), 1e-30)
-        out["dcn_s"] = round(exposed_comm_s * share, 6)
-        out["ici_s"] = round(exposed_comm_s * (1.0 - share), 6)
     ov = overlap_fraction(wall_s, compute_s, comm_s)
     if ov is not None:
         out["overlap_fraction"] = ov
@@ -893,9 +791,8 @@ __all__ = [
     "arm", "disarm", "get_tracer", "armed", "scoped", "maybe_span",
     "fetch_barrier",
     "expected_bubble_fraction", "SCHEDULES",
-    "ici_spec", "dcn_spec", "overlap_fraction", "step_anatomy",
+    "ici_spec", "overlap_fraction", "step_anatomy",
     "pipeline_anatomy", "timeline_summary",
     "chrome_trace", "write_chrome_trace",
     "ENV_TRACE", "ENV_PEAK_ICI_GBPS", "ICI_SPECS",
-    "ENV_PEAK_DCN_GBPS", "DCN_SPECS",
 ]

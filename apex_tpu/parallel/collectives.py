@@ -1,4 +1,4 @@
-"""Named-axis collectives — the communication backend over ICI/DCN.
+"""Named-axis collectives — the communication backend over ICI.
 
 Reference: apex uses torch.distributed/NCCL process-group verbs —
 ``all_reduce`` (apex/parallel/distributed.py:449-451,
@@ -8,7 +8,7 @@ apex/transformer/tensor_parallel/mappings.py:31), ``broadcast``
 streams for comm/compute overlap (distributed.py:425-475). SURVEY.md §2.4.
 
 Here each verb is a thin, documented wrapper over the XLA collective that
-rides ICI/DCN: process groups become mesh axis names, streams/overlap become
+rides ICI: process groups become mesh axis names, streams/overlap become
 XLA's async-collective latency hiding, and point-to-point pipeline traffic
 becomes ``ppermute`` ring shifts. All of these are only meaningful inside a
 ``shard_map`` (or vmapped/pjitted context) that binds the axis name.
@@ -63,15 +63,7 @@ COMM_SCOPE_HELPERS = ("_comm", "collective_scope",
                       "quantized_psum_scatter",
                       "quantized_all_gather",
                       "quantized_gather_chunk",
-                      "quantized_all_to_all",
-                      # two-tier hierarchical collectives
-                      # (parallel/hierarchy.py): each hop runs under its
-                      # own comm: scope, booked per tier
-                      "hier_psum",
-                      "hier_pmean",
-                      "hier_scatter_chunk",
-                      "hier_gather_chunk",
-                      "hier_all_to_all")
+                      "quantized_all_to_all")
 
 # The jaxpr-level decomposition contract of sequence parallelism (read
 # statically by apex_tpu.lint.trace.sequence_parallel_hazards, like the

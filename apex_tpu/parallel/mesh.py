@@ -16,7 +16,7 @@ Topology contract preserved from the reference (parallel_state.py:119-184):
 - data-parallel ranks stride by tp_size within a pipeline block
   (``:119-131``) — the ``data`` axis varies next;
 - pipeline-parallel ranks stride widest (``:159-164``) — the ``pipe`` axis is
-  slowest-varying, matching PP's tolerance for higher-latency links (DCN);
+  slowest-varying, matching PP's tolerance for higher-latency links;
 - the ``context`` axis (sequence/ring parallelism — absent in the reference,
   SURVEY.md §2.3) sits between ``data`` and ``model`` so ring-attention
   ppermutes stay on fast links.
@@ -45,16 +45,9 @@ AXIS_PIPE = "pipe"
 AXIS_DATA = "data"
 AXIS_CONTEXT = "context"
 AXIS_MODEL = "model"
-#: the inter-island (multi-host) axis of the two-tier topology
-#: (parallel/hierarchy.py): slowest-varying of all, so island-mates stay
-#: contiguous on fast ICI links and only this axis crosses the modeled
-#: DCN tier. Present only when ``islands > 1`` is requested.
-AXIS_DCN = "dcn"
 
 #: Canonical axis order, slowest- to fastest-varying across the device list.
 MESH_AXIS_NAMES: Tuple[str, ...] = (AXIS_PIPE, AXIS_DATA, AXIS_CONTEXT, AXIS_MODEL)
-#: Axis order of a two-tier (island) mesh: ``dcn`` outermost.
-POD_AXIS_NAMES: Tuple[str, ...] = (AXIS_DCN,) + MESH_AXIS_NAMES
 
 
 @dataclasses.dataclass
@@ -77,7 +70,6 @@ def initialize_model_parallel(
     virtual_pipeline_model_parallel_size: Optional[int] = None,
     pipeline_model_parallel_split_rank: Optional[int] = None,
     context_parallel_size: int = 1,
-    islands: int = 1,
     devices: Optional[Sequence[jax.Device]] = None,
 ) -> Mesh:
     """Build and install the global mesh (parallel_state.py:57-184 equivalent).
@@ -95,12 +87,6 @@ def initialize_model_parallel(
         split sits, for T5-style models (reference ``:96-102,165-184``).
       context_parallel_size: size of the ``context`` (sequence) axis — a new
         capability relative to the reference.
-      islands: number of ICI islands (modeled hosts) — ``islands > 1``
-        prepends a ``dcn`` axis (slowest-varying, so island-mates stay
-        contiguous on fast links) carrying the inter-host tier of the
-        two-tier topology (parallel/hierarchy.py). The data-parallel
-        size is then the PER-ISLAND size: global data parallelism is
-        ``islands * dp``.
       devices: explicit device list; defaults to ``jax.devices()``.
 
     Returns:
@@ -109,15 +95,14 @@ def initialize_model_parallel(
     tp = int(tensor_model_parallel_size)
     pp = int(pipeline_model_parallel_size)
     cp = int(context_parallel_size)
-    isl = int(islands)
     devs = list(devices) if devices is not None else jax.devices()
     world_size = len(devs)
-    denom = tp * pp * cp * isl
+    denom = tp * pp * cp
     if world_size % denom != 0:
         raise RuntimeError(
             f"world size ({world_size}) is not divisible by tensor parallel "
             f"size ({tp}) x pipeline parallel size ({pp}) x context parallel "
-            f"size ({cp})" + (f" x islands ({isl})" if isl > 1 else "")
+            f"size ({cp})"
         )
     dp = world_size // denom
     if virtual_pipeline_model_parallel_size is not None and pp < 2:
@@ -126,12 +111,8 @@ def initialize_model_parallel(
             "interleaved schedule"
         )
 
-    if isl > 1:
-        grid = np.asarray(devs, dtype=object).reshape(isl, pp, dp, cp, tp)
-        mesh = Mesh(grid, POD_AXIS_NAMES)
-    else:
-        grid = np.asarray(devs, dtype=object).reshape(pp, dp, cp, tp)
-        mesh = Mesh(grid, MESH_AXIS_NAMES)
+    grid = np.asarray(devs, dtype=object).reshape(pp, dp, cp, tp)
+    mesh = Mesh(grid, MESH_AXIS_NAMES)
 
     _STATE.mesh = mesh
     _STATE.virtual_pipeline_world_size = virtual_pipeline_model_parallel_size
@@ -193,33 +174,14 @@ def get_context_parallel_world_size() -> int:
     return _axis_size(AXIS_CONTEXT)
 
 
-def get_island_world_size() -> int:
-    """Number of ICI islands (the ``dcn`` axis size; 1 on a flat mesh)."""
-    mesh = get_mesh()
-    return mesh.shape[AXIS_DCN] if AXIS_DCN in mesh.axis_names else 1
-
-
-def get_data_parallel_axes() -> Tuple[str, ...]:
-    """Mesh axes the batch shards over: ``("dcn", "data")`` on a two-tier
-    island mesh (global data parallelism spans both), ``("data",)``
-    otherwise — the spec for batch sharding and for the bulk-grad group
-    the hierarchical collectives decompose (parallel/hierarchy.py)."""
-    if AXIS_DCN in get_mesh().axis_names:
-        return (AXIS_DCN, AXIS_DATA)
-    return (AXIS_DATA,)
-
-
 def get_gradient_reduction_axes() -> Tuple[str, ...]:
     """Mesh axes over which parameter gradients must be averaged.
 
     With context parallelism each sequence shard produces partial gradients
     for the *full* parameter set, so grad reduction spans ``data`` and
     ``context`` (the reference's data-parallel group, distributed.py:449-451,
-    covers only ``data`` because CP does not exist there). On a two-tier
-    island mesh the ``dcn`` axis joins the group — but a BULK reduce must
-    not bind it flat together with another axis
-    (lint.trace.flat_dcn_collective_hazards): decompose hierarchically."""
-    return get_data_parallel_axes() + (AXIS_CONTEXT,)
+    covers only ``data`` because CP does not exist there)."""
+    return (AXIS_DATA, AXIS_CONTEXT)
 
 
 def get_pipeline_model_parallel_split_rank() -> Optional[int]:
@@ -238,11 +200,9 @@ def get_rank_info_str() -> str:
     if _STATE.mesh is None:
         return ""
     pp, dp, cp, tp = (_STATE.mesh.shape[a] for a in MESH_AXIS_NAMES)
-    isl = (_STATE.mesh.shape[AXIS_DCN]
-           if AXIS_DCN in _STATE.mesh.axis_names else 1)
     vpp = _STATE.virtual_pipeline_world_size
-    return (f" mesh({f'dcn{isl} ' if isl > 1 else ''}pp{pp} dp{dp} cp{cp} "
-            f"tp{tp}{f' vpp{vpp}' if vpp else ''})")
+    return (f" mesh(pp{pp} dp{dp} cp{cp} tp{tp}"
+            f"{f' vpp{vpp}' if vpp else ''})")
 
 
 # -- virtual pipeline (interleaved schedule) state --------------------------

@@ -36,10 +36,6 @@ def main(argv=None) -> int:
     p.add_argument("--num-microbatches", type=int, default=1)
     p.add_argument("--window", type=int, default=None,
                    help="also enumerate attention_window=W candidates")
-    p.add_argument("--mesh-islands", type=int, default=1,
-                   help="search the two-tier pod layout: N ICI islands "
-                        "joined by DCN; candidates price per tier and "
-                        "enumerate the DCN wire dtype (ISSUE 19)")
     p.add_argument("--platform", type=str, default=None,
                    help="peak-spec platform override (e.g. cpu, v4, "
                         "v5e); default autodetects")
@@ -84,7 +80,7 @@ def main(argv=None) -> int:
         spec, mesh=args.mesh, hbm_gb=args.hbm_gb,
         micro_batch=args.micro_batch,
         num_microbatches=args.num_microbatches, window=args.window,
-        islands=args.mesh_islands, platform=args.platform)
+        platform=args.platform)
 
     if args.format == "json":
         print(json.dumps(result, default=str))
@@ -107,29 +103,18 @@ def main(argv=None) -> int:
         if c["moe_expert_axis"]:
             knobs.append("ep" + (f":{c['moe_dispatch_dtype']}"
                                  if c["moe_dispatch_dtype"] else ""))
-        if c.get("islands", 1) > 1:
-            knobs.append(f"isl{c['islands']}"
-                         + (f":{c['dcn_wire']}" if c.get("dcn_wire")
-                            else ""))
         if c["unroll"]:
             knobs.append("unroll")
-        wire = pred["comm_bytes_by_tier"]["ici"]
-        wire += pred["comm_bytes_by_tier"].get("dcn", 0)
         return (" ".join(knobs),
                 pred["hbm_bytes"] / 1024**3,
-                wire / 1e9,
+                pred["comm_bytes_by_tier"]["ici"] / 1e9,
                 pred["bubble_floor"],
                 pred["step_seconds"])
 
-    tiers = f"peak: {result['peak_spec']['source']}, " \
-            f"ici: {result['ici_spec']['source']}"
-    if result.get("dcn_spec"):
-        tiers += f", dcn: {result['dcn_spec']['source']}"
-    print(f"plan: {result['model']['name']} on {result['mesh']} devices"
-          + (f" x{result['islands']} islands" if result.get("islands", 1) > 1
-             else "")
-          + f", {result['hbm_budget_bytes'] / 1024**3:.1f} GiB/rank "
-          f"budget ({tiers})")
+    print(f"plan: {result['model']['name']} on {result['mesh']} devices, "
+          f"{result['hbm_budget_bytes'] / 1024**3:.1f} GiB/rank budget "
+          f"(peak: {result['peak_spec']['source']}, "
+          f"ici: {result['ici_spec']['source']})")
     print(f"{'#':>3} {'placement':<40} {'hbm GiB':>8} {'wire GB':>8} "
           f"{'bubble':>7} {'step s':>10}")
     for i, rec in enumerate(result["ranked"][:args.top]):

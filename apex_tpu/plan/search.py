@@ -30,14 +30,6 @@ Deployment rules baked in as feasibility, not time tradeoffs:
   deployment logic: quantize the wire only where the modeled slow tier
   binds. A narrowed ``APEX_TPU_PEAK_ICI_GBPS`` flips the verdict; tests
   pin both directions.
-- on a two-tier pod mesh (``islands > 1``, ISSUE 19) the same rule runs
-  PER TIER against ``tracing.dcn_spec``: an un-quantized candidate
-  whose exact-width inter-island hop would exceed compute is rejected
-  ``dcn-bound`` (with predicted per-tier bytes, so a calibrate join can
-  close on the verdict), and a ``dcn_wire``-quantized one whose exact
-  DCN hop would NOT bind is rejected ``wire-not-binding`` — which is
-  how the 13B rung blind-picks int8-on-DCN while ICI-only configs stay
-  fp32. ``APEX_TPU_PEAK_DCN_GBPS`` flips both.
 
 The model-level conventions (documented, tested, deliberately simple):
 pp=1 microbatches are grad-accumulated (one microbatch of activations
@@ -97,14 +89,7 @@ MODEL_PRESETS = {
 
 @dataclasses.dataclass(frozen=True)
 class Candidate:
-    """One placement: every knob the harness exposes, as data.
-
-    ``islands > 1`` models a two-tier pod mesh (ISSUE 19): the data axis
-    spans ``islands`` DCN-connected ICI islands of ``dp // islands``
-    ranks each; model axes (tp/pp) stay intra-island. ``dcn_wire``
-    quantizes the inter-island hop of the hierarchical collectives
-    (``parallel/hierarchy.py``) — the only wire knob a tiered candidate
-    enumerates (the intra-island stages run at working width there)."""
+    """One placement: every knob the harness exposes, as data."""
 
     dp: int
     tp: int = 1
@@ -120,8 +105,6 @@ class Candidate:
     moe_dispatch_dtype: Optional[str] = None
     attention_window: Optional[int] = None
     unroll: bool = False
-    islands: int = 1
-    dcn_wire: Optional[str] = None
 
     def as_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -202,25 +185,12 @@ def _divisors(n: int) -> List[int]:
 
 def enumerate_candidates(
     spec: ModelSpec, mesh: int, *, window: Optional[int] = None,
-    islands: int = 1,
 ) -> Tuple[List[Candidate], List[Dict[str, Any]]]:
     """All structurally-valid candidates over a ``mesh``-device topology,
     plus the rejected shapes with named provenance (``rejected_by``:
-    ``"divisibility"`` / ``"constraint:<name>"``).
-
-    ``islands > 1`` searches the two-tier pod layout: ``mesh`` devices
-    in ``islands`` ICI islands of ``mesh // islands`` each. Model axes
-    (tp*pp) must fit inside one island (the DCN tier never carries a
-    per-layer conjugate), so the data axis spans the islands; each
-    surviving shape then enumerates the DCN wire dtype
-    (``dcn_wire in (None, "int8")``) instead of the flat-mesh
-    ``reduce_dtype`` (hierarchy quantizes the inter-island hop only)."""
+    ``"divisibility"`` / ``"constraint:<name>"``)."""
     cands: List[Candidate] = []
     rejected: List[Dict[str, Any]] = []
-    isl = max(int(islands), 1)
-    if mesh % isl:
-        raise ValueError(f"mesh {mesh} % islands {isl} != 0")
-    island_size = mesh // isl
 
     def reject(shape: Dict[str, Any], by: str, reason: str) -> None:
         rejected.append({"candidate": shape, "rejected_by": by,
@@ -230,15 +200,6 @@ def enumerate_candidates(
         for pp in _divisors(mesh // tp):
             dp = mesh // (tp * pp)
             shape = {"dp": dp, "tp": tp, "pp": pp}
-            if isl > 1:
-                shape["islands"] = isl
-                if island_size % (tp * pp):
-                    reject(shape, "divisibility",
-                           f"model axes tp*pp {tp * pp} do not fit an "
-                           f"island of {island_size} (tp/pp must stay "
-                           "intra-island: the DCN tier never carries a "
-                           "per-layer conjugate)")
-                    continue
             if tp > 1 and spec.heads % tp:
                 reject(shape, "divisibility",
                        f"heads {spec.heads} % tp {tp} != 0")
@@ -279,13 +240,7 @@ def enumerate_candidates(
                                    "ZeRO-3 rejects expert-axis-sharded "
                                    "params (CLAUDE.md, ISSUE 15)")
                             continue
-                        # tiered meshes quantize the DCN hop, not the
-                        # intra-island stage (hierarchy.py runs the ICI
-                        # legs at working width), so reduce_dtype only
-                        # enumerates on the flat mesh
-                        rds = [None] + (
-                            ["int8"] if zl == 2 and isl == 1 else [])
-                        dws = [None] + (["int8"] if isl > 1 else [])
+                        rds = [None] + (["int8"] if zl == 2 else [])
                         for rd in rds:
                             pfs = [0] + ([1] if zl == 3 and pp == 1 else [])
                             for pf in pfs:
@@ -297,22 +252,18 @@ def enumerate_candidates(
                                     mdds = [None] + (
                                         ["int8"] if moe_axis else [])
                                     for mdd in mdds:
-                                        for dw in dws:
-                                            cands.append(Candidate(
-                                                dp=dp, tp=tp, pp=pp,
-                                                vpp=vpp,
-                                                schedule=schedule, sp=sp,
-                                                zero_level=zl,
-                                                zero3_prefetch=pf,
-                                                reduce_dtype=rd,
-                                                gather_dtype=("bf16" if zl
-                                                              else None),
-                                                moe_expert_axis=moe_axis,
-                                                moe_dispatch_dtype=mdd,
-                                                attention_window=window,
-                                                unroll=un,
-                                                islands=isl,
-                                                dcn_wire=dw))
+                                        cands.append(Candidate(
+                                            dp=dp, tp=tp, pp=pp, vpp=vpp,
+                                            schedule=schedule, sp=sp,
+                                            zero_level=zl,
+                                            zero3_prefetch=pf,
+                                            reduce_dtype=rd,
+                                            gather_dtype=("bf16" if zl
+                                                          else None),
+                                            moe_expert_axis=moe_axis,
+                                            moe_dispatch_dtype=mdd,
+                                            attention_window=window,
+                                            unroll=un))
     return cands, rejected
 
 
@@ -370,58 +321,31 @@ def _activation_bytes(spec: ModelSpec, cand: Candidate, mbr: int,
 
 def _comm_bytes(spec: ModelSpec, cand: Candidate, mbr: int, nm: int,
                 rank_param_elems: int) -> Dict[str, Any]:
-    """Per-rank wire bytes per step, by component and by tier.
-    ``exact_bytes``/``dcn_exact_bytes`` reprice every quantized payload
-    at the working width — the EQuARX deployment comparison (quantize
-    only where the exact wire would bind).
-
-    On a flat mesh (``islands == 1``) everything books on the ICI tier
-    (byte-identical to the pre-pod model). With ``islands > 1`` the data
-    axis spans DCN and each bulk collective decomposes hierarchically
-    (``parallel/hierarchy.py`` arithmetic, g = dp/islands ranks per
-    island): the intra-island stages ride ICI at full ring fraction
-    ``(g-1)/g`` while the inter-island exchange moves only the 1/g
-    chunk at fraction ``(islands-1)/islands`` — at ``dcn_wire`` width
-    when quantized. tp/pp conjugates stay intra-island by construction
-    (enumerate_candidates rejects shapes that would split them)."""
+    """Per-rank wire bytes per step, by component, on the single ICI
+    tier this topology has. ``exact_bytes`` reprices every quantized
+    payload at the working width — the EQuARX deployment comparison
+    (quantize only where the exact wire would bind)."""
     r_dp = (cand.dp - 1) / cand.dp if cand.dp > 1 else 0.0
     r_tp = (cand.tp - 1) / cand.tp if cand.tp > 1 else 0.0
     layers_local = max(spec.layers // cand.pp, 1)
     rd_b = 1 if cand.reduce_dtype in ("int8", "e5m2") else _WD
     gd_b = 1 if cand.gather_dtype == "int8" else _WD
-    isl = max(cand.islands, 1)
-    g = max(cand.dp // isl, 1)  # intra-island data-axis group
-    r_g = (g - 1) / g if g > 1 else 0.0
-    r_i = (isl - 1) / isl if isl > 1 else 0.0
-    dw_b = 1 if cand.dcn_wire in ("int8", "e5m2") else _WD
     comp: Dict[str, float] = {}
     exact: Dict[str, float] = {}
-    dcomp: Dict[str, float] = {}
-    dexact: Dict[str, float] = {}
     p = rank_param_elems
-
-    def grad_leg(name: str, mult: float, ici_b: int, dcn_b: int) -> None:
-        """One bulk data-axis collective: flat on ICI at islands=1,
-        hierarchical (full payload intra-island + 1/g chunk on DCN)
-        otherwise."""
-        if isl == 1:
-            comp[name] = mult * p * ici_b * r_dp
-            exact[name] = mult * p * _WD * r_dp
-        else:
-            comp[name] = mult * p * _WD * r_g
-            exact[name] = mult * p * _WD * r_g
-            dcomp[name] = mult * (p / g) * dcn_b * r_i
-            dexact[name] = mult * (p / g) * _WD * r_i
-
     if cand.zero_level == 0:
-        grad_leg("grad_allreduce", 2.0, _WD, dw_b)
+        comp["grad_allreduce"] = exact["grad_allreduce"] = \
+            2.0 * p * _WD * r_dp
     elif cand.zero_level in (1, 2):
-        grad_leg("grad_scatter", 1.0, rd_b, dw_b)
-        grad_leg("param_gather", 1.0, gd_b, dw_b)
+        comp["grad_scatter"] = p * rd_b * r_dp
+        exact["grad_scatter"] = p * _WD * r_dp
+        comp["param_gather"] = p * gd_b * r_dp
+        exact["param_gather"] = p * _WD * r_dp
     else:  # ZeRO-3: fwd gather + bwd re-gather + grad scatter, no
         # post-update bulk gather
-        grad_leg("param_gather", 2.0, _WD, dw_b)
-        grad_leg("grad_scatter", 1.0, _WD, dw_b)
+        comp["param_gather"] = exact["param_gather"] = \
+            2.0 * p * _WD * r_dp
+        comp["grad_scatter"] = exact["grad_scatter"] = p * _WD * r_dp
     act = mbr * spec.seq * spec.hidden * _WD  # one microbatch slab
     if cand.tp > 1:
         # 2 fwd allreduces + their 2 backward conjugates per layer, each
@@ -434,29 +358,15 @@ def _comm_bytes(spec: ModelSpec, cand: Candidate, mbr: int, nm: int,
     if cand.moe_expert_axis:
         md_b = 1 if cand.moe_dispatch_dtype else _WD
         routed = mbr * spec.seq * spec.moe_top_k * spec.hidden
-        per_step = 4.0 * routed * layers_local * nm
-        if isl == 1:
-            comp["moe_dispatch"] = per_step * md_b * r_dp
-            exact["moe_dispatch"] = per_step * _WD * r_dp
-        else:
-            # two-hop dispatch: intra-island all_to_all + inter-island
-            # exchange of the cross-island share (at the DCN wire width
-            # when either dispatch or DCN quantization is on)
-            dd_b = 1 if (cand.moe_dispatch_dtype or cand.dcn_wire) else _WD
-            comp["moe_dispatch"] = per_step * md_b * r_g
-            exact["moe_dispatch"] = per_step * _WD * r_g
-            dcomp["moe_dispatch"] = per_step * dd_b * r_i
-            dexact["moe_dispatch"] = per_step * _WD * r_i
+        comp["moe_dispatch"] = \
+            4.0 * routed * md_b * r_dp * layers_local * nm
+        exact["moe_dispatch"] = \
+            4.0 * routed * _WD * r_dp * layers_local * nm
     hidden = comp.get("param_gather", 0.0) if cand.zero3_prefetch else 0.0
-    out = {"components": {k: int(v) for k, v in comp.items()},
-           "total_bytes": int(sum(comp.values())),
-           "exact_bytes": int(sum(exact.values())),
-           "prefetch_hidden_bytes": int(hidden)}
-    if isl > 1:
-        out["dcn_components"] = {k: int(v) for k, v in dcomp.items()}
-        out["dcn_bytes"] = int(sum(dcomp.values()))
-        out["dcn_exact_bytes"] = int(sum(dexact.values()))
-    return out
+    return {"components": {k: int(v) for k, v in comp.items()},
+            "total_bytes": int(sum(comp.values())),
+            "exact_bytes": int(sum(exact.values())),
+            "prefetch_hidden_bytes": int(hidden)}
 
 
 # ---------------------------------------------------------------------------
@@ -474,7 +384,6 @@ def score_candidate(
     hbm_bytes: Optional[int] = None,
     peak: Optional[Dict[str, Any]] = None,
     ici: Optional[Dict[str, Any]] = None,
-    dcn: Optional[Dict[str, Any]] = None,
     platform: Optional[str] = None,
 ) -> Dict[str, Any]:
     """Price one candidate; returns the scored record.
@@ -484,23 +393,14 @@ def score_candidate(
     placement prices the same work, with its own per-rank rows
     ``global_rows/dp`` split into ``num_microbatches`` microbatches.
     ``feasible=False`` records carry ``rejected_by`` (``"static-hbm"`` /
-    ``"wire-not-binding"`` / ``"dcn-bound"``) + ``reason``; every record
-    carries the full ``predicted`` anatomy {hbm_bytes,
-    comm_bytes_by_tier, bubble_floor, step_seconds, ...} so a rejection
-    is auditable, not a verdict. Two-tier candidates (``islands > 1``)
-    price their inter-island hop against ``tracing.dcn_spec`` and obey
-    the tiered EQuARX pair: an exact-width DCN hop that would exceed the
-    bubble-inflated compute rejects the un-quantized candidate
-    ``dcn-bound`` (with predicted per-tier bytes — the calibrate join
-    closes on them), while a quantized DCN hop whose exact wire would
-    NOT bind rejects ``wire-not-binding`` as on the flat mesh."""
+    ``"wire-not-binding"``) + ``reason``; every record carries the full
+    ``predicted`` anatomy {hbm_bytes, comm_bytes_by_tier, bubble_floor,
+    step_seconds, ...} so a rejection is auditable, not a verdict."""
     from apex_tpu.lint.passes.static_hbm import sharded_residency
     from apex_tpu.monitor import mfu, tracing
 
     peak = peak or mfu.peak_spec(platform)
     ici = ici or tracing.ici_spec(platform)
-    if cand.islands > 1 and dcn is None:
-        dcn = tracing.dcn_spec(platform)
     census = param_census(spec)
     nm = max(int(num_microbatches), 1)
     if global_rows is None:
@@ -529,20 +429,15 @@ def score_candidate(
     if cand.reduce_dtype or cand.gather_dtype == "int8":
         overhead_s += (_QUANT_PASS_BYTES_PER_ELEM * res["param_count"]
                        / (peak["peak_hbm_bytes_per_sec"] or 1.0))
-    dcn_bytes = comm.get("dcn_bytes", 0) if cand.islands > 1 else 0
     timing = tracing.modeled_step_seconds(
         flops=compute_flops, comm_bytes=comm["total_bytes"],
         bubble_fraction=bubble,
         hidden_comm_bytes=comm["prefetch_hidden_bytes"],
-        overhead_s=overhead_s, spec=peak, ici=ici,
-        dcn_bytes=dcn_bytes, dcn=dcn)
-    tier_bytes = {"ici": comm["total_bytes"]}
-    if cand.islands > 1:
-        tier_bytes["dcn"] = dcn_bytes
+        overhead_s=overhead_s, spec=peak, ici=ici)
     predicted = {
         "hbm_bytes": int(hbm_total),
         "hbm": {"residency": res, "activations": act},
-        "comm_bytes_by_tier": tier_bytes,
+        "comm_bytes_by_tier": {"ici": comm["total_bytes"]},
         "comm": comm,
         "bubble_floor": bubble,
         "flops_per_step": flops["total"],
@@ -558,33 +453,10 @@ def score_candidate(
                    reason=(f"predicted per-rank peak {hbm_total} bytes "
                            f"exceeds budget {int(hbm_bytes)}"))
         return rec
-    compute_eff_s = timing["compute_s"] / (1.0 - timing["bubble_fraction"])
-    if cand.islands > 1:
-        # tiered EQuARX: judge the DCN hop against ITS OWN wire — a
-        # narrowed/widened APEX_TPU_PEAK_DCN_GBPS flips both verdicts
-        dcn_bw = (dcn or {}).get("dcn_bytes_per_sec") or 1.0
-        exact_dcn_s = comm.get("dcn_exact_bytes", 0) / dcn_bw
-        if cand.dcn_wire is None and exact_dcn_s > compute_eff_s:
-            rec.update(
-                feasible=False, rejected_by="dcn-bound",
-                reason=(f"exact-wire DCN hop {exact_dcn_s:.4g}s > "
-                        f"compute {compute_eff_s:.4g}s at per-tier "
-                        f"bytes ici={comm['total_bytes']} "
-                        f"dcn={comm.get('dcn_exact_bytes', 0)}: the "
-                        "inter-island wire binds — quantize it "
-                        "(dcn_wire=int8) or re-shape the placement"))
-            return rec
-        if cand.dcn_wire is not None and exact_dcn_s < compute_eff_s:
-            rec.update(
-                feasible=False, rejected_by="wire-not-binding",
-                reason=(f"exact-wire DCN hop {exact_dcn_s:.4g}s < "
-                        f"compute {compute_eff_s:.4g}s: quantize the "
-                        "inter-island hop only where the DCN wire binds "
-                        "(EQuARX rule, per tier)"))
-            return rec
     if cand.quantized_wire:
         bw = ici.get("ici_bytes_per_sec") or 1.0
         exact_comm_s = comm["exact_bytes"] / bw
+        compute_eff_s = timing["compute_s"] / (1.0 - timing["bubble_fraction"])
         if exact_comm_s < compute_eff_s:
             rec.update(
                 feasible=False, rejected_by="wire-not-binding",
@@ -602,7 +474,7 @@ def _sort_key(rec: Dict[str, Any]) -> Tuple:
     return (round(p["step_seconds"], 9), c["zero_level"], c["pp"],
             c["tp"], int(c["sp"]), c["zero3_prefetch"],
             c["reduce_dtype"] or "", c["moe_dispatch_dtype"] or "",
-            c.get("dcn_wire") or "", int(c["unroll"]))
+            int(c["unroll"]))
 
 
 def search(
@@ -614,7 +486,6 @@ def search(
     micro_batch: int = 1,
     num_microbatches: int = 1,
     window: Optional[int] = None,
-    islands: int = 1,
     platform: Optional[str] = None,
     constraints: Optional[Dict[str, Any]] = None,
 ) -> Dict[str, Any]:
@@ -630,10 +501,7 @@ def search(
     strict-JSON-ready table: ``ranked`` (feasible, best first),
     ``rejected`` (with ``rejected_by`` provenance), ``winner``
     (= ``ranked[0]`` or None), and the resolved peak/ICI specs with
-    their calibration provenance. ``islands > 1`` searches the two-tier
-    pod layout (the ``--mesh-islands`` knob): the result also carries
-    the resolved ``dcn_spec`` and per-candidate
-    ``comm_bytes_by_tier["dcn"]``; single-tier results are unchanged."""
+    their calibration provenance."""
     from apex_tpu.monitor import mfu, tracing
 
     if isinstance(spec, str):
@@ -645,10 +513,7 @@ def search(
     global_rows = micro_batch * max(int(num_microbatches), 1) * int(mesh)
     peak = mfu.peak_spec(platform)
     ici = tracing.ici_spec(platform)
-    isl = max(int(islands), 1)
-    dcn = tracing.dcn_spec(platform) if isl > 1 else None
-    cands, rejected = enumerate_candidates(spec, mesh, window=window,
-                                           islands=isl)
+    cands, rejected = enumerate_candidates(spec, mesh, window=window)
     n_structural = len(rejected)
     ranked: List[Dict[str, Any]] = []
     for cand in cands:
@@ -658,7 +523,7 @@ def search(
         rec = score_candidate(
             spec, cand, micro_batch=micro_batch,
             num_microbatches=num_microbatches, global_rows=global_rows,
-            hbm_bytes=budget, peak=peak, ici=ici, dcn=dcn)
+            hbm_bytes=budget, peak=peak, ici=ici)
         if rec["feasible"]:
             ranked.append(rec)
         else:
@@ -676,7 +541,6 @@ def search(
         "global_rows": int(global_rows),
         "peak_spec": peak,
         "ici_spec": ici,
-        **({"islands": isl, "dcn_spec": dcn} if isl > 1 else {}),
         "n_enumerated": len(cands),
         "n_rejected_structural": n_structural,
         "ranked": ranked,

@@ -150,13 +150,7 @@ def build_zero_train_step(
     )
 
     reducer = MeshGradScaler().found_inf_reducer
-    # on a two-tier mesh (mp_opt.dcn_axis, parallel/hierarchy.py) the
-    # hierarchical scatter reduces over the WHOLE (dcn, zero) group —
-    # both axes drop from the harness reduction, or the grads would
-    # double-reduce over the island axis exactly like the
-    # zero_redundancy_hazards class
-    _drop = {zero_axis, getattr(mp_opt, "dcn_axis", None)}
-    nonzero_axes = tuple(a for a in grad_axes if a not in _drop)
+    nonzero_axes = tuple(a for a in grad_axes if a != zero_axis)
 
     def reduce_nonzero(rest_g, layer_g):
         # nonzero_axes already excludes zero_axis: the sharded optimizer's

@@ -100,17 +100,6 @@ class MoEMLP:
         through the transposed exchange. No EF residual (activations are
         fresh every step — the quantize.py activation convention).
         ``None`` = exact wire (traces bit-identical to pre-knob).
-      dcn_axis: the slow inter-island tier of a two-tier mesh
-        (``parallel/hierarchy.py``): experts then shard over the COMBINED
-        ``(dcn_axis, expert_axis)`` group and the dispatch/combine
-        exchanges run as the TWO-HOP ``hier_all_to_all`` — re-bucket
-        within each island on the fast ICI links, then exactly ONE
-        all_to_all per island crosses DCN with ``1/n_ici`` of the
-        payload. ``dispatch_dtype`` then quantizes ONLY the DCN hop
-        (the intra-island hop stays full precision — quantizing the
-        fast links buys nothing). Same function, values AND grads, as
-        the flat single-hop dispatch over the tuple axis
-        (tests/test_hierarchy.py pins it).
     """
 
     def __init__(
@@ -125,7 +114,6 @@ class MoEMLP:
         params_dtype: Any = jnp.float32,
         init_method=None,
         dispatch_dtype: Optional[str] = None,
-        dcn_axis: Optional[str] = None,
     ):
         if top_k < 1 or top_k > num_experts:
             raise ValueError(f"top_k ({top_k}) must be in [1, {num_experts}]")
@@ -146,16 +134,6 @@ class MoEMLP:
                 "dispatch_dtype requires expert_axis: the quantized wire "
                 "rides the expert-parallel all_to_all dispatch/combine "
                 "exchange — a serial MoE layer has no wire to quantize")
-        self.dcn_axis = dcn_axis
-        if dcn_axis is not None:
-            if expert_axis is None:
-                raise ValueError(
-                    "dcn_axis requires expert_axis: it names the slow "
-                    "tier of the two-hop hierarchical dispatch "
-                    "(parallel/hierarchy.py)")
-            from apex_tpu.monitor.comms import register_dcn_axis
-
-            register_dcn_axis(dcn_axis)
 
     # -- parameters ---------------------------------------------------------
 
@@ -176,16 +154,8 @@ class MoEMLP:
                     "bias": jnp.zeros((E, d), self.params_dtype)},
         }
 
-    def _expert_group(self):
-        """The mesh axes the expert dim shards over: ``(dcn, expert)`` on
-        a two-tier mesh (first name most significant, the hier_* layout),
-        else the bare expert axis."""
-        if self.dcn_axis is not None:
-            return (self.dcn_axis, self.expert_axis)
-        return self.expert_axis
-
     def specs(self) -> Params:
-        ax, tx = self._expert_group(), self.tp_axis
+        ax, tx = self.expert_axis, self.tp_axis
         return {
             "router": {"kernel": P()},
             # fc1 column-parallel (split ffn out-dim), fc2 row-parallel
@@ -319,19 +289,8 @@ class MoEMLP:
         in CommAccount at its wire dtype: the exact fp32/bf16 exchange by
         default, the encoded 1 B/elem pair under ``dispatch_dtype``
         (parallel/quantize.quantized_all_to_all — same EQuARX-shaped
-        machinery as the ZeRO grad wire, minus the residual).
-
-        On a two-tier mesh (``dcn_axis``) the exchange is the two-hop
-        ``hier_all_to_all``: intra-island re-bucket on ICI, one
-        ``1/n_ici``-sized all_to_all across DCN — with ``dispatch_dtype``
-        quantizing only the DCN hop."""
+        machinery as the ZeRO grad wire, minus the residual)."""
         ax = self.expert_axis
-        if self.dcn_axis is not None:
-            from apex_tpu.parallel.hierarchy import hier_all_to_all
-
-            return hier_all_to_all(
-                x, self.dcn_axis, ax, split_axis=split_axis,
-                concat_axis=concat_axis, dcn_wire=self.dispatch_dtype)
         if self.dispatch_dtype is not None:
             from apex_tpu.parallel.quantize import quantized_all_to_all
 
@@ -365,12 +324,11 @@ class MoEMLP:
         ax = self.expert_axis
         if ax is None:
             raise ValueError("expert_axis is required for expert parallelism")
-        group = self._expert_group()
-        ep = lax.axis_size(group)
+        ep = lax.axis_size(ax)
         E = self.num_experts
         if E % ep:
             raise ValueError(f"num_experts ({E}) must divide by the "
-                             f"{group!r} axis size ({ep})")
+                             f"{ax!r} axis size ({ep})")
         shape = h_local.shape
         h2d = h_local.reshape(-1, shape[-1])
         # router params are replicated; local routing over local tokens
@@ -392,8 +350,7 @@ class MoEMLP:
         # the axis size, and each shard should own exactly its local
         # tokens' router gradient anyway (the caller psums router grads
         # like any replicated-param gradient).
-        stats = {k: _pmean_value_local_grad(v, group)
-                 for k, v in stats.items()}
+        stats = {k: _pmean_value_local_grad(v, ax) for k, v in stats.items()}
         return out.reshape(shape), self._aux_losses(stats)
 
     # -- expert-sharded inference forward (the serving conjugate) -----------

@@ -456,22 +456,18 @@ def placement_rung(*, hidden=2560, layers=34, heads=32, vocab=50304,
 
 def analytic_rung(*, model="gpt-13b", mesh=64,
                   hbm_bytes=PLACEMENT_HBM_BYTES, micro_batch=1,
-                  num_microbatches=8, islands=1, platform=None):
+                  num_microbatches=8):
     """The planner-generated 13B-class rung: a full placement search at a
     pod-slice mesh this container will never hold (mesh=64 — at mesh=8
     the 13B optimizer chunks alone blow a 16 GiB budget, and 'needs more
     chips' is itself the planner's verdict). Pure analysis — the row
     records the winner's predicted anatomy and the rejection-provenance
-    histogram, not a timed run. ``islands > 1`` prices the two-tier pod
-    layout per link class (ISSUE 19) — pass an explicit datasheet
-    ``platform`` there so the DCN row resolves from the table, not this
-    container's cpu backend."""
+    histogram, not a timed run."""
     from apex_tpu import plan as plan_mod
 
     result = plan_mod.search(
         model, mesh=mesh, hbm_bytes=hbm_bytes, micro_batch=micro_batch,
-        num_microbatches=num_microbatches, islands=islands,
-        platform=platform)
+        num_microbatches=num_microbatches)
     winner = result["winner"]
     by = {}
     for r in result["rejected"]:
@@ -489,13 +485,10 @@ def analytic_rung(*, model="gpt-13b", mesh=64,
     return {
         "config": {"analytic_rung": True, "model": model,
                    "mesh": int(mesh),
-                   **({"islands": int(islands)} if islands > 1 else {}),
                    "dp": wc.get("dp", "-"), "tp": wc.get("tp", "-"),
                    "pp": wc.get("pp", "-"),
                    "layers": result["model"]["layers"],
-                   "zero_level": wc.get("zero_level", 0),
-                   **({"dcn_wire": wc.get("dcn_wire")}
-                      if islands > 1 else {})},
+                   "zero_level": wc.get("zero_level", 0)},
         "hbm_budget_bytes": int(hbm_bytes),
         "global_rows": result["global_rows"],
         "n_enumerated": result["n_enumerated"],
@@ -505,8 +498,6 @@ def analytic_rung(*, model="gpt-13b", mesh=64,
         "top": [compact(r) for r in result["ranked"][:5]],
         "peak_source": result["peak_spec"].get("source"),
         "ici_source": result["ici_spec"].get("source"),
-        **({"dcn_source": (result.get("dcn_spec") or {}).get("source")}
-           if islands > 1 else {}),
         "basis": ("analytic: apex_tpu.plan.search over the full "
                   f"(dp,tp,pp,schedule,zero,wire) space at mesh={mesh}; "
                   "ranked by modeled step seconds, rejections carry "
@@ -599,11 +590,7 @@ _TABLE_NOTES = {
         "search) at mesh=64: winner anatomy + rejection-provenance "
         "histogram. At mesh=8 nothing places under 16 GiB — the 'needs "
         "more chips' verdict is the point; pure analysis, no "
-        "execution. The islands=8 pod row prices the same search per "
-        "link class (ICI + DCN at v4 datasheet clocks): the winner "
-        "carries dcn_wire=int8 where the inter-island hop binds while "
-        "the flat row stays fp32 — the tiered EQuARX pair "
-        "(dcn-bound / wire-not-binding, apex_tpu.plan.search)."),
+        "execution."),
 }
 
 
@@ -725,18 +712,6 @@ def run_grid(*, hidden, layers_list, heads, vocab, seq, micro_batch, n_micro,
             c = res13["config"]
             name = f"scaling_plan_{c['model']}_mesh{c['mesh']}.json"
             atomic_write_json(os.path.join(output_dir, name), res13)
-        # the pod rung: the same 13B search priced per tier on a two-tier
-        # 8x8 layout at v4 datasheet clocks — blind-picks the int8 DCN
-        # wire where the inter-island hop binds (ISSUE 19)
-        res13pod = analytic_rung(islands=8, num_microbatches=2,
-                                 platform="v4")
-        rows.append(res13pod)
-        print(json.dumps(res13pod), flush=True)
-        if output_dir:
-            c = res13pod["config"]
-            name = (f"scaling_plan_{c['model']}_mesh{c['mesh']}"
-                    f"_isl{c['islands']}.json")
-            atomic_write_json(os.path.join(output_dir, name), res13pod)
     if output_dir:
         # atomic (tmp + rename): a crash mid-sweep must never leave a
         # torn table for a later evidence consumer

@@ -52,21 +52,6 @@ def parse_args():
                         "at 1 B/elem + per-chunk fp32 scales, with an "
                         "error-feedback residual in the sharded state "
                         "(parallel/quantize.py)")
-    p.add_argument("--mesh-islands", type=int, default=1, metavar="N",
-                   help="model the devices as N ICI islands joined by DCN "
-                        "(parallel/hierarchy.py): a leading 'dcn' mesh "
-                        "axis joins the ZeRO group — batches shard over "
-                        "(dcn, data) and the grad reduction decomposes "
-                        "hierarchically so the slow tier carries only "
-                        "the 1/n_ici pre-reduced shard (LAMB trust-ratio "
-                        "norms psum over the whole group). Requires "
-                        "--zero at levels 1/2")
-    p.add_argument("--dcn-wire", default="int8",
-                   choices=["int8", "e5m2", "none"],
-                   help="wire dtype of the inter-island (DCN) gradient "
-                        "hop when --mesh-islands > 1; defaults ON at "
-                        "int8 with an error-feedback residual (the "
-                        "EQuARX rule); 'none' keeps the hop exact fp32")
     p.add_argument("--journal", default=None, metavar="PATH",
                    help="write a per-step JSON-lines metrics journal "
                         "(apex_tpu.monitor: wall time, tokens/s, loss, "
@@ -101,15 +86,6 @@ def parse_args():
     if args.reduce_dtype and not args.zero:
         p.error("--reduce-dtype requires --zero (it is the ZeRO grad "
                 "reduce-scatter wire dtype)")
-    if args.mesh_islands > 1:
-        if not args.zero or (args.zero_level or 0) >= 3:
-            p.error("--mesh-islands > 1 requires --zero at levels 1/2: "
-                    "the hierarchical grad path is the ZeRO optimizer's "
-                    "dcn_axis")
-        if args.reduce_dtype:
-            p.error("--reduce-dtype is the FLAT quantized wire; on a "
-                    "two-tier mesh use --dcn-wire for the inter-island "
-                    "hop (the intra-island stages stay exact)")
     return args
 
 
@@ -148,27 +124,12 @@ def main():
         if args.batch % n_dev:
             raise SystemExit(f"--batch {args.batch} must divide the "
                              f"device count {n_dev} under --zero")
-        isl = args.mesh_islands
-        if n_dev % max(isl, 1):
-            raise SystemExit(f"--mesh-islands {isl} must divide the "
-                             f"device count {n_dev}")
-        if isl > 1:
-            # two-tier topology (parallel/hierarchy.py): 'dcn' leads so
-            # island-mates stay contiguous; the ZeRO group spans both
-            # axes and LAMB's trust-ratio norms psum over the whole group
-            mesh = Mesh(np.asarray(jax.devices()).reshape(isl, -1),
-                        ("dcn", "data"))
-            zero_group = ("dcn", "data")
-        else:
-            mesh = Mesh(np.asarray(jax.devices()), ("data",))
-            zero_group = "data"
+        mesh = Mesh(np.asarray(jax.devices()), ("data",))
         mp_opt = amp.MixedPrecisionOptimizer(
             FusedLAMB(lr=args.lr, weight_decay=0.01,
-                      norm_psum_axis=zero_group),
+                      norm_psum_axis="data"),
             policy, zero_axis="data",
             zero_level=args.zero_level,
-            dcn_axis="dcn" if isl > 1 else None,
-            dcn_wire=None if args.dcn_wire == "none" else args.dcn_wire,
             # bf16 gather is free only when the model params already live
             # in half precision (cast O2/O3); for fp32-param policies
             # (O0/O1) it would round the weights every step.
@@ -177,7 +138,7 @@ def main():
             reduce_dtype=args.reduce_dtype)
         params = amp.cast_params(model.init(jax.random.PRNGKey(0)), policy)
         pspecs = jax.tree.map(lambda _: P(), params)
-        data_spec = P(zero_group)
+        data_spec = P("data")
 
         if args.zero_level >= 3:
             # fully-sharded: the bf16 params persist as 1/dp chunk trees;
@@ -207,7 +168,7 @@ def main():
                     rest_c, p["layers"])
                 np_, ns, m = mp_opt.apply_gradients(
                     s, p, dict(rg, layers=lg))
-                return np_, ns, collectives.pmean(ls, zero_group), m
+                return np_, ns, collectives.pmean(ls, "data"), m
         else:
             state, zero_specs = mp_opt.zero_init(params, mesh, pspecs)
 
@@ -219,7 +180,7 @@ def main():
 
                 ls, gs = jax.value_and_grad(scaled)(p)
                 np_, ns, m = mp_opt.apply_gradients(s, p, gs)
-                return np_, ns, collectives.pmean(ls, zero_group), m
+                return np_, ns, collectives.pmean(ls, "data"), m
 
         zero_fn = jax.shard_map(
             zero_step, mesh=mesh,
@@ -270,10 +231,7 @@ def main():
                   "batch": args.batch, "opt_level": args.opt_level,
                   "zero": bool(args.zero),
                   "zero_level": args.zero_level or 0,
-                  "reduce_dtype": args.reduce_dtype or "fp32",
-                  "islands": args.mesh_islands,
-                  "dcn_wire": (args.dcn_wire if args.mesh_islands > 1
-                               else "none")}
+                  "reduce_dtype": args.reduce_dtype or "fp32"}
     ledger_pred = {}
     journal = None
     if args.journal:
@@ -298,14 +256,10 @@ def main():
                 flops_per_token=costs["flops"] / toks_per_step,
                 bytes_per_token=costs["bytes"] / toks_per_step,
                 method=costs["method"])
-            dcn_bytes = acct.by_tier().get("dcn", {}).get("bytes", 0)
-            journal.set_step_comm(acct.total_bytes(),
-                                  dcn_bytes_per_step=dcn_bytes)
+            journal.set_step_comm(acct.total_bytes())
             ledger_pred.update(flops_per_step=costs["flops"],
                                bytes_per_step=costs["bytes"],
                                comm_bytes_per_step=acct.total_bytes())
-            if dcn_bytes:
-                ledger_pred["dcn_bytes_per_step"] = dcn_bytes
         except Exception as e:  # noqa: BLE001 - telemetry must not kill a run
             print(f"mfu arming failed (journal continues without): {e}")
     rng = np.random.default_rng(0)

@@ -60,7 +60,6 @@ def _apply_plan(args):
         moe_top_k=args.moe_top_k)
     result = plan_mod.search(
         spec, mesh=len(jax.devices()), hbm_gb=args.plan_hbm_gb,
-        islands=args.mesh_islands,
         micro_batch=args.micro_batch,
         num_microbatches=args.num_microbatches,
         # this harness exposes no sequence-parallel or attention-window
@@ -87,10 +86,6 @@ def _apply_plan(args):
     args.zero3_prefetch = c["zero3_prefetch"]
     args.zero_gather = c["gather_dtype"]
     args.reduce_dtype = c["reduce_dtype"]
-    if c.get("islands", 1) > 1:
-        # per-tier wire verdict (the dcn-bound/EQuARX rule): the winner
-        # names the DCN hop's dtype — 'none' keeps the exact fp32 hop
-        args.dcn_wire = c["dcn_wire"] or "none"
     if c["moe_expert_axis"]:
         args.moe_dispatch_dtype = c["moe_dispatch_dtype"]
     args.plan_predicted = winner["predicted"]
@@ -173,40 +168,6 @@ def parse_args(argv=None):
                         "at 1 B/elem + per-chunk fp32 scales, with an "
                         "error-feedback residual in the sharded optimizer "
                         "state (parallel/quantize.py)")
-    p.add_argument("--mesh-islands", type=int, default=1, metavar="N",
-                   help="model the mesh as N ICI islands joined by DCN "
-                        "(parallel/hierarchy.py): a leading 'dcn' mesh "
-                        "axis joins the data-parallel group — batches "
-                        "shard over (dcn, data) and the ZeRO grad "
-                        "reduction decomposes hierarchically (intra-"
-                        "island reduce-scatter, ONE 1/n_ici-sized inter-"
-                        "island exchange, intra-island gather) so the "
-                        "slow tier never carries the full payload "
-                        "(tripwire: lint.trace."
-                        "flat_dcn_collective_hazards). Requires --zero "
-                        "at levels 1/2")
-    p.add_argument("--dcn-wire", default="int8",
-                   choices=["int8", "e5m2", "none"],
-                   help="wire dtype of the inter-island (DCN) gradient "
-                        "hop when --mesh-islands > 1. Defaults ON at "
-                        "int8 — the EQuARX deployment point: quantize "
-                        "exactly where the slow tier binds, with an "
-                        "error-feedback residual in the sharded "
-                        "optimizer state; 'none' keeps the hop exact "
-                        "fp32 (parallel/hierarchy.py hier_scatter_chunk)")
-    p.add_argument("--offload-optimizer", action="store_true",
-                   help="host-offload the cold ZeRO optimizer state "
-                        "(optimizers/offload.py HostOffloadedZero): fp32 "
-                        "masters + moments (+ residual) live in host RAM "
-                        "between steps and stream through HBM in "
-                        "--offload-buckets contiguous buckets, bucket "
-                        "b+1's async H2D prefetched under bucket b's "
-                        "update — bit-identical step math, optimizer "
-                        "HBM bounded by the two largest buckets. "
-                        "Requires --zero at levels 1/2")
-    p.add_argument("--offload-buckets", type=int, default=2, metavar="N",
-                   help="bucket count for --offload-optimizer (more "
-                        "buckets = less peak HBM, more H2D/D2H trips)")
     p.add_argument("--moe-experts", type=int, default=None, metavar="E",
                    help="route every layer's FFN through a top-k MoE with "
                         "E experts (transformer/moe.py); with dp > 1 the "
@@ -310,38 +271,6 @@ def parse_args(argv=None):
         if not args.unroll:
             p.error("--zero3-prefetch requires --unroll (the prefetch "
                     "schedule is a static unrolled structure)")
-    if args.mesh_islands > 1:
-        if not args.zero or (args.zero_level or 0) >= 3:
-            p.error("--mesh-islands > 1 requires --zero at levels 1/2: "
-                    "the hierarchical grad path is the ZeRO optimizer's "
-                    "dcn_axis (amp.MixedPrecisionOptimizer; level 3's "
-                    "per-layer gather transposes have no two-tier "
-                    "decomposition)")
-        if args.reduce_dtype:
-            p.error("--reduce-dtype is the FLAT quantized wire; on a "
-                    "two-tier mesh the grad wire is per TIER — use "
-                    "--dcn-wire for the inter-island hop (the intra-"
-                    "island stages stay exact)")
-        if args.moe_experts:
-            p.error("--mesh-islands does not compose with --moe-experts "
-                    "(expert-parallel dispatch over the combined group "
-                    "has no two-hop spelling in this harness yet — "
-                    "transformer/moe.py MoEMLP(dcn_axis=) is the "
-                    "library seam)")
-    if args.offload_optimizer:
-        if not args.zero or (args.zero_level or 0) >= 3:
-            p.error("--offload-optimizer requires --zero at levels 1/2 "
-                    "(the offloaded state IS the ZeRO chunk tree; at "
-                    "level 3 grads arrive inside the backward, not in "
-                    "one apply phase)")
-        if args.moe_experts:
-            p.error("--offload-optimizer requires every param replicated "
-                    "over the zero group — expert-sharded MoE masters "
-                    "are the local shard and stay resident")
-        if args.save_dir:
-            p.error("--offload-optimizer does not checkpoint: the "
-                    "optimizer state is host-resident numpy, outside "
-                    "the device checkpoint tree")
     if args.moe_dispatch_dtype and not args.moe_experts:
         p.error("--moe-dispatch-dtype requires --moe-experts (it is the "
                 "expert-parallel dispatch wire dtype)")
@@ -373,10 +302,8 @@ def main(argv=None):
         n_dev,
         tensor_model_parallel_size=args.tp,
         pipeline_model_parallel_size=args.pp,
-        islands=args.mesh_islands,
     )
     dp = mesh_lib.get_data_parallel_world_size()
-    islands = mesh_lib.get_island_world_size()
     assert args.layers % max(args.pp * args.vpp, 1) == 0
 
     moe_kwargs = {}
@@ -417,12 +344,7 @@ def main(argv=None):
         zero_axis=mesh_lib.AXIS_DATA if args.zero else None,
         zero_level=args.zero_level or 2,
         gather_dtype=args.zero_gather,
-        reduce_dtype=args.reduce_dtype,
-        # two-tier mesh (parallel/hierarchy.py): the island axis joins
-        # the zero group and every bulk collective decomposes — the DCN
-        # hop carries 1/n_ici of the payload, quantized by default
-        dcn_axis=mesh_lib.AXIS_DCN if islands > 1 else None,
-        dcn_wire=None if args.dcn_wire == "none" else args.dcn_wire)
+        reduce_dtype=args.reduce_dtype)
 
     full = amp.cast_params(model.init(jax.random.PRNGKey(0)), policy)
     all_specs = model.specs()
@@ -449,7 +371,6 @@ def main(argv=None):
         tracer = tracing.arm(
             args.trace,
             meta={"run": "pretrain_gpt", "tp": args.tp, "pp": args.pp,
-                  "islands": islands,
                   "zero_level": args.zero_level or 0})
     if args.flight:
         # black box (monitor/flight.py): journal/span records and
@@ -459,13 +380,11 @@ def main(argv=None):
 
         flight_mod.arm(args.flight,
                        meta={"run": "pretrain_gpt", "tp": args.tp,
-                             "pp": args.pp, "dp": dp, "islands": islands,
+                             "pp": args.pp, "dp": dp,
                              "zero_level": args.zero_level or 0})
 
-    # global data parallelism spans both tiers on an island mesh: batch
-    # rows shard over ("dcn", "data") and each island sees dp shards
-    batch = args.micro_batch * dp * islands * args.num_microbatches
-    data_spec = P(mesh_lib.get_data_parallel_axes())
+    batch = args.micro_batch * dp * args.num_microbatches
+    data_spec = P(mesh_lib.AXIS_DATA)
     rest_specs = {k: v for k, v in all_specs.items() if k != "layers"}
     grad_axes = mesh_lib.get_gradient_reduction_axes()
     # MoE layers emit router aux losses: thread them through the ring and
@@ -516,60 +435,7 @@ def main(argv=None):
         layer_g = allreduce_gradients(layer_g, grad_axes)
         return collectives.pmean(loss, grad_axes), dict(rest_g, layers=layer_g)
 
-    offload = None
-    if args.offload_optimizer:
-        # host-offloaded ZeRO (optimizers/offload.py): grads compute in
-        # ONE jitted shard_map that returns them STACKED over a leading
-        # group axis (the global spelling of each rank's own unreduced
-        # local-mean grad), then the host driver streams the bucketed
-        # state — bucket b+1's async H2D in flight under bucket b's
-        # scatter→update→gather (its scatter IS the group reduction)
-        from apex_tpu.optimizers.offload import HostOffloadedZero
-        from apex_tpu.transformer.amp import MeshGradScaler
-
-        group_axes = mesh_lib.get_data_parallel_axes()
-        nonzero_axes = tuple(a for a in grad_axes if a not in group_axes)
-
-        def stacked_grads(p, toks, tgts, scale):
-            rest = {k: v for k, v in p.items() if k != "layers"}
-            if zb_vg is not None:
-                loss, rest_g, layer_g = zb_vg(rest, p["layers"], toks,
-                                              tgts, scale)
-            else:
-                def scaled_loss(rest, layers):
-                    return pipe_loss(rest, layers, toks, tgts) * scale
-
-                loss, (rest_g, layer_g) = jax.value_and_grad(
-                    scaled_loss, argnums=(0, 1))(rest, p["layers"])
-            # the group axes stay UNREDUCED — the offload driver's
-            # scatter is the reduction over them; only context partials
-            # and pipe embedding ties reduce here
-            rest_g = allreduce_gradients_by_spec(
-                rest_g, rest_specs, data_axes=nonzero_axes)
-            layer_g = allreduce_gradients(layer_g, nonzero_axes)
-            g = jax.tree.map(lambda x: x[None],
-                             dict(rest_g, layers=layer_g))
-            return collectives.pmean(loss, grad_axes), g
-
-        stacked_specs = jax.tree.map(
-            lambda sp: P(group_axes, *sp), specs,
-            is_leaf=lambda x: isinstance(x, P))
-        grads_fn = jax.jit(jax.shard_map(
-            stacked_grads, mesh=mesh,
-            in_specs=(specs, data_spec, data_spec, P()),
-            out_specs=(P(), stacked_specs), check_vma=False))
-        offload = HostOffloadedZero(
-            mp_opt, mesh, specs, num_buckets=args.offload_buckets,
-            found_inf_reducer=MeshGradScaler().found_inf_reducer)
-        opt_state = offload.init(params)
-
-        def train_step(params, opt_state, tokens, targets):
-            scale = opt_state.scaler.loss_scale
-            loss, scaled_g = grads_fn(params, tokens, targets, scale)
-            new_p, new_state, metrics = offload.apply_gradients(
-                opt_state, params, scaled_g)
-            return new_p, new_state, loss / scale, metrics
-    elif args.zero:
+    if args.zero:
         # ZeRO: the whole step — backward, spec-aware reduction over every
         # NON-data axis, and the sharded optimizer (psum_scatter → chunked
         # Adam → compressed all_gather) — runs inside ONE shard_map; the
@@ -631,7 +497,7 @@ def main(argv=None):
         # compile a second time — inside the timed steps. Commit the
         # state to the mesh and pin the step's outputs to its inputs'
         # shardings: the placement is the same, and so is the cache key.
-        # (The offload and two-program traced drives are host code.)
+        # (The two-program traced drive is host code.)
         replicated = NamedSharding(mesh, P())
         params, opt_state = jax.tree.map(
             lambda a: a if isinstance(a.sharding, NamedSharding)
@@ -679,10 +545,7 @@ def main(argv=None):
                   "zero3_prefetch": args.zero3_prefetch or 0,
                   "reduce_dtype": args.reduce_dtype or "fp32",
                   "moe_experts": args.moe_experts or 0,
-                  "moe_dispatch_dtype": args.moe_dispatch_dtype or "none",
-                  "islands": islands,
-                  "dcn_wire": (args.dcn_wire if islands > 1 else "none"),
-                  "offload": bool(args.offload_optimizer)}
+                  "moe_dispatch_dtype": args.moe_dispatch_dtype or "none"}
     ledger_pred = {}  # predicted block, filled at arm time (off-TPU math)
     if getattr(args, "plan_predicted", None):
         # the planner's predicted anatomy seeds the ledger keys the
@@ -693,9 +556,6 @@ def main(argv=None):
         ledger_pred.setdefault("bubble_floor", pred["bubble_floor"])
         ledger_pred.setdefault("comm_bytes_per_step",
                                pred["comm_bytes_by_tier"]["ici"])
-        if pred["comm_bytes_by_tier"].get("dcn"):
-            ledger_pred.setdefault("dcn_bytes_per_step",
-                                   pred["comm_bytes_by_tier"]["dcn"])
         ledger_pred.setdefault("modeled_step_s", pred["step_seconds"])
     journal = forensics = None
     if args.journal:
@@ -723,11 +583,7 @@ def main(argv=None):
             # up by `python -m apex_tpu.monitor.report`
             from apex_tpu.monitor.hbm import opt_state_bytes, param_bytes
 
-            journal.set_opt_state_bytes(
-                # offloaded state lives in host RAM: the honest HBM
-                # figure is the two-largest-buckets residency bound
-                opt_state.hbm_resident_bytes() if offload is not None
-                else opt_state_bytes(opt_state))
+            journal.set_opt_state_bytes(opt_state_bytes(opt_state))
             journal.set_param_bytes(param_bytes(params))
         except Exception as e:  # noqa: BLE001 - telemetry must not kill a run
             print(f"residency-bytes arming failed: {e}")
@@ -749,36 +605,18 @@ def main(argv=None):
             # the same trace also books collective payload bytes, so the
             # journal's step-anatomy fields (compute/comm/stall fractions
             # + overlap, monitor/tracing.py step_anatomy) arm for free
-            if offload is not None:
-                # the host bucket drive doesn't trace as one jaxpr; the
-                # jitted grads program is the step's on-device anatomy
-                scale0 = opt_state.scaler.loss_scale
-                with comm_accounting() as acct:
-                    costs = mfu_lib.traced_step_costs(
-                        lambda p, a, b: grads_fn(p, a, b, scale0),
-                        params, z, z)
-                    # the grad wire lives in the bucket apply programs —
-                    # trace them abstractly so the census is whole-step
-                    offload.abstract_step(params, opt_state)
-            else:
-                with comm_accounting() as acct:
-                    costs = mfu_lib.traced_step_costs(
-                        train_step, params, opt_state, z, z)
+            with comm_accounting() as acct:
+                costs = mfu_lib.traced_step_costs(
+                    train_step, params, opt_state, z, z)
             journal.set_step_costs(
                 flops_per_token=costs["flops"] / (batch * args.seq),
                 bytes_per_token=costs["bytes"] / (batch * args.seq),
                 method=costs["method"])
-            # per-link-class split (CommAccount.by_tier): the dcn arm
-            # prices the exposed DCN seconds report/compare gate on
-            dcn_bytes = acct.by_tier().get("dcn", {}).get("bytes", 0)
-            journal.set_step_comm(acct.total_bytes(),
-                                  dcn_bytes_per_step=dcn_bytes)
+            journal.set_step_comm(acct.total_bytes())
             # the same statics ARE the ledger's predicted block
             ledger_pred.update(flops_per_step=costs["flops"],
                                bytes_per_step=costs["bytes"],
                                comm_bytes_per_step=acct.total_bytes())
-            if dcn_bytes:
-                ledger_pred["dcn_bytes_per_step"] = dcn_bytes
         except Exception as e:  # noqa: BLE001 - telemetry must not kill a run
             print(f"mfu arming failed (journal continues without): {e}")
         train_step = RecompileTracker(journal).wrap(train_step,
@@ -892,8 +730,7 @@ def main(argv=None):
     n_done = max(args.steps - 1, 1)
     dt = (time.perf_counter() - t0) / n_done
     print(f"{batch * args.seq / dt:.0f} tokens/s | mesh: tp={args.tp} pp={args.pp} "
-          f"dp={dp}{f' islands={islands}' if islands > 1 else ''} | "
-          f"{dt * 1e3:.1f} ms/step")
+          f"dp={dp} | {dt * 1e3:.1f} ms/step")
     if args.ledger:
         try:
             from apex_tpu.monitor import ledger as ledger_mod

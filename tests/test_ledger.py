@@ -279,33 +279,6 @@ def test_calibrate_fit_and_file_round_trip(tmp_path, monkeypatch):
     assert calibrate.load(path) is None
 
 
-def test_calibrate_fit_dcn_peak(tmp_path, monkeypatch):
-    """The DCN fit (ISSUE 19): predicted slow-tier bytes over the
-    measured exposed DCN seconds p50 → peak_dcn_bytes_per_sec; an armed
-    file feeds tracing.dcn_spec with source='calibrated', outranking the
-    APEX_TPU_PEAK_DCN_GBPS env knob."""
-    from apex_tpu.monitor import tracing
-
-    recs = [_run_record(
-        wall=0.1,
-        measured={"timeline": {"tiers": {"dcn_s": {"p50": 0.01}}}},
-        predicted={"dcn_bytes_per_step": 2.5e7}) for _ in range(3)]
-    fit = calibrate.fit(recs)
-    assert fit["peak_dcn_bytes_per_sec"] == 2.5e9  # 2.5e7 B / 0.01 s
-    assert fit["n_records"]["peak_dcn_bytes_per_sec"] == 3
-    path = str(tmp_path / "cal.json")
-    calibrate.save(path, fit)
-    monkeypatch.setenv("APEX_TPU_PEAK_DCN_GBPS", "9.9")  # outranked
-    monkeypatch.setenv(calibrate.ENV_CALIBRATION, path)
-    spec = tracing.dcn_spec("tpu v4")
-    assert spec["dcn_bytes_per_sec"] == 2.5e9
-    assert spec["source"] == "calibrated"
-    monkeypatch.delenv(calibrate.ENV_CALIBRATION)
-    spec = tracing.dcn_spec("tpu v4")
-    assert spec["dcn_bytes_per_sec"] == 9.9e9
-    assert spec["source"] == "env"
-
-
 def test_calibration_file_outranks_peak_env(tmp_path, monkeypatch):
     from apex_tpu.monitor import mfu, tracing
 

@@ -672,90 +672,6 @@ def test_zero_redundancy_on_real_mixed_precision_step(zero_amp_step_irs):
 
 
 # ---------------------------------------------------------------------------
-# engine 2: flat-DCN collective tripwire (ISSUE 19)
-# ---------------------------------------------------------------------------
-
-
-def test_flat_dcn_flags_tuple_axis_bulk_collective():
-    def pod_flat(g):
-        return lax.psum(g, ("dcn", "data")) * 2.0  # full payload over DCN
-
-    hz = trace.flat_dcn_collective_hazards(
-        pod_flat, jnp.ones((64, 128)), axes={"dcn": 2, "data": 4})
-    assert hz["hazard"] and hz["flat_collectives"] == 1
-    assert hz["findings"][0]["rule"] == "flat-dcn-collective"
-    assert "hierarchy" in hz["findings"][0]["message"]
-    assert hz["census"]["flat"] == {"psum": 1}
-
-
-def test_flat_dcn_passes_staged_and_scalar():
-    """The hierarchical decomposition passes — every hierarchy stage
-    binds ONE axis, so the DCN hop lands in census['staged'] — and
-    scalar collectives spanning both tiers (global loss pmean, found_inf
-    pmax) are exempt under census['other']: 4 bytes cross the DCN
-    either way."""
-    from apex_tpu.parallel.hierarchy import hier_pmean, hier_psum
-
-    def staged(g):
-        full = hier_psum(g, "dcn", "data")
-        mean = hier_pmean(g, "dcn", "data")
-        loss = lax.pmean(jnp.sum(full), ("dcn", "data"))
-        bad = lax.pmax(jnp.float32(0.0), ("dcn", "data"))
-        return jnp.sum(mean) + loss + bad
-
-    # the DCN hop carries 1/n_ici of the payload by construction, so the
-    # bulk floor scales down with it at these tiny shapes (8192/4 elems)
-    hz = trace.flat_dcn_collective_hazards(
-        staged, jnp.ones((64, 128)), axes={"dcn": 2, "data": 4},
-        min_bulk_elems=1024)
-    assert not hz["hazard"], hz
-    assert not hz["census"]["flat"]
-    assert hz["census"]["staged"].get("psum", 0) >= 2  # the DCN hops
-    assert hz["census"]["other"].get("pmax") == 1
-    assert hz["census"]["other"].get("psum") == 1  # pmean lowers to psum
-
-
-def test_flat_dcn_on_real_hierarchical_zero_step():
-    """The actual two-tier optimizer step
-    (MixedPrecisionOptimizer(zero_axis=..., dcn_axis=..., dcn_wire=...))
-    traces clean — its scatter/gather stage per axis — while the SAME
-    step under the flat tuple-axis group (zero_axis=("dcn", "data")) is
-    exactly the flagged regression: every bulk chunk collective binds
-    the DCN axis jointly with the island axis."""
-    from apex_tpu import amp
-    from apex_tpu.optimizers import FusedAdam
-
-    params = {"w": jnp.zeros((64, 64), jnp.float32)}
-    gw = jnp.zeros((1, 64, 64), jnp.float32)
-
-    def step_of(mp):
-        def step(p, g0):
-            st = mp.init(p)
-            g = {"w": g0[0] * st.scaler.loss_scale}
-            new_p, _st, m = mp.apply_gradients(st, p, g)
-            return new_p, m["loss_scale"]
-
-        return step
-
-    # the staged chunks are 1/n_ici of the 4096-elem leaf: floor 1024
-    axes = {"dcn": 2, "data": 4}
-    flat_mp = amp.MixedPrecisionOptimizer(
-        FusedAdam(lr=1e-3), amp.get_policy("O2"),
-        zero_axis=("dcn", "data"))
-    hz = trace.flat_dcn_collective_hazards(
-        step_of(flat_mp), params, gw, axes=axes, min_bulk_elems=1024)
-    assert hz["hazard"] and hz["flat_collectives"] >= 2, hz
-
-    hier_mp = amp.MixedPrecisionOptimizer(
-        FusedAdam(lr=1e-3), amp.get_policy("O2"), zero_axis="data",
-        dcn_axis="dcn", dcn_wire="int8")
-    hz = trace.flat_dcn_collective_hazards(
-        step_of(hier_mp), params, gw, axes=axes, min_bulk_elems=1024)
-    assert not hz["hazard"], hz
-    assert hz["census"]["staged"], hz
-
-
-# ---------------------------------------------------------------------------
 # engine 2: ZeRO-3 bulk-gather tripwire
 # ---------------------------------------------------------------------------
 

@@ -19,8 +19,7 @@ def _clean_peak_env(monkeypatch):
     """The picks are blind: no shell-leaked peak overrides or armed
     calibration file may skew the modeled clocks."""
     for k in ("APEX_TPU_PEAK_FLOPS", "APEX_TPU_PEAK_HBM_GBPS",
-              "APEX_TPU_PEAK_ICI_GBPS", "APEX_TPU_PEAK_DCN_GBPS",
-              "APEX_TPU_CALIBRATION"):
+              "APEX_TPU_PEAK_ICI_GBPS", "APEX_TPU_CALIBRATION"):
         monkeypatch.delenv(k, raising=False)
 
 
@@ -78,50 +77,15 @@ def test_blind_pick_int8_wire_only_where_ici_binds(monkeypatch):
     wnb = [x for x in r["rejected"]
            if x.get("rejected_by") == "wire-not-binding"]
     assert wnb and "int8" == wnb[0]["candidate"]["reduce_dtype"]
+    # so too the 13B analytic rung at mesh=64 and v4 datasheet clocks
+    big = plan_mod.search("gpt-13b", mesh=64, hbm_gb=16.0,
+                          num_microbatches=2, platform="v4")
+    assert big["winner"]["candidate"]["reduce_dtype"] is None
 
     monkeypatch.setenv("APEX_TPU_PEAK_ICI_GBPS", "0.001")
     narrowed = plan_mod.search("gpt-345m", mesh=8, hbm_gb=16.0,
                                constraints={"dp": 8, "zero_level": 2})
     assert narrowed["winner"]["candidate"]["reduce_dtype"] == "int8"
-
-
-def test_blind_pick_int8_dcn_wire_on_pod_rung(monkeypatch):
-    """The 13B analytic rung priced per tier (ISSUE 19): on the two-tier
-    8x8 pod layout at v4 datasheet clocks the inter-island hop binds, so
-    the winner blind-picks dcn_wire=int8 and the un-quantized shapes
-    carry the named dcn-bound provenance (with predicted per-tier bytes
-    — not the generic wire-not-binding); the flat mesh=64 search of the
-    SAME model stays fp32. A widened APEX_TPU_PEAK_DCN_GBPS flips the
-    pod verdict — the EQuARX rule, per tier."""
-    pod = plan_mod.search("gpt-13b", mesh=64, hbm_gb=16.0, islands=8,
-                          num_microbatches=2, platform="v4")
-    w = pod["winner"]
-    assert w["candidate"]["dcn_wire"] == "int8"
-    assert pod["dcn_spec"]["source"].startswith("table")
-    assert w["predicted"]["comm_bytes_by_tier"]["dcn"] > 0
-    bound = [x for x in pod["rejected"]
-             if x.get("rejected_by") == "dcn-bound"]
-    assert bound and all(x["candidate"]["dcn_wire"] is None
-                         for x in bound)
-    # the rejection is auditable: predicted per-tier bytes ride both the
-    # record and the reason text (the calibrate-join seam)
-    assert "dcn" in bound[0]["predicted"]["comm_bytes_by_tier"]
-    assert "dcn=" in bound[0]["reason"]
-
-    flat = plan_mod.search("gpt-13b", mesh=64, hbm_gb=16.0,
-                           num_microbatches=2, platform="v4")
-    fc = flat["winner"]["candidate"]
-    assert fc["dcn_wire"] is None and fc["islands"] == 1
-    assert fc["reduce_dtype"] is None
-    assert "dcn" not in flat["winner"]["predicted"]["comm_bytes_by_tier"]
-
-    # widen the modeled DCN and the SAME pod search keeps the exact wire
-    monkeypatch.setenv("APEX_TPU_PEAK_DCN_GBPS", "1000")
-    wide = plan_mod.search("gpt-13b", mesh=64, hbm_gb=16.0, islands=8,
-                           num_microbatches=2, platform="v4")
-    assert wide["winner"]["candidate"]["dcn_wire"] is None
-    assert not any(x.get("rejected_by") == "dcn-bound"
-                   for x in wide["rejected"])
 
 
 # ---------------------------------------------------------------------------
