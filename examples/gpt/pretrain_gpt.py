@@ -12,6 +12,11 @@ Run on 8 virtual devices:
         python examples/gpt/pretrain_gpt.py --tp 2 --pp 2 --steps 10
 Run serial on one real TPU chip:
     python examples/gpt/pretrain_gpt.py --tp 1 --pp 1 --steps 10
+
+The ``train_step`` that ``main`` returns consumes the state it is given
+(``params`` and ``opt_state`` are donated): a caller steps it as
+``params, opt_state, loss, metrics = train_step(params, opt_state, ...)``
+and never reads the trees it handed in again.
 """
 
 from __future__ import annotations
@@ -476,6 +481,12 @@ def main(argv=None):
                 pipe_value_and_grad=zb_vg)
     else:
         opt_state = mp_opt.init(params)
+        # the masters of the leaves O2 keeps in float32 (the norms) are the
+        # params' own arrays, and a donated step cannot be handed one
+        # buffer twice: those masters get buffers of their own
+        shared = {id(a) for a in jax.tree.leaves(params)}
+        opt_state = jax.tree.map(
+            lambda a: jnp.copy(a) if id(a) in shared else a, opt_state)
         shard_fn = jax.shard_map(
             sharded_grads, mesh=mesh,
             in_specs=(specs, data_spec, data_spec, P()),
@@ -497,7 +508,13 @@ def main(argv=None):
         # compile a second time — inside the timed steps. Commit the
         # state to the mesh and pin the step's outputs to its inputs'
         # shardings: the placement is the same, and so is the cache key.
-        # (The two-program traced drive is host code.)
+        # Every leaf of the state then has a successor of its own shape,
+        # type and placement, so the state is donated whole and updated
+        # in place. Held twice, it cost a copy of the float32 masters and
+        # moments at the top of every step, and at GPT-2 345M on one chip
+        # pushed the step over the compiler's memory budget (PERF.md,
+        # Findings, PR 31). (The two-program traced drive is host code
+        # with no `.lower`: it stays as it is, nothing donated.)
         replicated = NamedSharding(mesh, P())
         params, opt_state = jax.tree.map(
             lambda a: a if isinstance(a.sharding, NamedSharding)
@@ -505,7 +522,8 @@ def main(argv=None):
         state_shardings = jax.tree.map(lambda a: a.sharding,
                                        (params, opt_state))
         train_step = jax.jit(train_step,
-                             out_shardings=(*state_shardings, None, None))
+                             out_shardings=(*state_shardings, None, None),
+                             donate_argnums=(0, 1))
 
     if args.data:
         from apex_tpu.csrc import TokenLoader

@@ -44,7 +44,7 @@ def test_train_phase_small(smoke, monkeypatch, tmp_path):
     assert len(facts["losses"]) == 3 and facts["skipped_steps"] <= 1
     assert facts["step_seconds_block_until_ready"] > 0
     assert facts["step_seconds_host_fetch"] > 0
-    assert facts["donated_program_bytes"]["total"] > 0
+    assert facts["program_bytes"]["alias"] > 0
     assert jax.config.jax_compilation_cache_dir is None
 
 
