@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from .. import compare, flops, harness, manifest, programs, trace_reduce
+from .. import compare, harness, manifest, programs, trace_reduce
 from ..references import train as ref_train
 
 #: how many first steps the reference follows
@@ -148,8 +148,13 @@ def run(cell: dict, *, root: str, seed: int, seconds: float, trace: bool,
     loop.free()
     program.free()
 
+    t_ref = time.perf_counter()
     ref = ref_train.follow(cfg["reference"], cfg, mix, seed,
                            pool[:FOLLOWED], steps=FOLLOWED)
+    # the process's peak never falls: this is the larger of the two
+    print(f"reference: {time.perf_counter() - t_ref:.1f} s, peak "
+          f"{harness.memory_peak_bytes()} bytes (the program's own: {peak})",
+          file=sys.stderr)
     readings = compare.train_readings(
         got, ref, manifest.part_groups(root, cell))
     print(f"readings: {readings}", file=sys.stderr)
@@ -172,10 +177,11 @@ def run(cell: dict, *, root: str, seed: int, seconds: float, trace: bool,
         ctx = trace_reduce.context(
             capture.events, hlo_text=hlo, module=mix["step_module"],
             host_spans=("batch_fetch", "dispatch", "wait_step"))
+        work = harness.flops_module(cfg)
         ctx.update(cfg=cfg, mix=mix, chips=chips, tokens=tokens,
                    rows=program.rows, causal=fam.CAUSAL,
-                   peak=harness.peaks()[device["kind"]],
-                   flops_per_token=flops.train_flops_per_token(
+                   peak=harness.peaks()[device["kind"]], flops=work,
+                   flops_per_token=work.train_flops_per_token(
                        cfg, mix["seq"], causal=fam.CAUSAL,
                        head_positions=fam.head_positions(mix)))
         metrics = harness.read_metrics(cell, ctx, root)

@@ -47,6 +47,11 @@ def train_flops_per_token(cfg: dict, seq: int, *, causal: bool,
         cfg, seq, causal=causal, head_positions=head_positions)
 
 
+def attention_layers(cfg: dict) -> int:
+    """Layers that run ``attention_core``: every one."""
+    return transformer_sizes(cfg)["layers"]
+
+
 def attention_core(cfg: dict, rows: int, seq: int, *, causal: bool,
                    backward: bool, bytes_per_el: int = 2) -> dict:
     """softmax(Q K^T) V over all heads of one layer for ``rows`` sequences.

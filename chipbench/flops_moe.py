@@ -13,6 +13,8 @@ and the sort that fills them are not work.
 
 from __future__ import annotations
 
+from . import flops
+
 
 def sizes(cfg: dict) -> dict:
     """The reference family's reading of the keys, with the layers by kind,
@@ -33,7 +35,8 @@ def assignments_per_token(cfg: dict) -> float:
     return z["top_k"] * z["held"] / z["experts"]
 
 
-def forward_parts_per_token(cfg: dict, seq: int) -> dict:
+def forward_parts_per_token(cfg: dict, seq: int, *, causal: bool = True,
+                            head_positions: float = 1.0) -> dict:
     """Multiply-adds x 2 of one token's forward pass, part by part."""
     z = sizes(cfg)
     h, nh = z["hidden"], z["heads"]
@@ -41,8 +44,8 @@ def forward_parts_per_token(cfg: dict, seq: int) -> dict:
     projections = 2 * (h * nh * z["qk"] + h * (z["latent"] + z["rope"])
                        + z["latent"] * nh * (z["nope"] + z["v"])
                        + h * nh * z["v"] + nh * z["v"] * h)
-    # QK^T and PV against seq keys, halved by the causal mask
-    core = 0.5 * 2 * seq * nh * (z["qk"] + z["v"])
+    # QK^T and PV against seq keys, halved by a causal mask
+    core = (0.5 if causal else 1.0) * 2 * seq * nh * (z["qk"] + z["v"])
     layers = z["dense_layers"] + z["expert_layers"]
     return {
         "attention_projections": layers * projections,
@@ -52,13 +55,24 @@ def forward_parts_per_token(cfg: dict, seq: int) -> dict:
         "router": z["expert_layers"] * 2 * h * z["experts"],
         "routed_experts": z["expert_layers"] * assignments_per_token(cfg)
         * gated(z["expert_ffn"]),
-        "head": 2 * z["vocab"] * h,
+        "head": head_positions * 2 * z["vocab"] * h,
     }
 
 
-def train_flops_per_token(cfg: dict, seq: int) -> float:
+def train_flops_per_token(cfg: dict, seq: int, *, causal: bool = True,
+                          head_positions: float = 1.0) -> float:
     """Forward plus backward (twice the forward); recompute not counted."""
-    return 3.0 * sum(forward_parts_per_token(cfg, seq).values())
+    return 3.0 * sum(forward_parts_per_token(
+        cfg, seq, causal=causal, head_positions=head_positions).values())
+
+
+#: the attention core and the layers that run it, as ``flops.py`` counts
+#: them: every layer attends, and this model's heads are as wide for values
+#: (``v_head_dim``) as for queries and keys (``qk_nope_head_dim +
+#: qk_rope_head_dim``): ``hidden_size // num_attention_heads``, all 128. A
+#: family whose two widths differ writes its own.
+attention_layers = flops.attention_layers
+attention_core = flops.attention_core
 
 
 def grouped_products(cfg: dict, tokens: int, *, bytes_per_el: int = 2) -> dict:

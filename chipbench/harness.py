@@ -28,6 +28,19 @@ def peaks() -> dict:
     return manifest.load_json(os.path.join(HERE, "peaks.json"))
 
 
+#: what a configuration's flops module has to give
+FLOPS_FUNCTIONS = ("train_flops_per_token", "attention_layers",
+                   "attention_core")
+
+
+def flops_module(cfg: dict):
+    """The module of ``chipbench/`` that counts what this configuration's
+    algorithm needs: the one its file names under ``"flops"``, else
+    ``flops.py``."""
+    return importlib.import_module(
+        f"{__package__}.{cfg.get('flops', 'flops')}")
+
+
 def device_info() -> dict:
     """The device as JAX reports it."""
     import jax

@@ -1,6 +1,7 @@
 """The whole step's share of the chips' peak: the operations the algorithm
-needs for the tokens of the traced window (``flops.py``; recompute and
-padding are not work) over the traced window times the chips' peak rate."""
+needs for the tokens of the traced window (``train_flops_per_token`` of the
+configuration's flops module; recompute and padding are not work) over the
+traced window times the chips' peak rate."""
 
 
 def read(ctx):

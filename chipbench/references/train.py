@@ -94,6 +94,14 @@ def _tree_add(acc, g):
     return jax.tree.map(jnp.add, acc, g)
 
 
+def _to_host(tree):
+    """``tree`` as numpy arrays, its device buffers freed at once."""
+    host = jax.device_get(tree)
+    for leaf in jax.tree.leaves(tree):
+        leaf.delete()
+    return host
+
+
 def optimizer(name: str):
     """``(init, update, seen)`` of a published optimizer: ``seen(grads,
     **options)`` is the gradient as its moments get it."""
@@ -108,29 +116,52 @@ def optimizer(name: str):
     raise ValueError(f"no reference optimizer {name!r}")
 
 
-def follow(model: str, cfg: dict, mix: dict, seed: int, batches, *,
-           steps: int, precision: str = "float32", rows_kept=None,
-           block_rows: int = 2) -> dict:
-    """``batches``: the first ``steps`` batches (dicts of numpy arrays) the
-    timed step was fed. Returns numpy readings."""
-    fam = family(model)
-    fused = functools.partial(fam.fused_parts, cfg)
-    opt = dict(mix["optimizer"])
-    init_opt, update, seen = optimizer(opt.pop("name"))
-    w0 = jax.jit(lambda k: jax.tree.map(
-        lambda a: a.astype(jnp.float32),
-        fam.init_weights(cfg, k, jnp.dtype(mix["weights_dtype"]))))(
-            common.seed_key(seed))
-    w = jax.tree.map(jnp.copy, w0)
-    state = init_opt(w)
-
+def block_gradient(fam, cfg: dict, precision: str):
+    """``grad_block(w, block, inv_den)``: a block of rows' share of the
+    batch's loss and its gradient."""
     @jax.jit
     def grad_block(w, block, inv_den):
         def f(w):
             nums = fam.loss_numerators(cfg, w, block, precision)
             return jnp.sum(nums * inv_den)
         return jax.value_and_grad(f)(w)
+    return grad_block
 
+
+#: rows of the batch in a block of the gradient's sum: at 2 x 4096 tokens a
+#: block's scratch is 0.33 GB beside the trees (the expert model's cell)
+BLOCK_ROWS = 2
+
+
+def follow(model: str, cfg: dict, mix: dict, seed: int, batches, *,
+           steps: int, precision: str = "float32", rows_kept=None) -> dict:
+    """``batches``: the first ``steps`` batches (dicts of numpy arrays) the
+    timed step was fed. Returns numpy readings.
+
+    The gradient is summed over blocks of ``BLOCK_ROWS`` rows. At most four
+    float32 trees of the model are alive on the device at a time: the
+    weights, the sum of the gradient, and either one block's gradient or
+    the two moments, which wait on the host while the gradient is summed.
+    The seeded weights are not kept: they are made again at the end, by the
+    same program from the same key.
+
+    Four is what the count of live arrays says, and a test holds that. What
+    the chip allocates also rests on the two ``block_until_ready`` lines
+    below, which no test can hold: the CPU's count of live arrays is the
+    same without them. On the chip, without them, the process's peak read
+    five and a half and six trees where it reads four (``PERF.md``,
+    Findings, PR 32): read the driver's ``reference: ... peak`` line on
+    standard error after any change here."""
+    fam = family(model)
+    fused = functools.partial(fam.fused_parts, cfg)
+    opt = dict(mix["optimizer"])
+    init_opt, update, seen = optimizer(opt.pop("name"))
+    seeded = jax.jit(lambda k: jax.tree.map(
+        lambda a: a.astype(jnp.float32),
+        fam.init_weights(cfg, k, jnp.dtype(mix["weights_dtype"]))))
+    w = seeded(common.seed_key(seed))
+    state = None
+    grad_block = block_gradient(fam, cfg, precision)
     losses, grad_norms, grad_sample = [], None, None
     for i in range(steps):
         batch = batches[i]
@@ -139,12 +170,17 @@ def follow(model: str, cfg: dict, mix: dict, seed: int, batches, *,
         inv_den = jnp.asarray(1.0 / fam.denominators(batch), jnp.float32)
         rows = len(next(iter(batch.values())))
         loss, grads = 0.0, None
-        for r in range(0, rows, block_rows):
-            block = {k: jnp.asarray(v[r:r + block_rows])
+        for r in range(0, rows, BLOCK_ROWS):
+            block = {k: jnp.asarray(v[r:r + BLOCK_ROWS])
                      for k, v in batch.items()}
             l, g = grad_block(w, block, inv_den)
             loss += float(l)
             grads = g if grads is None else _tree_add(grads, g)
+            # or two blocks' gradients are alive in the next call: buffers
+            # are found when a call is dispatched, and a tree that a
+            # running sum still reads is not free yet
+            del g
+            jax.block_until_ready(grads)
         losses.append(loss)
         if i == 0:
             g = seen(grads, **opt)
@@ -152,8 +188,15 @@ def follow(model: str, cfg: dict, mix: dict, seed: int, batches, *,
             grad_sample = jax.device_get(
                 leaf_samples(g, fused, sample_key(seed)))
             del g
+        # the moments: made for the first update, on the host between two
+        state = init_opt(w) if state is None else jax.device_put(state)
         w, state = update(w, state, grads, **opt)
         del grads
+        if i + 1 < steps:
+            state = _to_host(state)
+    jax.block_until_ready(w)    # the update's operands are free by now
+    del state
+    w0 = seeded(common.seed_key(seed))
     update_norms = jax.device_get(leaf_norms(_tree_sub(w, w0), fused))
     return {"losses": np.asarray(losses, np.float64),
             "grad_norms": grad_norms, "grad_sample": grad_sample,

@@ -7,6 +7,9 @@ Run it before the first chip call and after every change to the manifest:
 (a) ``BENCHMARK.json`` and every file it names against the contract's rules
     of form, and again with the tiny cells added the way a later PR adds a
     cell (files and entries of their own, no edit to a file that is there);
+    every configuration's flops module (its ``"flops"`` key, else
+    ``flops.py``) is there and has the functions the driver and the readers
+    call;
 (b) the trace reduction on the recorded trace under ``tests/``, against
     numbers worked out by hand beside it;
 (c) each driver end to end at a tiny size, checking the result line's keys
@@ -26,19 +29,38 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from chipbench import manifest, trace_reduce  # noqa: E402
+from chipbench import harness, manifest, trace_reduce  # noqa: E402
 
 RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device",
                "checks")
 
 
-def check_manifest() -> list:
-    from chipbench.tests import tiny
+def flops_problems(bench: dict) -> list:
+    """A configuration whose flops module is missing or lacks a function
+    would end its first traced run on the chip."""
+    bad = []
+    for c in bench["configs"]:
+        cfg = manifest.load_json(os.path.join(ROOT, c["file"]))
+        try:
+            module = harness.flops_module(cfg)
+        except ImportError as e:
+            bad.append(f"config {c['name']!r}: its flops module: {e}")
+            continue
+        bad += [f"config {c['name']!r}: {module.__name__} has no {f}()"
+                for f in harness.FLOPS_FUNCTIONS
+                if not callable(getattr(module, f, None))]
+    return bad
 
+
+def check_manifest() -> list:
+    from chipbench.tests import tiny_instella
+
+    tiny_bench = tiny_instella.bench(ROOT)
     bad = [f"BENCHMARK.json: {p}"
            for p in manifest.problems(manifest.load(ROOT), ROOT)]
     bad += [f"with the tiny cells: {p}"
-            for p in manifest.problems(tiny.tiny_bench(ROOT), ROOT)]
+            for p in manifest.problems(tiny_bench, ROOT)]
+    bad += flops_problems(tiny_bench)
     for w in manifest.load(ROOT)["workloads"]:
         try:
             manifest.limits(ROOT, manifest.cell(manifest.load(ROOT),

@@ -11,7 +11,7 @@ from chipbench import compare, manifest
 from chipbench.drivers import train
 from chipbench.references import train as ref_train
 from chipbench.tests import tiny_instella
-from chipbench.tests.test_correct import _HalfBatch, _program
+from chipbench.tests.test_correct import _HalfBatch, _program, _Unchanged
 
 
 def test_manifest_with_the_tiny_cell_has_no_problem_of_form():
@@ -42,29 +42,6 @@ def test_int8_control_reads_over_the_limits():
         for name in ("grad_sample_diff.dense_parts",
                      "grad_diff_over.dense_parts", "update_norm_gap"):
             assert readings[name] > limits[name], (seed, name, readings)
-
-
-class _Unchanged:
-    """A step that computes its loss and returns its state as it was. The
-    trainer's step donates its state, so the state handed back is a copy
-    made before the call."""
-
-    def __init__(self, program):
-        import jax
-        import jax.numpy as jnp
-
-        self._p = program
-        inner = program.step
-
-        def step(params, opt_state, *batch):
-            kept = jax.tree.map(jnp.copy, (params, opt_state))
-            _, _, loss, metrics = inner(params, opt_state, *batch)
-            return (*kept, loss, metrics)
-
-        self.step = step
-
-    def __getattr__(self, name):
-        return getattr(self._p, name)
 
 
 @pytest.mark.parametrize("fault", [_Unchanged, _HalfBatch])
