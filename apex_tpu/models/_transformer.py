@@ -683,3 +683,47 @@ class TransformerBase:
         if per_layer:
             aux = each
         return (h, aux) if return_aux else h
+
+    # -- a stack whose pattern is data ---------------------------------------
+
+    def init_pattern(self, key, kinds, layer_init) -> Params:
+        """The layers of a stack whose pattern is a list: ``kinds`` names
+        each layer's kind in order (any hashable), ``layer_init(key, kind)``
+        makes one layer's tree. Runs of like consecutive layers
+        (:func:`layer_runs`) are stacked on a leading axis, one stack a run,
+        under the run's index as two digits (``"00"``, ``"01"``, ...: the
+        names sort in the stack's order)."""
+        keys = jax.random.split(key, len(kinds))
+        return {f"{r:02d}": jax.vmap(lambda k, kind=kind: layer_init(k, kind))(
+                    keys[first:first + count])
+                for r, (kind, first, count) in enumerate(layer_runs(kinds))}
+
+    def run_pattern(self, stacks: Params, h, attn_bias=None,
+                    dropout_key=None):
+        """``h`` through :meth:`init_pattern`'s stacks in the order of their
+        names, each run one scan of :meth:`run_layers` under the model's
+        recompute policy: the compiled step grows with the runs of the
+        pattern, not with its depth, and no pattern needs a class of its
+        own (what kind a layer is shows in the tree ``_layer_aux`` is
+        given). Returns ``(h, [each run's aux])``."""
+        names = sorted(stacks)
+        keys = ([None] * len(names) if dropout_key is None
+                else jax.random.split(dropout_key, len(names)))
+        auxes = []
+        for name, key in zip(names, keys):
+            h, aux = self.run_layers(stacks[name], h, attn_bias, key,
+                                     return_aux=True)
+            auxes.append(aux)
+        return h, auxes
+
+
+def layer_runs(kinds) -> list:
+    """``[(kind, first, count)]``: the runs of like consecutive entries of
+    ``kinds``, one layer's kind each."""
+    runs = []
+    for i, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, i, 1])
+    return [tuple(r) for r in runs]

@@ -605,6 +605,7 @@ def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, b_ref, ks_ref, qs_ref, bnd_ref,
     off_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     *, scale, causal, blk_q, blk_k, pad_id, window=None, rows=None,
+    group=1, acc_refs=(),
 ):
     """dK and dV of one k block, in the TRANSPOSED domain: the scores are
     computed as ``S^T = K Q^T`` (k along rows), so ``dV = P^T dO`` and
@@ -613,7 +614,9 @@ def _bwd_dkv_kernel(
     ``dS`` with q along rows, both products contract their left operand's
     rows: two ``(blk_q, blk_k)`` transposes a tile.) Segment ids arrive the
     other way round for it: k ids lane-replicated ``(blk_k, 128)``, q ids
-    sublane-replicated ``(8, sq)``."""
+    sublane-replicated ``(8, sq)``. With grouped-query heads (``group`` >
+    1) the grid's last axis walks the query heads that read this key-value
+    head and ``acc_refs`` sum their parts."""
     k = k_ref[0, 0].astype(jnp.float32)  # (blk_k, d)
     v = v_ref[0, 0].astype(jnp.float32)
     sq = q_ref.shape[2]
@@ -695,9 +698,18 @@ def _bwd_dkv_kernel(
             for i, masked in tiles:
                 carry = tile(i, carry, i * blk_q, ki * blk_k, masked)
         dk, dv = init if carry is None else carry
-        dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+        if group == 1:
+            dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+            dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+            return
+        for acc, part, out in zip(acc_refs, (dk, dv), (dk_ref, dv_ref)):
+            acc[...] = jnp.where(gi == 0, part, acc[...] + part)
 
+            @pl.when(gi == group - 1)
+            def _write(acc=acc, out=out):
+                out[0, 0] = acc[...].astype(out.dtype)
+
+    gi = pl.program_id(3) if group > 1 else None
     _walk(rows, pl.program_id(2), row)
 
 
@@ -852,14 +864,20 @@ def _bwd_dkv_kernel_stream(q_ref, k_ref, v_ref, qs_ref, ks_ref, qmm_ref,
                            kmm_ref, bnd_ref, off_ref, do_ref, lse_ref,
                            delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
                            *, scale, causal, blk_q, blk_k, pad_id, nq,
-                           window=None, q_base=None, lse_group=1):
+                           window=None, q_base=None, lse_group=1, group=1):
     ki = pl.program_id(2)
-    qi_raw = pl.program_id(3)
+    # grouped-query heads put the group's query heads on axis 3, ahead of
+    # the q blocks: the accumulators then run over both
+    inner = 3 if group == 1 else 4
+    qi_raw = pl.program_id(inner)
     qi = q_base(ki) + qi_raw if q_base is not None else qi_raw
     q_off = off_ref[0] if off_ref is not None else 0
     k_off = off_ref[1] if off_ref is not None else 0
+    first = qi_raw == 0
+    if group > 1:
+        first &= pl.program_id(3) == 0
 
-    @pl.when(qi_raw == 0)
+    @pl.when(first)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
@@ -912,7 +930,11 @@ def _bwd_dkv_kernel_stream(q_ref, k_ref, v_ref, qs_ref, ks_ref, qmm_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi_raw == pl.num_programs(3) - 1)
+    last = qi_raw == pl.num_programs(inner) - 1
+    if group > 1:
+        last &= pl.program_id(3) == group - 1
+
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
@@ -953,6 +975,31 @@ def _dq_grid_order(bias, b_bcast, h_bcast):
 def _offsets_spec():
     """SMEM spec for the (q_off, k_off) global-position scalars."""
     return pl.BlockSpec((2,), lambda *_: (0,), memory_space=pltpu.SMEM)
+
+
+def _kv_head(group: int):
+    """The key-value head that query head ``hi`` reads, for the index maps:
+    ``group`` query heads in a row share one (grouped-query attention), so K
+    and V keep their own ``heads // group`` heads and are never repeated in
+    memory. Equal heads keep the identity, and their index maps trace as
+    they always did."""
+    return ((lambda hi: hi) if group == 1
+            else (lambda hi: jax.lax.div(hi, jnp.int32(group))))
+
+
+def _group_axis(group: int):
+    """``(of_q, of_kv)`` for the dK/dV passes of grouped-query attention,
+    whose grids walk the key-value heads and, one axis further in, the
+    ``group`` query heads that read each: an index map written over
+    ``(bi, hi, ki[, qi])`` is wrapped to take the grid's ``(bi, hk, ki, gi[,
+    qi])``, ``hi`` being the query head for the operands that have one
+    (``of_q``) and the key-value head for the rest (``of_kv``). Equal heads:
+    the maps as they are."""
+    if group == 1:
+        return (lambda f: f), (lambda f: f)
+    return ((lambda f: lambda bi, hk, ki, gi, *qi: f(bi, hk * group + gi, ki,
+                                                     *qi)),
+            (lambda f: lambda bi, hk, ki, gi, *qi: f(bi, hk, ki, *qi)))
 
 
 def _seg_layouts(q_seg, kv_seg):
@@ -1053,7 +1100,9 @@ def _flash_fwd(q, k, v, bias, offsets, q_seg=None, kv_seg=None, *,
     lse_g = _lse_group(nq)
     qspec = pl.BlockSpec((1, 1, blk_q, d), lambda bi, hi, qi: (bi, hi, qi, 0),
                          memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, 1, sk, d), lambda bi, hi, qi: (bi, hi, 0, 0),
+    kvh = _kv_head(h // k.shape[1])
+    kspec = pl.BlockSpec((1, 1, sk, d),
+                         lambda bi, hi, qi: (bi, kvh(hi), 0, 0),
                          memory_space=pltpu.VMEM)
     ospec = qspec
     lspec = pl.BlockSpec((1, 1, lse_g, blk_q),
@@ -1132,11 +1181,12 @@ def _flash_fwd_stream(q, k, v, offsets, q_seg, kv_seg, *, scale, causal,
     nkw, k_base, kmap = _window_grid_maps(blk_q, blk_k, nk, causal, window,
                                           offsets)
     grid = (b, h, nq, nkw)
+    kvh = _kv_head(h // k.shape[1])
     qspec = pl.BlockSpec((1, 1, blk_q, d),
                          lambda bi, hi, qi, kj: (bi, hi, qi, 0),
                          memory_space=pltpu.VMEM)
     kspec = pl.BlockSpec((1, 1, blk_k, d),
-                         lambda bi, hi, qi, kj: (bi, hi, kmap(qi, kj), 0),
+                         lambda bi, hi, qi, kj: (bi, kvh(hi), kmap(qi, kj), 0),
                          memory_space=pltpu.VMEM)
     # lse travels as a DENSE (b, h, nq, blk_q) table — a (b, h, sq, 1)
     # custom-call operand gets the T(8, 128) layout, which lane-pads the
@@ -1254,11 +1304,13 @@ def _flash_bwd_stream(q, k, v, offsets, o, lse, do, q_seg, kv_seg, *,
                                           offsets)
 
     # dQ pass
+    group = h // k.shape[1]
+    kvh = _kv_head(group)
     qspec = pl.BlockSpec((1, 1, blk_q, d),
                          lambda bi, hi, qi, kj: (bi, hi, qi, 0),
                          memory_space=pltpu.VMEM)
     kspec = pl.BlockSpec((1, 1, blk_k, d),
-                         lambda bi, hi, qi, kj: (bi, hi, kmap(qi, kj), 0),
+                         lambda bi, hi, qi, kj: (bi, kvh(hi), kmap(qi, kj), 0),
                          memory_space=pltpu.VMEM)
     lse_g = _lse_group(nq)
     lblk = pl.BlockSpec((1, 1, lse_g, blk_q),
@@ -1324,35 +1376,43 @@ def _flash_bwd_stream(q, k, v, offsets, o, lse, do, q_seg, kv_seg, *,
     # dK/dV pass
     nqw, q_base, qmap = _window_grid_maps(blk_k, blk_q, nq, causal, window,
                                           offsets, inner_is_k=False)
+    # grouped-query heads: the grid walks the key-value heads and, ahead of
+    # the q blocks, the query heads of each one's group
+    of_q, of_kv = _group_axis(group)
     qspec2 = pl.BlockSpec((1, 1, blk_q, d),
-                          lambda bi, hi, ki, qi: (bi, hi, qmap(ki, qi), 0),
+                          of_q(lambda bi, hi, ki, qi: (bi, hi, qmap(ki, qi),
+                                                       0)),
                           memory_space=pltpu.VMEM)
     kspec2 = pl.BlockSpec((1, 1, blk_k, d),
-                          lambda bi, hi, ki, qi: (bi, hi, ki, 0),
+                          of_kv(lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
                           memory_space=pltpu.VMEM)
     lblk2 = pl.BlockSpec((1, 1, lse_g, blk_q),
-                         lambda bi, hi, ki, qi: (bi, hi,
-                                                 qmap(ki, qi) // lse_g, 0),
+                         of_q(lambda bi, hi, ki, qi: (bi, hi,
+                                                      qmap(ki, qi) // lse_g,
+                                                      0)),
                          memory_space=pltpu.VMEM)
     in_specs2 = [qspec2, kspec2, kspec2]
     args2 = [q, k, v]
     if has_seg:
         in_specs2 += [
             pl.BlockSpec((1, blk_q, _NUM_LANES),
-                         lambda bi, hi, ki, qi: (bi, qmap(ki, qi), 0),
+                         of_kv(lambda bi, hi, ki, qi: (bi, qmap(ki, qi), 0)),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, _NUM_SUBLANES, blk_k),
-                         lambda bi, hi, ki, qi: (bi, 0, ki),
+                         of_kv(lambda bi, hi, ki, qi: (bi, 0, ki)),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 2, nq), lambda bi, hi, ki, qi: (bi, 0, 0),
+            pl.BlockSpec((1, 2, nq),
+                         of_kv(lambda bi, hi, ki, qi: (bi, 0, 0)),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 2, nk), lambda bi, hi, ki, qi: (bi, 0, 0),
+            pl.BlockSpec((1, 2, nk),
+                         of_kv(lambda bi, hi, ki, qi: (bi, 0, 0)),
                          memory_space=pltpu.SMEM),
         ]
         args2 += [qs_l, ks_l, qmm, kmm]
         if has_bnd:
             in_specs2.append(
-                pl.BlockSpec((1, 2, nk), lambda bi, hi, ki, qi: (bi, 0, 0),
+                pl.BlockSpec((1, 2, nk),
+                             of_kv(lambda bi, hi, ki, qi: (bi, 0, 0)),
                              memory_space=pltpu.SMEM))
             args2.append(bounds_k)
     if has_off:
@@ -1380,11 +1440,12 @@ def _flash_bwd_stream(q, k, v, offsets, o, lse, do, q_seg, kv_seg, *,
                                scale=scale, causal=causal, blk_q=blk_q,
                                blk_k=blk_k, pad_id=pad_id, nq=nq,
                                window=window, q_base=q_base,
-                               lse_group=lse_g)
+                               lse_group=lse_g, group=group)
 
     dk, dv = pl.pallas_call(
         dkv_kern,
-        grid=(b, h, nk, nqw),
+        grid=((b, h, nk, nqw) if group == 1
+              else (b, h // group, nk, group, nqw)),
         in_specs=in_specs2,
         out_specs=[kspec2, kspec2],
         out_shape=[
@@ -1448,9 +1509,12 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
 
         return idx
 
+    group = h // k.shape[1]
+    kvh = _kv_head(group)
     qspec = pl.BlockSpec((1, 1, blk_q, d), reorder(lambda bi, hi, qi: (bi, hi, qi, 0)),
                          memory_space=pltpu.VMEM)
-    kfull = pl.BlockSpec((1, 1, sk, d), reorder(lambda bi, hi, qi: (bi, hi, 0, 0)),
+    kfull = pl.BlockSpec((1, 1, sk, d),
+                         reorder(lambda bi, hi, qi: (bi, kvh(hi), 0, 0)),
                          memory_space=pltpu.VMEM)
     lblk = pl.BlockSpec((1, 1, lse_g, blk_q),
                         reorder(lambda bi, hi, qi: (bi, hi, qi // lse_g, 0)),
@@ -1524,11 +1588,18 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
     dq, dbias = (res[0], res[1]) if bias is not None else (res[0], None)
 
     # dK/dV pass: grid over k blocks; q/do/lse/delta stream in full.
-    qfull = pl.BlockSpec((1, 1, sq, d), lambda bi, hi, ki: (bi, hi, 0, 0),
+    # Grouped-query heads: the grid walks the key-value heads and, one
+    # axis further in, the query heads of each one's group, whose dK and dV
+    # are summed in float32 scratch before the block is written.
+    of_q, of_kv = _group_axis(group)
+    qfull = pl.BlockSpec((1, 1, sq, d),
+                         of_q(lambda bi, hi, ki: (bi, hi, 0, 0)),
                          memory_space=pltpu.VMEM)
-    kblk = pl.BlockSpec((1, 1, blk_k, d), lambda bi, hi, ki: (bi, hi, ki, 0),
+    kblk = pl.BlockSpec((1, 1, blk_k, d),
+                        of_kv(lambda bi, hi, ki: (bi, hi, ki, 0)),
                         memory_space=pltpu.VMEM)
-    lfull = pl.BlockSpec((1, 1, nq, blk_q), lambda bi, hi, ki: (bi, hi, 0, 0),
+    lfull = pl.BlockSpec((1, 1, nq, blk_q),
+                         of_q(lambda bi, hi, ki: (bi, hi, 0, 0)),
                          memory_space=pltpu.VMEM)
     in_specs2 = [qfull, kblk, kblk]
     args2 = [q, k, v]
@@ -1536,7 +1607,8 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
         bb, bh = bias.shape[0], bias.shape[1]
         bspec2 = pl.BlockSpec(
             (1, 1, sq, blk_k),
-            lambda bi, hi, ki: (bi if bb > 1 else 0, hi if bh > 1 else 0, 0, ki),
+            of_q(lambda bi, hi, ki: (bi if bb > 1 else 0,
+                                     hi if bh > 1 else 0, 0, ki)),
             memory_space=pltpu.VMEM,
         )
         in_specs2.append(bspec2)
@@ -1547,16 +1619,16 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
         ks_t, qs_t = _seg_layouts(kv_seg, q_seg)
         in_specs2 += [
             pl.BlockSpec((1, blk_k, _NUM_LANES),
-                         lambda bi, hi, ki: (bi, ki, 0),
+                         of_kv(lambda bi, hi, ki: (bi, ki, 0)),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, _NUM_SUBLANES, sq),
-                         lambda bi, hi, ki: (bi, 0, 0),
+                         of_kv(lambda bi, hi, ki: (bi, 0, 0)),
                          memory_space=pltpu.VMEM),
         ]
         args2 += [ks_t, qs_t]
         if has_bnd:
             in_specs2.append(pl.BlockSpec(
-                (1, 2, sk // blk_k), lambda bi, hi, ki: (bi, 0, 0),
+                (1, 2, sk // blk_k), of_kv(lambda bi, hi, ki: (bi, 0, 0)),
                 memory_space=pltpu.SMEM))
             args2.append(bounds_k)
     if offsets is not None:
@@ -1582,11 +1654,16 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
         _bwd_dkv_kernel(qr, kr, vr, br, ksr, qsr, bndr, offr,
                         dor, lr, dr, dkr, dvr,
                         scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
-                        pad_id=pad_id, window=window, rows=rows_k)
+                        pad_id=pad_id, window=window, rows=rows_k,
+                        group=group, acc_refs=refs[i + 5:i + 7])
 
+    grouped = {} if group == 1 else {"scratch_shapes": [
+        pltpu.VMEM((blk_k, d), jnp.float32),
+        pltpu.VMEM((blk_k, d), jnp.float32)]}
     dk, dv = pl.pallas_call(
         dkv_kern,
-        grid=(b, h, sk // blk_k),
+        grid=((b, h, sk // blk_k) if group == 1
+              else (b, h // group, sk // blk_k, group)),
         in_specs=in_specs2,
         out_specs=[kblk, kblk],
         out_shape=[
@@ -1594,6 +1671,7 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=_interpret(),
+        **grouped,
     )(*args2)
     return dq, dk, dv, dbias
 
@@ -1739,6 +1817,11 @@ def mha_reference(
     fused_softmax.py:193-199 forward_torch_softmax equivalent)."""
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
+    if k.shape[1] != q.shape[1]:
+        # grouped-query heads: this dense path repeats what the kernels read
+        # through their index maps
+        k, v = (jnp.repeat(x, q.shape[1] // k.shape[1], axis=1)
+                for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     if bias is not None:
         s = s + bias.astype(jnp.float32)
@@ -1790,6 +1873,11 @@ def flash_attention(
     Args:
       q, k, v: ``(batch, heads, seq, head_dim)``; kv seq may differ from q seq
         (encoder-decoder attention, apex/contrib/multihead_attn encdec path).
+        ``k`` and ``v`` may hold fewer heads than ``q``, a divisor of its
+        count (grouped-query attention): query heads ``i * g .. i * g + g -
+        1`` read key-value head ``i`` through the kernels' index maps,
+        nothing is repeated in memory, and dK and dV come back summed over
+        each group, in the key-value heads' own shape.
       bias: optional additive bias broadcastable to ``(b, h, sq, sk)``
         (additive-mask attention; use -10000 for masked positions like the
         reference's masked_fill).
@@ -1837,6 +1925,10 @@ def flash_attention(
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    if k.shape[1] != v.shape[1] or h % k.shape[1]:
+        raise ValueError(
+            f"k and v hold {k.shape[1]} and {v.shape[1]} heads under {h} "
+            "query heads: they must agree and divide the query heads")
     scale = (d ** -0.5) if scale is None else float(scale)
     if window is not None:
         window = int(window)

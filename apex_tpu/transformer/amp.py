@@ -393,3 +393,47 @@ def build_zero_train_step(
                 scaled / opt_state.scaler.loss_scale, metrics)
 
     return train_step
+
+
+def build_dropless_train_step(model, mp_opt):
+    """``train_step(params, opt_state, tokens, targets)`` →
+    ``(params, opt_state, loss, metrics)`` for a model whose ``loss`` returns
+    ``(mean loss, stats)`` with the counters of
+    :class:`apex_tpu.transformer.moe.DroplessExperts` (``None`` or an empty
+    dict with no expert layer), under
+    :class:`apex_tpu.amp.MixedPrecisionOptimizer` ``mp_opt``: the dynamic
+    loss scale, and a step skipped when the experts' buffer could not hold
+    every assignment. The trainers jit it with ``params`` and ``opt_state``
+    donated. ``metrics["moe"]`` holds the counters."""
+
+    def train_step(params, opt_state, tokens, targets):
+        scale = opt_state.scaler.loss_scale
+
+        def scaled(p):
+            loss, stats = model.loss(p, tokens, targets)
+            return loss * scale, (loss, stats)
+
+        (_, (loss, stats)), grads = jax.value_and_grad(
+            scaled, has_aux=True)(params)
+        stats = stats or {}            # no expert layer, no counters
+        # an assignment the buffer could not hold is never lost in
+        # silence: the step is skipped, as one with an overflowed gradient
+        overflowed = jnp.sum(stats.get("overflow", 0.0)) > 0
+        of_grads = []
+
+        def skip_too(found_inf):
+            of_grads.append(found_inf)
+            return found_inf | overflowed
+
+        new_params, new_state, metrics = mp_opt.apply_gradients(
+            opt_state, params, grads, found_inf_reducer=skip_too)
+        # ... but a full buffer says nothing of the loss scale: only an
+        # overflowed gradient moves it
+        spare = overflowed & ~of_grads[0]
+        scaler = jax.tree.map(lambda old, new: jnp.where(spare, old, new),
+                              opt_state.scaler, new_state.scaler)
+        metrics["loss_scale"] = scaler.loss_scale
+        metrics["moe"] = stats
+        return new_params, new_state._replace(scaler=scaler), loss, metrics
+
+    return train_step

@@ -1,25 +1,23 @@
-"""Pretraining a DeepSeek-V3-style expert model (latent attention, shared
-and routed experts without dropped tokens, a far-skip residual) as ONE rank
-of an expert-parallel stage trains it: ``apex_tpu.models.InstellaModel``
-under amp O2 with ``MixedPrecisionOptimizer(FusedAdam)``, the dynamic loss
-scale, full recompute over scanned layers, the chunked head loss. The
-defaults are Instella-MoE-16B-A3B-Base's published widths and one chip's
-share of an 8-way expert-parallel stage (8 of 64 experts, an eighth of the
-vocabulary, one dense and four expert layers).
+"""Pretraining an LFM2 expert model (gated short convolutions beside
+grouped-query attention in an order a list gives, routed experts without
+dropped tokens) as ONE rank of an expert-parallel stage trains it:
+``apex_tpu.models.Lfm2Model`` under amp O2 with
+``MixedPrecisionOptimizer(FusedAdam)``, the dynamic loss scale, full
+recompute over the scanned runs of like layers, the chunked head loss on the
+tied table. The defaults are LFM2-8B-A1B's published widths and one chip's
+share of a 4-way expert-parallel stage (8 of 32 experts, a quarter of the
+vocabulary, the leading dense layer and one period of the pattern).
 
-    python examples/instella/pretrain_instella.py --steps 10
-    python examples/instella/pretrain_instella.py --hidden 64 --heads 4 \
-        --qk-nope-dim 12 --qk-rope-dim 4 --v-dim 16 --kv-lora-rank 32 \
-        --ffn 160 --moe-ffn 24 --experts 16 --experts-held 4 --top-k 3 \
-        --vocab 512 --seq 64 --micro-batch 2 --steps 5     # on the CPU
+    python examples/lfm2/pretrain_lfm2.py --steps 10
+    python examples/lfm2/pretrain_lfm2.py --hidden 64 --heads 4 \
+        --kv-heads 2 --ffn 96 --moe-ffn 32 --experts 8 --experts-held 4 \
+        --top-k 2 --vocab 512 --seq 64 --micro-batch 2 --steps 5  # the CPU
 
-The step donates ``params`` and ``opt_state``: undonated it would hold the
-float32 masters and both moments twice, and this model's share does not fit
-the chip that way. A caller that keeps driving the step rebinds both from
-its outputs, as ``main`` does. No exchange between ranks is built: the
-layer routes over every expert and adds its own experts' terms.
+The step donates ``params`` and ``opt_state``; a caller that keeps driving it
+rebinds both from its outputs, as ``main`` does. No exchange between ranks
+is built: a layer routes over every expert and adds its own experts' terms.
 
-``main(argv)`` returns the run's record, as ``pretrain_gpt.main`` does:
+``main(argv)`` returns the run's record, as ``pretrain_instella.main`` does:
 ``losses``, ``loss_scales``, ``found_inf``, ``moe`` (the routed experts'
 counters of the last step), ``first_step_seconds``, ``seconds_per_step``,
 ``tokens_per_step`` and the live ``train_step`` / ``params`` / ``opt_state``
@@ -40,45 +38,39 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu import amp
-from apex_tpu.models import InstellaConfig, InstellaModel
+from apex_tpu.models import Lfm2Config, Lfm2Model
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.transformer.amp import build_dropless_train_step
 from apex_tpu.utils.compile_cache import enable_compile_cache
 
-_D = InstellaConfig()
+_D = Lfm2Config()
 
 
 def parse_args(argv=None):
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     # the model's sizes
     p.add_argument("--hidden", type=int, default=_D.hidden_size)
-    p.add_argument("--layers", type=int, default=_D.num_layers,
-                   help="layers held, the dense ones included")
-    p.add_argument("--dense-layers", type=int, default=_D.num_dense_layers)
+    p.add_argument("--layer-types", default=",".join(_D.layer_types),
+                   help="each layer's operator in order, 'conv' or "
+                        "'full_attention', comma-separated")
+    p.add_argument("--dense-layers", type=int, default=_D.num_dense_layers,
+                   help="leading layers whose feed-forward is the dense MLP")
     p.add_argument("--heads", type=int, default=_D.num_attention_heads)
-    p.add_argument("--qk-nope-dim", type=int, default=_D.qk_nope_head_dim)
-    p.add_argument("--qk-rope-dim", type=int, default=_D.qk_rope_head_dim)
-    p.add_argument("--v-dim", type=int, default=_D.v_head_dim)
-    p.add_argument("--kv-lora-rank", type=int, default=_D.kv_lora_rank)
+    p.add_argument("--kv-heads", type=int, default=_D.num_kv_heads)
+    p.add_argument("--conv-taps", type=int, default=_D.conv_taps)
     p.add_argument("--ffn", type=int, default=_D.ffn_hidden_size,
                    help="width of the dense layers' MLP")
     p.add_argument("--moe-ffn", type=int, default=_D.moe_ffn_hidden_size,
                    help="width of one expert")
-    p.add_argument("--shared-experts", type=int,
-                   default=_D.num_shared_experts)
     p.add_argument("--experts", type=int, default=_D.num_experts,
                    help="experts the router scores")
     p.add_argument("--top-k", type=int, default=_D.top_k)
     p.add_argument("--routed-scaling", type=float,
                    default=_D.routed_scaling_factor)
     p.add_argument("--vocab", type=int, default=_D.vocab_size,
-                   help="rows of the embedding and the head held here")
+                   help="rows of the tied table held here")
     p.add_argument("--rope-theta", type=float, default=_D.rope_theta)
-    p.add_argument("--yarn-factor", type=float, default=_D.yarn_factor)
-    p.add_argument("--yarn-original-seq", type=int,
-                   default=_D.yarn_original_seq)
-    p.add_argument("--no-farskip", action="store_true",
-                   help="the usual residual path")
+    p.add_argument("--norm-eps", type=float, default=_D.norm_eps)
     # the experts held
     p.add_argument("--experts-held", type=int, default=_D.experts_held,
                    help="how many of --experts this rank holds")
@@ -86,7 +78,7 @@ def parse_args(argv=None):
                    default=_D.first_expert_held)
     # the run
     p.add_argument("--seq", type=int, default=_D.max_seq_len)
-    p.add_argument("--micro-batch", type=int, default=8)
+    p.add_argument("--micro-batch", type=int, default=4)
     p.add_argument("--lr", type=float, default=3e-4)
     p.add_argument("--opt-level", default="O2")
     p.add_argument("--steps", type=int, default=10)
@@ -99,27 +91,23 @@ def build(args):
     chip (``chipbench/rehearse.py``)."""
     policy = amp.get_policy(args.opt_level)
     tokens = args.micro_batch * args.seq
-    model = InstellaModel(InstellaConfig(
+    model = Lfm2Model(Lfm2Config(
         vocab_size=args.vocab, hidden_size=args.hidden,
-        num_layers=args.layers, num_dense_layers=args.dense_layers,
-        num_attention_heads=args.heads, qk_nope_head_dim=args.qk_nope_dim,
-        qk_rope_head_dim=args.qk_rope_dim, v_head_dim=args.v_dim,
-        kv_lora_rank=args.kv_lora_rank, ffn_hidden_size=args.ffn,
-        moe_ffn_hidden_size=args.moe_ffn,
-        num_shared_experts=args.shared_experts, num_experts=args.experts,
+        layer_types=tuple(args.layer_types.split(",")),
+        num_dense_layers=args.dense_layers,
+        num_attention_heads=args.heads, num_kv_heads=args.kv_heads,
+        conv_taps=args.conv_taps, ffn_hidden_size=args.ffn,
+        moe_ffn_hidden_size=args.moe_ffn, num_experts=args.experts,
         experts_held=args.experts_held,
         first_expert_held=args.first_expert_held, top_k=args.top_k,
-        routed_scaling_factor=args.routed_scaling,
-        farskip=not args.no_farskip, rope_theta=args.rope_theta,
-        yarn_factor=args.yarn_factor,
-        yarn_original_seq=args.yarn_original_seq, max_seq_len=args.seq,
+        routed_scaling_factor=args.routed_scaling, norm_eps=args.norm_eps,
+        rope_theta=args.rope_theta, max_seq_len=args.seq,
         compute_dtype=jnp.bfloat16 if args.opt_level != "O0"
         else jnp.float32,
         # the logits of a microbatch in float32, 512 MiB a chunk at most
         lm_head_chunks=max(1, -(-tokens * args.vocab * 4 // 2**29)),
         remat=True))
     mp_opt = amp.MixedPrecisionOptimizer(FusedAdam(lr=args.lr), policy)
-
     train_step = build_dropless_train_step(model, mp_opt)
     return model, policy, mp_opt, jax.jit(train_step, donate_argnums=(0, 1))
 

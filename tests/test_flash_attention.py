@@ -842,3 +842,116 @@ def test_derived_plan_masks_only_where_an_edge_crosses(case):
     for a, b, tol in zip(got, ref, (2e-5,) + (1e-4,) * 4):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=tol, atol=tol)
+
+
+# -- grouped-query heads ------------------------------------------------------
+
+def _dense_softmax(q, k, v, causal):
+    """Float32 softmax(Q K^T) V with each key-value head repeated for its
+    group of query heads: what the kernels read through their index maps."""
+    g = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision="highest") * q.shape[-1] ** -0.5
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("kv_heads", [2, 8], ids=["4to1", "1to1"])
+@pytest.mark.parametrize("stream", ["never", "always"],
+                         ids=["resident", "streamed"])
+def test_grouped_query_heads_match_a_dense_softmax(stream, kv_heads, causal):
+    """Forward, dQ, dK and dV with 8 query heads over 2 key-value heads (and
+    over 8, the kernels' old case) against a dense float32 softmax. K and V
+    keep their own heads, and so do their gradients, summed over each
+    group."""
+    b, h, s, d = 2, 8, 256, 16
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(keys[0], (b, h, s, d))
+    k = jax.random.normal(keys[1], (b, kv_heads, s, d))
+    v = jax.random.normal(keys[2], (b, kv_heads, s, d))
+    w = jax.random.normal(keys[3], (b, h, s, d))
+    kernel = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, impl="pallas", stream=stream, block_q=128,
+        block_k=128)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(_dense_softmax(q, k, v, causal)),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_dense_softmax(*a, causal) * w),
+                    (0, 1, 2))(q, k, v)
+    assert got[1].shape == k.shape and got[2].shape == v.shape
+    for a, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_grouped_query_heads_on_the_dense_path_and_refused_shapes():
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 4, 32, 8))
+    k = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 32, 8))
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, k, causal=True, impl="xla")),
+        np.asarray(_dense_softmax(q, k, k, True)), rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="divide the query heads"):
+        flash_attention(q, k[:, :1].repeat(3, 1), k[:, :1].repeat(3, 1))
+    with pytest.raises(ValueError, match="must agree"):
+        flash_attention(q, k, k[:, :1])
+
+
+def _pallas_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_eqns(sub)
+
+
+@pytest.mark.parametrize("stream", ["never", "always"],
+                         ids=["resident", "streamed"])
+def test_equal_heads_lower_as_before_and_grouped_heads_repeat_nothing(
+        monkeypatch, stream):
+    """With as many key-value heads as query heads every index map is the
+    one it was (no division by the group's size: the kernels' serialized
+    text is the old one but for line numbers) and the dK/dV grids keep
+    their rank; with grouped heads K and V go into every call in their own
+    shape, never repeated."""
+    from apex_tpu.ops import layer_norm
+
+    monkeypatch.setattr(layer_norm, "_on_tpu", lambda: True)
+
+    def calls(kv_heads):
+        q = jax.ShapeDtypeStruct((1, 8, 256, 64), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, kv_heads, 256, 64), jnp.bfloat16)
+        f = lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, stream=stream, block_q=128,
+            block_k=128).astype(jnp.float32))
+        return list(_pallas_eqns(jax.make_jaxpr(
+            jax.grad(f, (0, 1, 2)))(q, k, k).jaxpr))
+
+    equal, grouped = calls(8), calls(2)
+    assert len(equal) == len(grouped) == 3         # forward, dQ, dK/dV
+    def prims(eqn, operand):
+        """What the index map of ``operand`` (0 q, 1 k, 2 v) computes."""
+        jaxpr = eqn.params["grid_mapping"].block_mappings[
+            operand].index_map_jaxpr.jaxpr
+        return [e.primitive.name for e in jaxpr.eqns]
+
+    for eqn in equal:
+        for operand in (0, 1, 2):
+            assert not {"div", "mul"} & set(prims(eqn, operand))
+    # (where a group shares a head the maps show it: the forward and the dQ
+    # pass divide the query head for K and V, the dK/dV pass counts the
+    # group's query heads out for q)
+    assert [prims(e, 1) for e in grouped[:2]] == [["div"], ["div"]]
+    assert prims(grouped[2], 1) == [] and "mul" in prims(grouped[2], 0)
+    ranks = lambda eqns: [len(e.params["grid_mapping"].grid) for e in eqns]
+    inner = 4 if stream == "always" else 3
+    assert ranks(equal) == [inner] * 3
+    assert ranks(grouped) == [inner, inner, inner + 1]
+    for eqn in grouped:
+        shapes = [v.aval.shape for v in eqn.invars]
+        assert (1, 2, 256, 64) in shapes            # K and V as they came
+        assert shapes.count((1, 8, 256, 64)) <= 2   # q and dO, nothing else

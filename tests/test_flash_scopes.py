@@ -68,12 +68,26 @@ def _instella():
     return model, lambda p, t: model.loss(p, t, t)[0]
 
 
+def _lfm2():
+    """A tiny LFM2 model: the cut's five layers, one of which attends
+    (8 query heads over 2 key-value heads), three scanned runs."""
+    from apex_tpu.models import Lfm2Config, Lfm2Model
+
+    model = Lfm2Model(Lfm2Config(
+        vocab_size=256, hidden_size=64, num_attention_heads=8,
+        num_kv_heads=2, ffn_hidden_size=96, moe_ffn_hidden_size=32,
+        num_experts=8, experts_held=4, top_k=2, max_seq_len=32,
+        attention_impl="pallas"))
+    return model, lambda p, t: model.loss(p, t, t)[0]
+
+
 @pytest.fixture(scope="module")
 def pallas_calls():
     """``op_name`` of every ``pallas_call`` in the gradient of each tiny
     model: forward, recompute and backward."""
     out = {}
-    for name, build in (("gpt", _gpt), ("instella", _instella)):
+    for name, build in (("gpt", _gpt), ("instella", _instella),
+                        ("lfm2", _lfm2)):
         model, loss = build()
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
@@ -89,6 +103,9 @@ def pallas_calls():
     # the same, in each of the two stacks
     ("instella", "train.flash_fwd_roofline", 4),
     ("instella", "train.flash_bwd_roofline", 4),
+    # one run attends: the grouped-query calls keep the names
+    ("lfm2", "train.flash_fwd_roofline", 2),
+    ("lfm2", "train.flash_bwd_roofline", 2),
 ])
 def test_flash_kernels_keep_the_names_the_benchmark_reads(
         pallas_calls, model, metric, calls):

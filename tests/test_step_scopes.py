@@ -3,9 +3,11 @@
 
 On the CPU, at a tiny size, for the GPT step ``pretrain_gpt.main`` builds
 (O2, FusedAdam, microbatch ring), the BERT + LAMB step the benchmark's
-adapter composes and the expert model's step ``pretrain_instella.build``
+adapter composes, the expert model's step ``pretrain_instella.build``
 makes (PR 28: a second block under the same contract, with scopes of its
-own inside it): every scope names instructions where its phase runs, every
+own inside it) and the LFM2 model's step ``pretrain_lfm2.build`` makes (PR
+34: a stack of four kinds of layer, the conv operator's scopes beside the
+attention's): every scope names instructions where its phase runs, every
 ``chipbench/metrics/train.*.json`` that reads scopes finds something to read
 (a rename would end a traced chip run with exit code 4, after the chip time
 is spent), and the scopes change nothing but metadata in the compiled step.
@@ -36,11 +38,18 @@ UPDATE_SCOPES = ("amp_unscale", "optimizer_update", "amp_cast",
 INSTELLA_SCOPES = ("attention", "attn_latent", "rope", "attn_gate", "mlp",
                    "moe_shared", "moe", "moe_route", "moe_dispatch",
                    "moe_experts", "moe_combine")
-PROGRAMS = ("gpt", "bert", "instella")
+#: the LFM2 block's: the conv operator and the filter between its two
+#: projections, the head norms under ``layer_norm``, the routed experts'
+LFM2_SCOPES = ("conv_operator", "conv_mix", "attention", "qk_norm", "rope",
+               "mlp", "moe", "moe_route", "moe_dispatch", "moe_experts",
+               "moe_combine")
+OWN_SCOPES = {"instella": INSTELLA_SCOPES, "lfm2": LFM2_SCOPES}
+PROGRAMS = ("gpt", "bert", "instella", "lfm2")
 #: the cell of the benchmark that runs each program
 CELLS = {"gpt": "gpt2_345m.pretrain_b8s1024",
          "bert": "bert_large.pretrain_b8s512",
-         "instella": "instella_moe_16b_a3b.pretrain_b8s4096"}
+         "instella": "instella_moe_16b_a3b.pretrain_b8s4096",
+         "lfm2": "lfm2_8b_a1b.pretrain_b4s8192"}
 METRICS = sorted(
     os.path.basename(f)[:-len(".json")]
     for f in glob.glob(os.path.join(ROOT, "chipbench", "metrics", "*.json"))
@@ -89,17 +98,15 @@ def _bert_text() -> str:
         *(batch[k] for k in bert_lamb.Program.FEED)).compile().as_text()
 
 
-def _instella_text() -> str:
+def _trainer_text(adapter, cell) -> str:
+    """The compiled step an adapter's ``build`` makes for a tiny cell."""
     import numpy as np
 
     from apex_tpu import amp
-    from chipbench.programs import pretrain_instella
     from chipbench.references import train as ref_train
-    from chipbench.tests import tiny_instella
 
-    cell = tiny_instella.cell(ROOT)
     cfg, mix = cell["config"], cell["mix"]
-    model, policy, mp_opt, step = pretrain_instella.build(cfg, mix, ROOT)
+    model, policy, mp_opt, step = adapter.build(cfg, mix, ROOT)
 
     def state(key):
         params = amp.cast_params(model.init(key), policy)
@@ -109,11 +116,26 @@ def _instella_text() -> str:
         cfg, mix, np.random.default_rng(0), mix["batch"])
     return step.lower(
         *jax.eval_shape(state, jax.random.PRNGKey(0)),
-        *(batch[k] for k in pretrain_instella.Program.FEED)
+        *(batch[k] for k in adapter.Program.FEED)
     ).compile().as_text()
 
 
-BUILD = {"gpt": _gpt_text, "bert": _bert_text, "instella": _instella_text}
+def _instella_text() -> str:
+    from chipbench.programs import pretrain_instella
+    from chipbench.tests import tiny_instella
+
+    return _trainer_text(pretrain_instella, tiny_instella.cell(ROOT))
+
+
+def _lfm2_text() -> str:
+    from chipbench.programs import pretrain_lfm2
+    from chipbench.tests import tiny_lfm2
+
+    return _trainer_text(pretrain_lfm2, tiny_lfm2.cell(ROOT))
+
+
+BUILD = {"gpt": _gpt_text, "bert": _bert_text, "instella": _instella_text,
+         "lfm2": _lfm2_text}
 
 
 @pytest.fixture(scope="module")
@@ -149,7 +171,7 @@ def _some(scopes, *patterns, none=()):
 
 @pytest.mark.parametrize("program,scope", [
     (p, s) for p in PROGRAMS for s in MODEL_SCOPES
-] + [("instella", s) for s in INSTELLA_SCOPES])
+] + [(p, s) for p, own in OWN_SCOPES.items() for s in own])
 def test_model_scope_names_forward_backward_and_recompute(
         scopes, program, scope):
     mine = scopes[program]
@@ -258,18 +280,23 @@ SHARED = ["train.amp_unscale_ms", "train.attention_proj_ms",
           "train.layer_norm_ms", "train.lm_head_ms",
           "train.optimizer_update_ms", "train.recompute_ms",
           "train.unattributed_ms"]
-#: PR 28's two, which read scopes only the expert model's block has
-INSTELLA_ONLY = ["train.attn_latent_ms", "train.moe_route_ms"]
+#: PR 28's two: the latent attention's scopes only the expert model's
+#: block has, the routed experts' the LFM2 block has too
+INSTELLA_ONLY = ["train.attn_latent_ms"]
+ROUTED = ["train.moe_route_ms"]
+#: PR 34's two, which read the conv operator's scopes
+LFM2_ONLY = ["train.conv_mix_ms", "train.conv_proj_ms"]
 
 
 def test_the_metrics_that_read_scopes():
     """Seven that every cell reports: ``amp_cast`` has no metric of its own,
     since the compiler fuses the cast into the update (``PERF.md``,
     Findings, PR 26) and ``train.optimizer_update_ms`` reads both scopes.
-    Two more for the expert model's cell alone (its third,
-    ``train.moe_experts_ms``, also reads the grouped-product calls by name
-    and has a reader of its own)."""
-    assert METRICS == sorted(SHARED + INSTELLA_ONLY)
+    Two more for the expert model's cell, of which the LFM2 model's cell
+    reports one (their third, ``train.moe_experts_ms``, also reads the
+    grouped-product calls by name and has a reader of its own), and two
+    for the LFM2 model's cell alone."""
+    assert METRICS == sorted(SHARED + INSTELLA_ONLY + ROUTED + LFM2_ONLY)
     update = manifest.metric_file(ROOT, ["chipbench"],
                                   "train.optimizer_update_ms")
     for scope in ("optimizer_update", "amp_cast"):
@@ -306,7 +333,7 @@ def test_scopes_change_nothing_but_metadata(texts, program):
     named = _instructions(texts["named"][program])
     bare = _instructions(texts["bare"][program])
     assert len(named) == len(bare)
-    if program == "instella":
+    if program in OWN_SCOPES:
         # the two builds number a few of this step's instructions apart
         # (%call.17 against %call.20, the same call of the same
         # computation): compared with the numbers off
@@ -314,7 +341,7 @@ def test_scopes_change_nothing_but_metadata(texts, program):
         named = [number.sub(r"\1", line) for line in named]
         bare = [number.sub(r"\1", line) for line in bare]
     assert named == bare
-    mine = INSTELLA_SCOPES if program == "instella" else ()
+    mine = OWN_SCOPES.get(program, ())
     for scope in MODEL_SCOPES + UPDATE_SCOPES + mine:
         assert not re.search(token(scope), texts["bare"][program])
 
@@ -325,6 +352,8 @@ def test_every_scope_metric_is_in_the_manifest():
         assert listed[metric]["source"] == "program_span"
         assert listed[metric]["workloads"] == (
             list(CELLS.values()) if metric in SHARED
+            else [CELLS["instella"], CELLS["lfm2"]] if metric in ROUTED
+            else [CELLS["lfm2"]] if metric in LFM2_ONLY
             else [CELLS["instella"]])
 
 
