@@ -41,6 +41,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.monitor.comms import collective_scope as _comm
+from apex_tpu.ops.gated_rows import gated_rows, rows_visited
 from apex_tpu.transformer import tensor_parallel as tp
 
 Params = Dict[str, Any]
@@ -407,7 +408,12 @@ class MoEMLP:
 # assignments by expert, gathers their tokens into one buffer and runs the
 # experts as grouped products over the rows actually filled
 # (``lax.ragged_dot``, which XLA:TPU compiles to its grouped-matmul call and
-# whose time follows the group sizes, not the buffer).
+# whose time follows the group sizes, not the buffer: a call takes the same
+# 2.43 ms in a buffer of one, two and four times the rows filled, ``PERF.md``,
+# Findings, PR 35), two forward: one over ``[gate | up]`` side by side, one
+# over ``down``. The gated activation between them is
+# ``ops/gated_rows.py``'s, kernels whose walk ends at the rows filled; an
+# elementwise pass left to XLA is paid over the whole buffer.
 #
 # The rows are moved the same way: every mover below walks the rows that
 # hold an assignment, ``MOVE_ROWS`` at a trip of a loop whose trip count is
@@ -616,10 +622,16 @@ class DroplessExperts:
     of ``BUFFER_FACTOR`` times their number under an even router (never more
     than the worst case, ``min(top_k, held)`` a token). Every assignment
     that fits is computed whatever the imbalance between experts, and the
-    products' and the row movers' time follows the rows filled, not the
-    buffer; if more arrive than the buffer holds, ``stats["overflow"]``
-    counts them and the caller must skip the step (``pretrain_instella``
-    does, and the driver counts it failed): never a silent loss.
+    time of the products, of the activation between them and of the row
+    movers follows the rows filled, not the buffer: on the chip no
+    instruction between ``spread_rows`` and ``collect_rows`` touches a row
+    past the tile that holds the last filled one (off it the activation is
+    plain ``jax.numpy`` over every row), and what the rows past it hold is
+    never read. What still passes over the whole buffer is the zeros each
+    mover's loop starts from. If more assignments arrive than the buffer
+    holds, ``stats["overflow"]`` counts them and the caller must skip the
+    step (``pretrain_instella`` does, and the driver counts it failed):
+    never a silent loss.
 
     The selection bias is a held buffer here: nothing moves it. (Moving it
     as ``noaux_tc`` does in training, 0.001 a step against each expert's
@@ -629,9 +641,12 @@ class DroplessExperts:
 
     ``apply`` returns ``(out, stats)`` with the counters ``assignments`` (to
     held experts), ``max_load_over_mean`` (the fullest held expert over the
-    mean), ``overflow`` and ``rows_moved`` (the rows of ``hidden_size`` the
+    mean), ``overflow``, ``rows_moved`` (the rows of ``hidden_size`` the
     forward movers touched, from their own trip counts: about twice the
-    assignments plus the tokens, whatever the buffer).
+    assignments plus the tokens, whatever the buffer) and ``expert_rows``
+    (the rows of the buffer the activation between the products visits, from
+    the kernels' own bound: the rows filled to the tile; all of them where
+    the ``jax.numpy`` form runs).
     """
 
     #: rows of the buffer over the assignments an even router makes to the
@@ -738,13 +753,15 @@ class DroplessExperts:
             e = params["experts"]
             with jax.named_scope("moe_experts"):
                 dt = x.dtype
-                act = jax.nn.silu(lax.ragged_dot(xb, e["gate"].astype(dt),
-                                                 sizes))
-                act = act * lax.ragged_dot(xb, e["up"].astype(dt), sizes)
-                yb = lax.ragged_dot(act, e["down"].astype(dt), sizes)
+                # the gate's and the up product as one, over [gate | up]:
+                # the rows are read once and have one cotangent
+                gu = lax.ragged_dot(xb, jnp.concatenate(
+                    [e["gate"], e["up"]], axis=-1).astype(dt), sizes)
+                # rows past the filled ones hold whatever the products and
+                # the kernels between them left: nothing visits them
+                yb = lax.ragged_dot(gated_rows(gu, filled),
+                                    e["down"].astype(dt), sizes)
             with jax.named_scope("moe_combine"):
-                # rows past the filled ones hold whatever the product
-                # left: no mover visits them
                 out = collect_rows(yb, wb, plan)
             total = jnp.sum(counts)
             stats = {
@@ -755,5 +772,6 @@ class DroplessExperts:
                 "rows_moved": (
                     _trips(filled, rows) * (2 * _chunk(rows) + self.top_k)
                     + n).astype(jnp.float32),
+                "expert_rows": rows_visited(filled, gu).astype(jnp.float32),
             }
             return out.reshape(shape), stats

@@ -252,7 +252,7 @@ def test_one_scan_a_run_and_one_compile_for_the_stack():
     assert np.all(np.isfinite(run["losses"])) and not any(run["found_inf"])
     # the counters, one entry an expert layer
     assert set(run["moe"]) == {"assignments", "max_load_over_mean",
-                               "overflow", "rows_moved"}
+                               "overflow", "rows_moved", "expert_rows"}
     assert all(len(v) == 4 for v in run["moe"].values())
     assert sum(run["moe"]["overflow"]) == 0
 

@@ -98,8 +98,9 @@ def _bert_text() -> str:
         *(batch[k] for k in bert_lamb.Program.FEED)).compile().as_text()
 
 
-def _trainer_text(adapter, cell) -> str:
-    """The compiled step an adapter's ``build`` makes for a tiny cell."""
+def _trainer_step(adapter, cell):
+    """``(model, step, its arguments)`` as an adapter's ``build`` makes
+    them for a tiny cell."""
     import numpy as np
 
     from apex_tpu import amp
@@ -114,10 +115,14 @@ def _trainer_text(adapter, cell) -> str:
 
     batch = ref_train.family(cfg["reference"]).make_batch(
         cfg, mix, np.random.default_rng(0), mix["batch"])
-    return step.lower(
-        *jax.eval_shape(state, jax.random.PRNGKey(0)),
-        *(batch[k] for k in adapter.Program.FEED)
-    ).compile().as_text()
+    return model, step, (*jax.eval_shape(state, jax.random.PRNGKey(0)),
+                         *(batch[k] for k in adapter.Program.FEED))
+
+
+def _trainer_text(adapter, cell) -> str:
+    """The compiled step an adapter's ``build`` makes for a tiny cell."""
+    _, step, args = _trainer_step(adapter, cell)
+    return step.lower(*args).compile().as_text()
 
 
 def _instella_text() -> str:
@@ -248,6 +253,66 @@ def test_the_dropless_movers_stay_under_dispatch_and_combine(texts):
                          none=("rematted_computation",)), scope
             if scope == "moe_dispatch":   # the recompute stops at the products
                 assert _some(mine, "/rematted_computation/"), scope
+
+
+def _traced(jaxpr, stack=""):
+    """``(primitive, name, result shapes)`` of every equation of a traced
+    program, those inside its scans, checkpoints and calls included, each
+    named as the compiled instruction's ``op_name`` names it. A kernel is
+    one equation here (``pallas_call``), on the chip one Mosaic call."""
+    from apex_tpu.lint import ir
+
+    for eqn in jaxpr.eqns:
+        name = "/".join(
+            part for part in (stack, str(eqn.source_info.name_stack)) if part)
+        inner = ir.sub_jaxprs(eqn)
+        if inner and eqn.primitive.name != "pallas_call":
+            for sub in inner:
+                yield from _traced(sub, name)
+        else:
+            yield (eqn.primitive.name, f"{name}/{eqn.primitive.name}",
+                   [getattr(v.aval, "shape", ()) for v in eqn.outvars])
+
+
+@pytest.mark.parametrize("program", sorted(OWN_SCOPES))
+def test_under_moe_experts_only_products_and_kernels_are_buffer_sized(
+        program, monkeypatch):
+    """Everything between ``spread_rows`` and ``collect_rows`` costs by the
+    rows filled (PR 35): in the traced step, with the kernels taken as the
+    chip takes them, whatever under ``moe_experts`` has the buffer's rows
+    is a grouped product or a kernel whose walk ends at ``filled``: no
+    elementwise pass, no sum of two cotangents. And the kernels keep the
+    scope ``train.moe_experts_ms`` reads, forward, in the recompute and
+    backward: one that lost it would read as a gain in that row and a loss
+    under ``train.unattributed_ms``."""
+    from apex_tpu.ops import gated_rows
+    from chipbench.programs import pretrain_instella, pretrain_lfm2
+    from chipbench.tests import tiny_instella, tiny_lfm2
+
+    adapter, tiny = {"instella": (pretrain_instella, tiny_instella),
+                     "lfm2": (pretrain_lfm2, tiny_lfm2)}[program]
+    # off the chip 'auto' is the jax.numpy form: the kernels, as there
+    monkeypatch.setattr(gated_rows, "_resolve_impl", lambda _: "pallas")
+    cell = tiny.cell(ROOT)
+    model, step, args = _trainer_step(adapter, cell)
+    rows = model.experts.buffer_rows(cell["mix"]["batch"] * cell["mix"]["seq"])
+    scope = manifest.metric_file(
+        ROOT, ["chipbench"], "train.moe_experts_ms")["params"]["scope"]
+    under = [(prim, name, shapes) for prim, name, shapes in _traced(
+        jax.make_jaxpr(step)(*args).jaxpr) if re.search(scope, name)]
+    mine = [(prim, name) for prim, name, shapes in under
+            if any(s[:1] == (rows,) for s in shapes)]
+    assert {prim for prim, _ in mine} == {"ragged_dot_general", "pallas_call"}
+    for prim in ("ragged_dot_general", "pallas_call"):
+        names = [name for p, name in mine if p == prim]
+        assert _some(names, r"jvp\(", none=(r"transpose\(",)), prim
+        assert _some(names, token("layers"), "/rematted_computation/"), prim
+        assert _some(names, r"transpose\(",
+                     none=("rematted_computation",)), prim
+    # eight products to a layer's three kernels, where twelve were: the
+    # gate's and the up product are one, so the rows have one cotangent
+    count = lambda prim: len([p for p, _, _ in under if p == prim])
+    assert 3 * count("ragged_dot_general") == 8 * count("pallas_call") > 0
 
 
 def _reader_ctx(scopes):
