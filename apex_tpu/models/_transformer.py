@@ -657,8 +657,13 @@ class TransformerBase:
             return (h, acc), None
 
         if self.cfg.remat or chunk_meta is not None:
+            # inside a loop the recompute cannot merge with the first
+            # forward pass; a stack of ONE layer is no loop once compiled,
+            # and a model whose pattern leaves such runs (run_pattern) asks
+            # for the barrier there (``remat_barrier_single_layer``)
             body = jax.checkpoint(
-                body, prevent_cse=False,
+                body, prevent_cse=n == 1 and getattr(
+                    self, "remat_barrier_single_layer", False),
                 policy=_remat_policy(getattr(self.cfg, "remat_policy", None)),
             )
         if getattr(self.cfg, "unroll_layers", False):
@@ -715,6 +720,23 @@ class TransformerBase:
                                      return_aux=True)
             auxes.append(aux)
         return h, auxes
+
+
+def latent_kv(model, p: Params, u: jax.Array, rank: int, heads: int):
+    """Latent attention's down-projection, latent norm and up-projection
+    (DeepSeek-V2/V3 MLA, expanded form; no reference analog), under the scope
+    ``attn_latent``: ``[c; k_R] = u W_kva``, ``c' = RMSNorm(c)`` over the
+    ``rank`` of the latent, ``kv = c' W_kvb`` as ``heads`` heads. Returns
+    ``(u W_kva, kv)``: ``(b, s, rank + rope)`` and ``(b, heads, s, nope +
+    v)``. ``model`` gives ``_proj`` and ``_rms``; what is rotated, and
+    whether anything is, is the caller's."""
+    b, s, _ = u.shape
+    with jax.named_scope("attn_latent"):
+        kva = model._proj(p["kv_a"], u)
+        latent = model._rms(p["kv_norm"], kva[..., :rank])
+        kv = model._proj(p["kv_b"], latent).reshape(
+            b, s, heads, -1).transpose(0, 2, 1, 3)
+    return kva, kv
 
 
 def layer_runs(kinds) -> list:

@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.models._transformer import TransformerBase
+from apex_tpu.models._transformer import TransformerBase, latent_kv
 from apex_tpu.ops.flash_attention import flash_attention
 from apex_tpu.ops.layer_norm import rms_norm
 from apex_tpu.transformer import tensor_parallel as tp
@@ -155,11 +155,6 @@ class InstellaModel(TransformerBase):
             raise ValueError("num_dense_layers is not within num_layers")
         if c.qk_rope_head_dim % 2:
             raise ValueError("rotary needs an even qk_rope_head_dim")
-        if c.head_dim != c.v_head_dim:
-            raise ValueError(
-                "flash_attention takes one head size: qk_nope_head_dim + "
-                f"qk_rope_head_dim ({c.head_dim}) must equal v_head_dim "
-                f"({c.v_head_dim})")
         self.experts = DroplessExperts(
             c.hidden_size, c.moe_ffn_hidden_size, c.num_experts, c.top_k,
             held=c.experts_held, first_held=c.first_expert_held,
@@ -256,11 +251,7 @@ class InstellaModel(TransformerBase):
         with jax.named_scope("attention"):
             q = self._proj(p["q"], u).reshape(b, s, nh, dn + dr)
             q = q.transpose(0, 2, 1, 3)
-            with jax.named_scope("attn_latent"):
-                kva = self._proj(p["kv_a"], u)
-                latent = self._rms(p["kv_norm"], kva[..., :c.kv_lora_rank])
-                kv = self._proj(p["kv_b"], latent).reshape(
-                    b, s, nh, dn + c.v_head_dim).transpose(0, 2, 1, 3)
+            kva, kv = latent_kv(self, p, u, c.kv_lora_rank, nh)
             with jax.named_scope("rope"):
                 ang = self._token_positions(s).astype(jnp.float32)[:, None] \
                     * jnp.asarray(self._inv_freq, jnp.float32)

@@ -455,7 +455,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, qs_ref, ks_ref, bnd_ref,
         return acc_new, m_new, l_new
 
     def row(qi, tiles):
-        init = (jnp.zeros((blk_q, d), jnp.float32),
+        init = (jnp.zeros((blk_q, v_ref.shape[-1]), jnp.float32),
                 jnp.full((blk_q, 1), _NEG_INF, jnp.float32),
                 jnp.zeros((blk_q, 1), jnp.float32))
         if tiles is None:
@@ -977,6 +977,14 @@ def _offsets_spec():
     return pl.BlockSpec((2,), lambda *_: (0,), memory_space=pltpu.SMEM)
 
 
+def _of_width(spec, width: int):
+    """``spec`` with its last axis ``width`` wide: the block of values (or
+    of the output, or of its gradient) beside a block of keys or queries
+    whose head is of another size."""
+    return pl.BlockSpec((*spec.block_shape[:-1], width), spec.index_map,
+                        memory_space=spec.memory_space)
+
+
 def _kv_head(group: int):
     """The key-value head that query head ``hi`` reads, for the index maps:
     ``group`` query heads in a row share one (grouped-query attention), so K
@@ -1105,10 +1113,13 @@ def _flash_fwd(q, k, v, bias, offsets, q_seg=None, kv_seg=None, *,
                          lambda bi, hi, qi: (bi, kvh(hi), 0, 0),
                          memory_space=pltpu.VMEM)
     ospec = qspec
+    vspec, dv = kspec, v.shape[-1]
+    if dv != d:   # values of a width of their own, and so the output
+        vspec, ospec = (_of_width(x, dv) for x in (kspec, qspec))
     lspec = pl.BlockSpec((1, 1, lse_g, blk_q),
                          lambda bi, hi, qi: (bi, hi, qi // lse_g, 0),
                          memory_space=pltpu.VMEM)
-    in_specs = [qspec, kspec, kspec]
+    in_specs = [qspec, kspec, vspec]
     args = [q, k, v]
     if bias is not None:
         in_specs.append(_bias_spec(bias, blk_q, sk))
@@ -1154,7 +1165,7 @@ def _flash_fwd(q, k, v, bias, offsets, q_seg=None, kv_seg=None, *,
         in_specs=in_specs,
         out_specs=[ospec, lspec],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, nq, blk_q), jnp.float32),
         ],
         interpret=_interpret(),
@@ -1200,7 +1211,10 @@ def _flash_fwd_stream(q, k, v, offsets, q_seg, kv_seg, *, scale, causal,
     lse_spec = pl.BlockSpec((1, 1, lse_g, blk_q),
                             lambda bi, hi, qi, kj: (bi, hi, qi // lse_g, 0),
                             memory_space=pltpu.VMEM)
-    in_specs = [qspec, kspec, kspec]
+    vspec, ospec, dv = kspec, qspec, v.shape[-1]
+    if dv != d:   # values of a width of their own, and so the output
+        vspec, ospec = (_of_width(x, dv) for x in (kspec, qspec))
+    in_specs = [qspec, kspec, vspec]
     args = [q, k, v]
     has_seg = q_seg is not None
     has_bnd = has_seg and contiguous
@@ -1256,13 +1270,13 @@ def _flash_fwd_stream(q, k, v, offsets, q_seg, kv_seg, *, scale, causal,
         kern,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[qspec, lse_spec],
+        out_specs=[ospec, lse_spec],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, nq, blk_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_q, d), jnp.float32),
+            pltpu.VMEM((blk_q, dv), jnp.float32),
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, 1), jnp.float32),
         ],
@@ -1316,7 +1330,11 @@ def _flash_bwd_stream(q, k, v, offsets, o, lse, do, q_seg, kv_seg, *,
     lblk = pl.BlockSpec((1, 1, lse_g, blk_q),
                         lambda bi, hi, qi, kj: (bi, hi, qi // lse_g, 0),
                         memory_space=pltpu.VMEM)
-    in_specs = [qspec, kspec, kspec]
+    # values, and with them the output's gradient, of a width of their own
+    vspec, dospec, dv = kspec, qspec, v.shape[-1]
+    if dv != d:
+        vspec, dospec = (_of_width(x, dv) for x in (kspec, qspec))
+    in_specs = [qspec, kspec, vspec]
     args = [q, k, v]
     if has_seg:
         in_specs += [
@@ -1340,7 +1358,7 @@ def _flash_bwd_stream(q, k, v, offsets, o, lse, do, q_seg, kv_seg, *,
     if has_off:
         in_specs.append(_offsets_spec())
         args.append(offsets)
-    in_specs += [qspec, lblk, lblk]
+    in_specs += [dospec, lblk, lblk]
     args += [do, lse, delta]
 
     def dq_kern(*refs):
@@ -1391,7 +1409,10 @@ def _flash_bwd_stream(q, k, v, offsets, o, lse, do, q_seg, kv_seg, *,
                                                       qmap(ki, qi) // lse_g,
                                                       0)),
                          memory_space=pltpu.VMEM)
-    in_specs2 = [qspec2, kspec2, kspec2]
+    vspec2, dospec2 = kspec2, qspec2
+    if dv != d:
+        vspec2, dospec2 = (_of_width(x, dv) for x in (kspec2, qspec2))
+    in_specs2 = [qspec2, kspec2, vspec2]
     args2 = [q, k, v]
     if has_seg:
         in_specs2 += [
@@ -1418,7 +1439,7 @@ def _flash_bwd_stream(q, k, v, offsets, o, lse, do, q_seg, kv_seg, *,
     if has_off:
         in_specs2.append(_offsets_spec())
         args2.append(offsets)
-    in_specs2 += [qspec2, lblk2, lblk2]
+    in_specs2 += [dospec2, lblk2, lblk2]
     args2 += [do, lse, delta]
 
     def dkv_kern(*refs):
@@ -1447,14 +1468,14 @@ def _flash_bwd_stream(q, k, v, offsets, o, lse, do, q_seg, kv_seg, *,
         grid=((b, h, nk, nqw) if group == 1
               else (b, h // group, nk, group, nqw)),
         in_specs=in_specs2,
-        out_specs=[kspec2, kspec2],
+        out_specs=[kspec2, vspec2],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, d), jnp.float32),
-            pltpu.VMEM((blk_k, d), jnp.float32),
+            pltpu.VMEM((blk_k, dv), jnp.float32),
         ],
         interpret=_interpret(),
     )(*args2)
@@ -1520,7 +1541,11 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
                         reorder(lambda bi, hi, qi: (bi, hi, qi // lse_g, 0)),
                         memory_space=pltpu.VMEM)
 
-    in_specs = [qspec, kfull, kfull]
+    # values, and with them the output's gradient, of a width of their own
+    vfull, dospec, dv = kfull, qspec, v.shape[-1]
+    if dv != d:
+        vfull, dospec = (_of_width(x, dv) for x in (kfull, qspec))
+    in_specs = [qspec, kfull, vfull]
     args = [q, k, v]
     if bias is not None:
         bb, bh = bias.shape[0], bias.shape[1]
@@ -1539,7 +1564,7 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
     if offsets is not None:
         in_specs.append(_offsets_spec())
         args.append(offsets)
-    in_specs += [qspec, lblk, lblk]
+    in_specs += [dospec, lblk, lblk]
     args += [do, lse, delta]
     has_bias, has_off = bias is not None, offsets is not None
     # traced bounds (ring offsets, contiguous-segment ranges): dynamic loop
@@ -1601,7 +1626,10 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
     lfull = pl.BlockSpec((1, 1, nq, blk_q),
                          of_q(lambda bi, hi, ki: (bi, hi, 0, 0)),
                          memory_space=pltpu.VMEM)
-    in_specs2 = [qfull, kblk, kblk]
+    vblk, dofull = kblk, qfull
+    if dv != d:
+        vblk, dofull = (_of_width(x, dv) for x in (kblk, qfull))
+    in_specs2 = [qfull, kblk, vblk]
     args2 = [q, k, v]
     if bias is not None:
         bb, bh = bias.shape[0], bias.shape[1]
@@ -1634,7 +1662,7 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
     if offsets is not None:
         in_specs2.append(_offsets_spec())
         args2.append(offsets)
-    in_specs2 += [qfull, lfull, lfull]
+    in_specs2 += [dofull, lfull, lfull]
     args2 += [do, lse, delta]
 
     def dkv_kern(*refs):
@@ -1659,13 +1687,13 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse, do, q_seg=None, kv_seg=None, *,
 
     grouped = {} if group == 1 else {"scratch_shapes": [
         pltpu.VMEM((blk_k, d), jnp.float32),
-        pltpu.VMEM((blk_k, d), jnp.float32)]}
+        pltpu.VMEM((blk_k, dv), jnp.float32)]}
     dk, dv = pl.pallas_call(
         dkv_kern,
         grid=((b, h, sk // blk_k) if group == 1
               else (b, h // group, sk // blk_k, group)),
         in_specs=in_specs2,
-        out_specs=[kblk, kblk],
+        out_specs=[kblk, vblk],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -1873,6 +1901,8 @@ def flash_attention(
     Args:
       q, k, v: ``(batch, heads, seq, head_dim)``; kv seq may differ from q seq
         (encoder-decoder attention, apex/contrib/multihead_attn encdec path).
+        ``v`` may have a width of its own (latent attention scores over 192
+        and sums values of 128): the output and dV have ``v``'s.
         ``k`` and ``v`` may hold fewer heads than ``q``, a divisor of its
         count (grouped-query attention): query heads ``i * g .. i * g + g -
         1`` read key-value head ``i`` through the kernels' index maps,
@@ -1943,6 +1973,11 @@ def flash_attention(
             f"sq={sq}, sk={sk}, d={d} is outside the kernel's envelope "
             "(8-aligned sequences, head_dim >= 8)")
     global _WARNED_PACKED_OPT_IN
+    if block_q is None and block_k is None and d > _NUM_LANES:
+        # a head wider than the 128 lanes is padded to 256 in VMEM: beside
+        # the four float32 score tiles of the backward pass, a tile of 1024
+        # no longer fits the 16 MB a kernel may use (192-wide keys, v5e)
+        block_q = block_k = 512
     blk_q, blk_k, _, _ = flash_tile_plan(
         sq, sk, causal, window, block_q=block_q, block_k=block_k,
         has_segments=segment_ids is not None,

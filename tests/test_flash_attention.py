@@ -955,3 +955,107 @@ def test_equal_heads_lower_as_before_and_grouped_heads_repeat_nothing(
         shapes = [v.aval.shape for v in eqn.invars]
         assert (1, 2, 256, 64) in shapes            # K and V as they came
         assert shapes.count((1, 8, 256, 64)) <= 2   # q and dO, nothing else
+
+
+# -- values of a width of their own (latent attention: 192 | 128) -------------
+
+def _dense_softmax_wide(q, k, v, causal, scale):
+    g = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * scale
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["1to1", "2to1"])
+@pytest.mark.parametrize("stream", ["never", "always"],
+                         ids=["resident", "streamed"])
+def test_values_of_their_own_width_match_a_dense_softmax(stream, kv_heads,
+                                                         causal):
+    """Scores over 24 and values of 16 (192 and 128 in the Kimi Linear
+    model's latent attention): forward, dQ, dK and dV of all four training
+    kernels against a dense float32 softmax, and the lax path against it.
+    The output and dV have the values' width, never a narrower score."""
+    b, h, s, d, dv = 2, 4, 256, 24, 16
+    keys = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(keys[0], (b, h, s, d))
+    k = jax.random.normal(keys[1], (b, kv_heads, s, d))
+    v = jax.random.normal(keys[2], (b, kv_heads, s, dv))
+    w = jax.random.normal(keys[3], (b, h, s, dv))
+    scale = d ** -0.5
+    dense = lambda *a: _dense_softmax_wide(*a, causal, scale)
+    for impl in ("pallas", "xla"):
+        kernel = lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, impl=impl, stream=stream, block_q=128,
+            block_k=128)
+        out = kernel(q, k, v)
+        assert out.shape == (b, h, s, dv)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(dense(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+        got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+        assert [x.shape for x in got] == [q.shape, k.shape, v.shape]
+        for a, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                       rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("stream", ["never", "always"],
+                         ids=["resident", "streamed"])
+def test_equal_widths_trace_as_before_and_a_wide_head_takes_smaller_tiles(
+        monkeypatch, stream):
+    """With values as wide as keys every block of every call is what it was
+    (the jaxpr's text, file positions cut out, is what the tree before the
+    value width gave, for equal and for grouped heads); with 192 | 128 the
+    values, the output and its gradient go into the calls 128 wide, nothing
+    is padded, and the tile's edge is 512: a head over 128 lanes is padded
+    to 256 in VMEM, and a tile of 1024 then no longer fits beside the
+    backward pass's score tiles."""
+    import hashlib
+    import re
+
+    from apex_tpu.ops import layer_norm
+
+    monkeypatch.setattr(layer_norm, "_on_tpu", lambda: True)
+
+    def text(kv_heads):
+        q = jax.ShapeDtypeStruct((1, 8, 256, 64), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, kv_heads, 256, 64), jnp.bfloat16)
+        f = lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, stream=stream, block_q=128,
+            block_k=128).astype(jnp.float32))
+        return re.sub(r" at /[^\s\]]*:\d+", "", str(jax.make_jaxpr(
+            jax.grad(f, (0, 1, 2)))(q, k, k)))
+
+    # (the suite's default precision, named so that the text does not hang
+    # on where the test runs)
+    with jax.default_matmul_precision("highest"):
+        digest = hashlib.sha256(
+            "\n".join([text(8), text(2)]).encode()).hexdigest()
+    assert digest == {
+        "never": "edb80025dd44d4a67ef5cd700d473e4ca759024cf3fa07ed31ee503e"
+                 "0ffb60bc",
+        "always": "8ce793e93192b0adcca52eb52cbc99a05a918b6230cd19152153be40"
+                  "6af96605"}[stream]
+
+    q = jax.ShapeDtypeStruct((1, 4, 2048, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 4, 2048, 128), jnp.bfloat16)
+    f = lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, stream=stream).astype(jnp.float32))
+    calls = list(_pallas_eqns(jax.make_jaxpr(
+        jax.grad(f, (0, 1, 2)))(q, q, v).jaxpr))
+    assert len(calls) == 3
+    for eqn in calls:
+        shapes = [x.aval.shape for x in eqn.invars + eqn.outvars]
+        assert (1, 4, 2048, 128) in shapes and (1, 4, 2048, 192) in shapes
+        assert not any(s[-1] == 256 for s in shapes if len(s) == 4)
+        size = lambda x: int(getattr(x, "block_size", x))
+        blocks = [[size(x) for x in bm.block_shape] for bm in
+                  eqn.params["grid_mapping"].block_mappings]
+        edges = {blk[2] for blk in blocks
+                 if len(blk) == 4 and blk[3] in (128, 192)}
+        assert max(edges) in ((512, 2048) if stream == "never" else (512,))

@@ -5,9 +5,11 @@ On the CPU, at a tiny size, for the GPT step ``pretrain_gpt.main`` builds
 (O2, FusedAdam, microbatch ring), the BERT + LAMB step the benchmark's
 adapter composes, the expert model's step ``pretrain_instella.build``
 makes (PR 28: a second block under the same contract, with scopes of its
-own inside it) and the LFM2 model's step ``pretrain_lfm2.build`` makes (PR
+own inside it), the LFM2 model's step ``pretrain_lfm2.build`` makes (PR
 34: a stack of four kinds of layer, the conv operator's scopes beside the
-attention's): every scope names instructions where its phase runs, every
+attention's) and the Kimi Linear model's step ``pretrain_kimi_linear.build``
+makes (PR 36: the KDA operator's scopes beside the latent attention's and
+the experts'): every scope names instructions where its phase runs, every
 ``chipbench/metrics/train.*.json`` that reads scopes finds something to read
 (a rename would end a traced chip run with exit code 4, after the chip time
 is spent), and the scopes change nothing but metadata in the compiled step.
@@ -43,13 +45,22 @@ INSTELLA_SCOPES = ("attention", "attn_latent", "rope", "attn_gate", "mlp",
 LFM2_SCOPES = ("conv_operator", "conv_mix", "attention", "qk_norm", "rope",
                "mlp", "moe", "moe_route", "moe_dispatch", "moe_experts",
                "moe_combine")
-OWN_SCOPES = {"instella": INSTELLA_SCOPES, "lfm2": LFM2_SCOPES}
-PROGRAMS = ("gpt", "bert", "instella", "lfm2")
+#: the Kimi Linear block's: the KDA operator from its first projection to
+#: ``W_o`` with the filters, the gates and the chunked scan inside it, the
+#: latent attention's as the expert model names them (no rotation: no
+#: ``rope``), the shared and the routed experts'
+KIMI_SCOPES = ("kda_operator", "conv_mix", "kda_gates", "kda_scan",
+               "attention", "attn_latent", "mlp", "moe_shared", "moe",
+               "moe_route", "moe_dispatch", "moe_experts", "moe_combine")
+OWN_SCOPES = {"instella": INSTELLA_SCOPES, "lfm2": LFM2_SCOPES,
+              "kimi": KIMI_SCOPES}
+PROGRAMS = ("gpt", "bert", "instella", "lfm2", "kimi")
 #: the cell of the benchmark that runs each program
 CELLS = {"gpt": "gpt2_345m.pretrain_b8s1024",
          "bert": "bert_large.pretrain_b8s512",
          "instella": "instella_moe_16b_a3b.pretrain_b8s4096",
-         "lfm2": "lfm2_8b_a1b.pretrain_b4s8192"}
+         "lfm2": "lfm2_8b_a1b.pretrain_b4s8192",
+         "kimi": "kimi_linear_48b_a3b.pretrain_b2s8192"}
 METRICS = sorted(
     os.path.basename(f)[:-len(".json")]
     for f in glob.glob(os.path.join(ROOT, "chipbench", "metrics", "*.json"))
@@ -139,8 +150,15 @@ def _lfm2_text() -> str:
     return _trainer_text(pretrain_lfm2, tiny_lfm2.cell(ROOT))
 
 
+def _kimi_text() -> str:
+    from chipbench.programs import pretrain_kimi_linear
+    from chipbench.tests import tiny_kimi_linear
+
+    return _trainer_text(pretrain_kimi_linear, tiny_kimi_linear.cell(ROOT))
+
+
 BUILD = {"gpt": _gpt_text, "bert": _bert_text, "instella": _instella_text,
-         "lfm2": _lfm2_text}
+         "lfm2": _lfm2_text, "kimi": _kimi_text}
 
 
 @pytest.fixture(scope="module")
@@ -286,11 +304,17 @@ def test_under_moe_experts_only_products_and_kernels_are_buffer_sized(
     backward: one that lost it would read as a gain in that row and a loss
     under ``train.unattributed_ms``."""
     from apex_tpu.ops import gated_rows
-    from chipbench.programs import pretrain_instella, pretrain_lfm2
-    from chipbench.tests import tiny_instella, tiny_lfm2
+    from chipbench.programs import (
+        pretrain_instella,
+        pretrain_kimi_linear,
+        pretrain_lfm2,
+    )
+    from chipbench.tests import tiny_instella, tiny_kimi_linear, tiny_lfm2
 
     adapter, tiny = {"instella": (pretrain_instella, tiny_instella),
-                     "lfm2": (pretrain_lfm2, tiny_lfm2)}[program]
+                     "lfm2": (pretrain_lfm2, tiny_lfm2),
+                     "kimi": (pretrain_kimi_linear, tiny_kimi_linear)
+                     }[program]
     # off the chip 'auto' is the jax.numpy form: the kernels, as there
     monkeypatch.setattr(gated_rows, "_resolve_impl", lambda _: "pallas")
     cell = tiny.cell(ROOT)
@@ -349,8 +373,11 @@ SHARED = ["train.amp_unscale_ms", "train.attention_proj_ms",
 #: block has, the routed experts' the LFM2 block has too
 INSTELLA_ONLY = ["train.attn_latent_ms"]
 ROUTED = ["train.moe_route_ms"]
-#: PR 34's two, which read the conv operator's scopes
+#: PR 34's two, which read the conv operator's scopes; the filter's the
+#: KDA operator names too
 LFM2_ONLY = ["train.conv_mix_ms", "train.conv_proj_ms"]
+#: PR 36's two, which read the KDA operator's scopes
+KIMI_ONLY = ["train.kda_scan_ms", "train.kda_proj_ms"]
 
 
 def test_the_metrics_that_read_scopes():
@@ -359,9 +386,12 @@ def test_the_metrics_that_read_scopes():
     Findings, PR 26) and ``train.optimizer_update_ms`` reads both scopes.
     Two more for the expert model's cell, of which the LFM2 model's cell
     reports one (their third, ``train.moe_experts_ms``, also reads the
-    grouped-product calls by name and has a reader of its own), and two
-    for the LFM2 model's cell alone."""
-    assert METRICS == sorted(SHARED + INSTELLA_ONLY + ROUTED + LFM2_ONLY)
+    grouped-product calls by name and has a reader of its own), two for the
+    LFM2 model's cell, and two for the Kimi Linear model's cell alone, which
+    also reports the latent attention's, the routed experts' and the
+    filter's."""
+    assert METRICS == sorted(SHARED + INSTELLA_ONLY + ROUTED + LFM2_ONLY
+                             + KIMI_ONLY)
     update = manifest.metric_file(ROOT, ["chipbench"],
                                   "train.optimizer_update_ms")
     for scope in ("optimizer_update", "amp_cast"):
@@ -417,9 +447,13 @@ def test_every_scope_metric_is_in_the_manifest():
         assert listed[metric]["source"] == "program_span"
         assert listed[metric]["workloads"] == (
             list(CELLS.values()) if metric in SHARED
-            else [CELLS["instella"], CELLS["lfm2"]] if metric in ROUTED
+            else [CELLS["instella"], CELLS["lfm2"], CELLS["kimi"]]
+            if metric in ROUTED
+            else [CELLS["lfm2"], CELLS["kimi"]]
+            if metric == "train.conv_mix_ms"
             else [CELLS["lfm2"]] if metric in LFM2_ONLY
-            else [CELLS["instella"]])
+            else [CELLS["kimi"]] if metric in KIMI_ONLY
+            else [CELLS["instella"], CELLS["kimi"]])
 
 
 @pytest.mark.parametrize("metric", [
@@ -452,3 +486,42 @@ def test_the_expert_models_kernel_metrics_find_their_instructions(
         assert CELLS["instella"] in next(
             m for m in manifest.load(ROOT)["per_layer"]
             if m["name"] == metric)["workloads"]
+
+
+def test_the_kda_scopes_cut_the_operator_as_the_metrics_read_it(scopes):
+    """``train.kda_scan_ms`` and ``train.kda_scan_roofline`` read
+    ``kda_scan``, the chunked delta rule and nothing else: every instruction
+    under it lies inside ``kda_operator``, none under the filter's or the
+    gates' scope, and its loops over the chunks (forward, recompute and the
+    reverse one) keep the name. ``train.kda_proj_ms`` reads the operator
+    without the scan and the filter: the projections, the gates and the
+    head norm. ``train.conv_mix_roofline`` and ``train.kda_scan_roofline``
+    find the scope and the flops module's count."""
+    from chipbench import flops_kimi_linear
+    from chipbench.readers import scope_roofline
+
+    # (a reduction's scalar body is a computation of its own in the text,
+    # named from the scope inwards: no instruction of the step)
+    mine = [s for s in scopes["kimi"] if s.startswith("jit(")]
+    scan = _some(mine, token("kda_scan"))
+    assert scan and not _some(scan, "^", none=(token("kda_operator"),))
+    assert not _some(scan, token("conv_mix")) \
+        and not _some(scan, token("kda_gates"))
+    loops = _some(scan, "/while/body/")
+    assert _some(loops, r"jvp\(", none=(r"transpose\(",))
+    assert _some(loops, token("layers"), "/rematted_computation/")
+    assert _some(loops, r"transpose\(")
+    proj = manifest.metric_file(ROOT, ["chipbench"],
+                                "train.kda_proj_ms")["params"]
+    left = _some(mine, proj["include"], none=(proj["exclude"],))
+    assert _some(left, token("kda_gates")) and _some(left, "dot_general")
+    assert _some(left, token("layer_norm"))
+    cell = manifest.cell(manifest.load(ROOT), CELLS["kimi"], ROOT)
+    ctx = dict(_reader_ctx(mine), planes=["/device:TPU:0"],
+               flops=flops_kimi_linear, cfg=cell["config"], mix=cell["mix"],
+               rows=cell["mix"]["batch"], chips=1,
+               peak={"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    for metric in ("train.kda_scan_roofline", "train.conv_mix_roofline"):
+        params = manifest.metric_file(ROOT, ["chipbench"], metric)["params"]
+        got = scope_roofline.read(ctx, **params)
+        assert got is not None and got > 0, metric
