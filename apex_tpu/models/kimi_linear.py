@@ -139,7 +139,8 @@ class KimiLinearModel(TransformerBase):
     loss, stats)``; ``embed`` / ``run_stacks`` / ``head`` are the stage
     boundaries. ``stats`` holds the routed experts' counters, one entry an
     expert layer, and the KDA layers' (``kda_min_chunk_log_decay``,
-    ``kda_chunks``, ``kda_kernel_chunks``), one entry a KDA layer."""
+    ``kda_chunks``, ``kda_kernel_chunks``, ``kda_transpose_chunks``), one
+    entry a KDA layer."""
 
     causal = True
     #: run_layers stacks what each layer's _layer_aux returns
@@ -296,10 +297,11 @@ class KimiLinearModel(TransformerBase):
                 "kda_min_chunk_log_decay": jnp.min(per_chunk),
                 "kda_chunks": jnp.float32(per_chunk.size // d)}
         q, k, v = heads(q), heads(k), heads(v)
-        # the chunks whose chunk-local forward the kernel computes: all of
-        # them or none, known when the step is traced
-        counters["kda_kernel_chunks"] = jnp.float32(
-            kernel_chunks(q, v, chunk=c.kda_chunk))
+        # the chunks whose chunk-local forward the kernel computes, and
+        # those whose transpose its twin computes: all of them or none, the
+        # same chunks, known when the step is traced
+        counters["kda_kernel_chunks"] = counters["kda_transpose_chunks"] = \
+            jnp.float32(kernel_chunks(q, v, chunk=c.kda_chunk))
         return (self._l2norm(q), self._l2norm(k), v, g, beta, gate, counters)
 
     def _kda_operator(self, p: Params, u: jax.Array):

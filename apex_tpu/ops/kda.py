@@ -49,21 +49,26 @@ and nothing else, the backward makes the chunk-local results again, runs
 the scan in reverse and then the chunk-local part's transpose for all
 chunks at once.
 
-**What is a kernel.** The chunk-local part's forward (:func:`_local`'s six
-results, made three times a step: forward, recompute, and the backward's
-operands of the reverse scan) is one Pallas kernel on the chip
-(:func:`_kernel`): a grid step takes one chunk of :data:`BLOCK` heads and
-keeps everything from its inputs to its six results in VMEM, the same
-arithmetic in the same precisions (the running sum as float32 adds, the
-decays split as above, ``T`` at ``HIGHEST``), and writes the results with
-the chunks in front, as the scan reads them. Still ``jax.numpy``: the
-scans over the chunks, which carry the state, and the chunk-local part's
-transpose, which differentiates :func:`_local` itself, a group of heads at
-a time so that its float32 temporaries stay a fraction of an operand's
-size (all heads at once, the step of the Kimi Linear cell held 9.9 GB of
-them where it holds 4.2, compiled for a described v5e). Off the chip and
-outside the kernel's envelope the forward is :func:`_local` too, a group
-of heads at a time. The caller names the scope; the kernel keeps it.
+**What is a kernel.** On the chip the chunk-local part is two Pallas
+kernels, each a grid step one chunk of :data:`BLOCK` heads kept in VMEM
+from its inputs to its results. :func:`_kernel` makes :func:`_local`'s six
+results (three times a step: forward, recompute, and the backward's
+operands of the reverse scan), the same arithmetic in the same precisions
+(the running sum as float32 adds, the decays split as above, ``T`` at
+``HIGHEST``), and writes them with the chunks in front, as the scan reads
+them. :func:`_transpose_kernel` is its transpose, written out: it takes the
+six results' cotangents as the reverse scan leaves them, makes ``G``,
+``A``, ``T`` and the right-hand sides again, and gives ``q``'s, ``k``'s,
+``v``'s, ``g``'s and ``beta``'s cotangents (``dA = -(T^T dT T^T)`` at
+``HIGHEST``; the decayed products' transposes with every decay split as
+the forward splits it, so no exponent there is above 0 either). Still
+``jax.numpy``: the scans over the chunks, which carry the state. Off the
+chip and outside the kernels' envelope the chunk-local part is
+:func:`_local` and its transpose ``jax.vjp`` of it, a group of heads at a
+time so that the float32 temporaries stay a fraction of an operand's size
+(all heads at once, the step of the Kimi Linear cell held 9.9 GB of them
+where it held 4.2, compiled for a described v5e). The caller names the
+scope; the kernels keep it.
 """
 
 from __future__ import annotations
@@ -233,15 +238,16 @@ def _local(q, k, v, g, beta, dtype):
 BLOCK = 8
 
 
-def _dot(a, b, dtype, contract: int = -2):
+def _dot(a, b, dtype, contract: int = -2, over: int = -1):
     """:func:`_mm` inside the kernel, over ``b``'s rows (``contract`` -2)
-    or its columns (-1), the leading axes a batch: one pass of the
+    or its columns (-1) and ``a``'s columns (``over`` -1) or its rows
+    (-2, ``a`` transposed), the leading axes a batch: one pass of the
     operands' type, named (Mosaic refuses a float32 contraction of bf16
     operands, which a caller's default precision would ask for)."""
     batch = tuple(range(a.ndim - 2))
     return lax.dot_general(
         a.astype(dtype), b.astype(dtype),
-        (((a.ndim - 1,), (b.ndim + contract,)), (batch, batch)),
+        (((a.ndim + over,), (b.ndim + contract,)), (batch, batch)),
         precision=lax.Precision.DEFAULT, preferred_element_type=_F32)
 
 
@@ -269,78 +275,181 @@ def _highest(x, y):
             + dot(side(xm, xh), over(ym, yl)))
 
 
+# The kernels' building blocks. A chunk's ``(C, d)`` operands live in VMEM
+# scratch below ``lift`` rows of zeros (and above as many, where a kernel
+# moves rows up), so that a load ``s`` rows higher reads the operand moved
+# down by ``s``: row ``i`` then holds ``x[i - s]``, zeros above.
+
+def _shifted(ref, lift: int, at: int, n: int):
+    """Rows ``at`` to ``at + n`` of the operand ``ref`` holds below its
+    ``lift`` rows of zeros: a negative ``at`` reads it moved down by
+    ``-at``, a positive one moved up, zeros where it has no rows."""
+    return ref[lift + at:lift + at + n, :]
+
+
+def _running_sum(x, pad_ref, lift: int, reverse: bool = False):
+    """``x``'s running sum down its rows (up them, if ``reverse``) in
+    log2(C) adds of ``x`` moved by 1, 2, 4, ... rows through ``pad_ref``
+    (zeros in the ``lift`` rows above the operand, and below it too if
+    ``reverse``)."""
+    c = x.shape[0]
+    s = 1
+    while s < c:
+        pad_ref[lift:lift + c, :] = x
+        x = x + _shifted(pad_ref, lift, s if reverse else -s, c)
+        s *= 2
+    return x
+
+
+def _running_sums(g_ref, out_ref):
+    """The running sum down the rows of every chunk of ``g_ref`` ``(heads,
+    C, d)`` into ``out_ref``, a row at a time for all heads at once: each
+    ``G_i`` is one rounding from ``G_{i - 1}``, so that a decay's exponent,
+    a difference of two, holds to float32's rounding of ``G``.
+    :func:`_running_sum` rounds each row several times: at 30 a token that
+    doubled the error of ``g``'s cotangent, where the forward kernel's
+    results hardly notice it."""
+    acc = g_ref[:, 0:1, :]
+    out_ref[:, 0:1, :] = acc
+    for i in range(1, g_ref.shape[1]):
+        acc = acc + g_ref[:, i:i + 1, :]
+        out_ref[:, i:i + 1, :] = acc
+
+
+def _near_pairs(big_g, big_g_ref, lift: int):
+    """The pairs inside each block of :data:`NEAR` tokens, one offset
+    ``gap = i - j`` at a time: ``(gap, decay)``, ``decay[i] = exp(G_i -
+    G_{i - gap})`` where token ``i - gap`` lies in ``i``'s block and 0
+    where it does not (``big_g_ref`` holds ``G`` as the block comment
+    above says)."""
+    c = big_g.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    for gap in range(1, min(NEAR, c)):
+        yield gap, jnp.exp(jnp.where(
+            row % NEAR >= gap,
+            big_g - _shifted(big_g_ref, lift, -gap, c), -jnp.inf))
+
+
+def _half_blocks(big_g, big_g_ref, lift: int):
+    """The halves of every block, from the chunk's own down to blocks of
+    twice :data:`NEAR`: ``(same, rows, cols)`` for the pairs with ``i`` in a
+    block's second half and ``j`` in its first, split at the first half's
+    last token ``r``: ``rows[i] = exp(G_i - G_r)`` (0 in a first half),
+    ``cols[j] = exp(G_r - G_j)`` (0 in a second half), ``same[i, j]`` where
+    ``i`` and ``j`` lie in one block."""
+    c = big_g.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    half = c // 2
+    while half >= NEAR:
+        span = 2 * half
+        second = row % span >= half
+        ref = _shifted(big_g_ref, lift, half - 1, 1)
+        for n in range(1, c // span):
+            ref = jnp.where(row // span == n,
+                            _shifted(big_g_ref, lift, n * span + half - 1, 1),
+                            ref)
+        yield (row // span == col // span,
+               jnp.exp(jnp.where(second, big_g - ref, -jnp.inf)),
+               jnp.exp(jnp.where(second, -jnp.inf, ref - big_g)))
+        half //= 2
+
+
+def _decayed(xs, y, y_ref, big_g, big_g_ref, lift: int, dtype):
+    """:func:`_decayed_products` inside a kernel, strictly below the
+    diagonal, for each ``x`` of ``xs`` against one ``y`` (``y_ref`` and
+    ``big_g_ref`` hold ``y`` and ``G`` padded): the near pairs decay by
+    decay, the halves' products in ``dtype``."""
+    c = y.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    out = [jnp.zeros((c, c), _F32) for _ in xs]
+    for gap, decay in _near_pairs(big_g, big_g_ref, lift):
+        yj = _shifted(y_ref, lift, -gap, c)
+        at = col == row - gap
+        out = [m + jnp.where(at, jnp.sum(x * yj * decay, axis=1,
+                                         keepdims=True), 0.0)
+               for m, x in zip(out, xs)]
+    for same, rows, cols in _half_blocks(big_g, big_g_ref, lift):
+        cols = y * cols
+        out = [m + jnp.where(same, _dot(x * rows, cols, dtype, -1), 0.0)
+               for m, x in zip(out, xs)]
+    return out
+
+
+def _decayed_transpose(dms, xs, y, y_ref, up_ref, big_g, big_g_ref,
+                       lift: int, dtype):
+    """The transpose of :func:`_decayed` given the cotangent ``dm`` of each
+    product (strictly below the diagonal): ``(dxs, dy)``, ``dx_i = sum_j
+    dm_ij y_j exp(G_i - G_j)`` for each ``x`` and ``dy_j = sum_i dm_ij x_i
+    exp(G_i - G_j)`` summed over the products, with every decay split as
+    the products split it. ``G``'s cotangent is ``x * dx - y * dy`` (the
+    split point's share cancels), the caller's. ``up_ref`` has zeros below
+    the operand, for the near pairs' terms moved up to ``j``'s row."""
+    c = y.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    dxs = [jnp.zeros(y.shape, _F32) for _ in xs]
+    dy = jnp.zeros(y.shape, _F32)
+    for gap, decay in _near_pairs(big_g, big_g_ref, lift):
+        yj = _shifted(y_ref, lift, -gap, c) * decay
+        at = col == row - gap
+        ms = [jnp.sum(jnp.where(at, dm, 0.0), axis=1, keepdims=True)
+              for dm in dms]
+        dxs = [dx + m * yj for dx, m in zip(dxs, ms)]
+        up_ref[lift:lift + c, :] = sum(m * x for m, x in zip(ms, xs)) * decay
+        dy = dy + _shifted(up_ref, lift, gap, c)
+    for same, rows, cols in _half_blocks(big_g, big_g_ref, lift):
+        # the products' cotangents one above the other, so that each
+        # product is one, as deep as all of them
+        dm = jnp.concatenate([jnp.where(same, dm, 0.0) for dm in dms], 0)
+        dx = _dot(dm, y * cols, dtype)
+        dxs = [d + rows * dx[n * c:(n + 1) * c] for n, d in enumerate(dxs)]
+        dy = dy + cols * _dot(
+            dm, jnp.concatenate([x * rows for x in xs], 0), dtype, over=-2)
+    return dxs, dy
+
+
+def _row_of(block, j):
+    """Row ``j`` of a ``(heads, n)`` block, as ``(1, n)``."""
+    at = lax.broadcasted_iota(jnp.int32, (block.shape[0], 1), 0) == j
+    return jnp.sum(jnp.where(at, block, 0.0), axis=0, keepdims=True)
+
+
 def _kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
             w_ref, u0_ref, b_ref, qg_ref, kg_ref, end_ref,
             pad_ref, big_g_ref, a_ref, *, lift):
     """:func:`_local` for the chunks of one grid step, in VMEM from their
     inputs to their six results: chunk by chunk up to ``A`` (into
     ``a_ref``), then ``T`` and ``W``, ``U_0`` for all of them at once.
-    ``pad_ref`` and ``big_g_ref`` hold ``lift`` rows of zeros above an
-    operand (the running sum's and then ``K``; ``G``), so that a load ``s``
-    rows higher reads it moved down by ``s``."""
+    ``pad_ref`` holds the running sum's operand and then ``K``,
+    ``big_g_ref`` ``G``, both padded (the building blocks' comment)."""
     nb, c, dk = q_ref.shape
     dtype = q_ref.dtype
     scale = dk ** -0.5
-    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
     col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
     diag = lax.broadcasted_iota(jnp.int32, (c, c), 0) == col
     chunk_of = lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
     for ref in (pad_ref, big_g_ref):
         ref[:lift, :] = jnp.zeros((lift, dk), _F32)
-    # a padded operand's row ``at``, or its rows moved down by ``-at``: row
-    # ``i`` then holds ``x[i + at]``, zeros above
-    rows_of = lambda ref, at, n=c: ref[lift + at:lift + at + n, :]
 
     def one(j, ends):
         q = q_ref[j].astype(_F32) * scale
         k = k_ref[j].astype(_F32)
-        big_g = g_ref[j]
-        s = 1
-        while s < c:                # the running sum, log2(C) adds
-            pad_ref[lift:, :] = big_g
-            big_g = big_g + rows_of(pad_ref, -s)
-            s *= 2
+        big_g = _running_sum(g_ref[j], pad_ref, lift)
         big_g_ref[lift:, :] = big_g
         pad_ref[lift:, :] = k
-        beta = jnp.sum(jnp.where(diag, jnp.sum(
-            jnp.where(chunk_of == j, beta_ref[...], 0.0), axis=0,
-            keepdims=True), 0.0), axis=1, keepdims=True)     # (C, 1)
-        # the pairs inside a block of NEAR, decay by decay, one offset
-        # ``i - j`` at a time: A's strictly below the diagonal, B's on it too
-        a = jnp.zeros((c, c), _F32)
-        b = jnp.where(diag, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
-        for gap in range(1, min(NEAR, c)):
-            near = row % NEAR >= gap
-            decay = jnp.exp(jnp.where(
-                near, big_g - rows_of(big_g_ref, -gap), -jnp.inf))
-            kj = rows_of(pad_ref, -gap)
-            at = col == row - gap
-            a = a + jnp.where(at, jnp.sum(k * kj * decay, axis=1,
-                                          keepdims=True), 0.0)
-            b = b + jnp.where(at, jnp.sum(q * kj * decay, axis=1,
-                                          keepdims=True), 0.0)
-        # the halves of every block, split at its first half's last token
-        half = c // 2
-        while half >= NEAR:
-            span = 2 * half
-            second = row % span >= half
-            ref = rows_of(big_g_ref, half - 1, 1)
-            for n in range(1, c // span):
-                ref = jnp.where(row // span == n,
-                                rows_of(big_g_ref, n * span + half - 1, 1),
-                                ref)
-            cols = k * jnp.exp(jnp.where(second, -jnp.inf, ref - big_g))
-            rows = jnp.exp(jnp.where(second, big_g - ref, -jnp.inf))
-            same = row // span == col // span
-            a = a + jnp.where(same, _dot(k * rows, cols, dtype, -1), 0.0)
-            b = b + jnp.where(same, _dot(q * rows, cols, dtype, -1), 0.0)
-            half //= 2
+        beta = jnp.sum(jnp.where(diag, _row_of(beta_ref[...], j), 0.0),
+                       axis=1, keepdims=True)                # (C, 1)
+        # A's pairs strictly below the diagonal, B's on it too
+        a, b = _decayed((k, q), k, pad_ref, big_g, big_g_ref, lift, dtype)
+        b = b + jnp.where(diag, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
         a_ref[j] = beta * a
         # W's and U_0's right-hand sides, in their results' place until T
         # is known
         w_ref[j] = (beta * k * jnp.exp(big_g)).astype(dtype)
         u0_ref[j] = (beta * v_ref[j].astype(_F32)).astype(dtype)
-        end = rows_of(big_g_ref, c - 1, 1)
+        end = _shifted(big_g_ref, lift, c - 1, 1)
         b_ref[j] = b.astype(dtype)
         qg_ref[j] = (q * jnp.exp(big_g)).astype(dtype)
         kg_ref[j] = (k * jnp.exp(end - big_g)).astype(dtype)
@@ -352,6 +461,109 @@ def _kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
     inv = _unit_lower_inverse(a_ref[...], _split3, _highest)
     w_ref[...] = _dot(inv, w_ref[...], dtype).astype(dtype)
     u0_ref[...] = _dot(inv, u0_ref[...], dtype).astype(dtype)
+
+
+def _transpose_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
+                      dw_ref, du0_ref, db_ref, dqg_ref, dkg_ref, dend_ref,
+                      dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                      pad_ref, big_g_ref, k_pad_ref, a_ref, dkk_ref,
+                      g_all_ref, rw_ref, ru_ref, drw_ref, dru_ref, *, lift):
+    """The transpose of :func:`_local` for the chunks of one grid step, in
+    VMEM from the inputs and the six results' cotangents to the five
+    inputs' cotangents. ``G`` for all chunks at once, a row at a time
+    (:func:`_running_sums`), then chunk by chunk ``A`` and the right-hand
+    sides made again as :func:`_kernel` makes them; then for all chunks at
+    once ``T``, ``dT = dW (beta K e^G)^T + dU_0 (beta V)^T``, the
+    right-hand sides' cotangents ``T^T dW`` and ``T^T dU_0`` and ``dA =
+    -(T^T dT T^T)`` below the diagonal (``T``'s products at ``HIGHEST``);
+    then chunk by chunk the decayed products' transposes, every decay split
+    as the forward splits it, and the rest elementwise. ``pad_ref`` has
+    zeros above and below the operand (the running sums, the near pairs'
+    terms moved up)."""
+    nb, c, dk = q_ref.shape
+    dtype = q_ref.dtype
+    scale = dk ** -0.5
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    diag = lax.broadcasted_iota(jnp.int32, (c, c), 0) == col
+    chunk_of = lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
+    for ref in (pad_ref, big_g_ref, k_pad_ref):
+        ref[:lift, :] = jnp.zeros((lift, dk), _F32)
+    pad_ref[lift + c:, :] = jnp.zeros((lift, dk), _F32)
+    beta_of = lambda j: jnp.sum(jnp.where(
+        diag, _row_of(beta_ref[...], j), 0.0), axis=1, keepdims=True)
+
+    _running_sums(g_ref, g_all_ref)
+
+    def forward(j, carry):
+        k = k_ref[j].astype(_F32)
+        big_g = g_all_ref[j]
+        big_g_ref[lift:, :] = big_g
+        k_pad_ref[lift:, :] = k
+        beta = beta_of(j)
+        a, = _decayed((k,), k, k_pad_ref, big_g, big_g_ref, lift, dtype)
+        dkk_ref[j] = a
+        a_ref[j] = beta * a
+        rw_ref[j] = (beta * k * jnp.exp(big_g)).astype(dtype)
+        ru_ref[j] = (beta * v_ref[j].astype(_F32)).astype(dtype)
+        return carry
+
+    lax.fori_loop(0, nb, forward, 0)
+    inv = _unit_lower_inverse(a_ref[...], _split3, _highest)
+    dw, du0 = dw_ref[...], du0_ref[...]
+    d_inv = (_dot(dw, rw_ref[...], dtype, -1)
+             + _dot(du0, ru_ref[...], dtype, -1))
+    drw_ref[...] = _dot(inv, dw, dtype, over=-2)
+    dru_ref[...] = _dot(inv, du0, dtype, over=-2)
+    inv_t = _split3(jnp.swapaxes(inv, -1, -2))
+    below = (lax.broadcasted_iota(jnp.int32, (nb, c, c), 1)
+             > lax.broadcasted_iota(jnp.int32, (nb, c, c), 2))
+    a_ref[...] = jnp.where(below, -_highest(
+        _split3(_highest(inv_t, _split3(d_inv))), inv_t), 0.0)
+
+    def backward(j, d_betas):
+        q = q_ref[j].astype(_F32) * scale
+        k = k_ref[j].astype(_F32)
+        big_g = g_all_ref[j]
+        big_g_ref[lift:, :] = big_g
+        k_pad_ref[lift:, :] = k
+        beta = beta_of(j)
+        decay = jnp.exp(big_g)
+        end = _shifted(big_g_ref, lift, c - 1, 1)
+        to_end = jnp.exp(end - big_g)
+        d_a = a_ref[j]
+        d_b = jnp.where(col <= row, db_ref[j].astype(_F32), 0.0)
+        d_rw, d_ru = drw_ref[j], dru_ref[j]
+        d_qg = dqg_ref[j].astype(_F32)
+        d_kg = dkg_ref[j].astype(_F32) * to_end
+        (dx_a, dx_b), dy = _decayed_transpose(
+            (beta * d_a, d_b), (k, q), k, k_pad_ref, pad_ref, big_g,
+            big_g_ref, lift, dtype)
+        # K e^{G_C - G}'s share of G's cotangent: -x at G and the sum of x
+        # at G_C, the last row's own two shares left out (they cancel)
+        last = row == c - 1
+        to_g_end = jnp.where(last, 0.0, k * d_kg)
+        d_end = (jnp.sum(to_g_end, axis=0, keepdims=True)
+                 + _row_of(dend_ref[...], j) * jnp.exp(end))
+        d_g = (k * (d_rw * beta * decay + dx_a - dy) - to_g_end
+               + q * (dx_b + d_qg * decay) + jnp.where(last, d_end, 0.0))
+        dg_ref[j] = _running_sum(d_g, pad_ref, lift, reverse=True)
+        # B's diagonal: no decay, and no share of G's cotangent
+        d_diag = jnp.sum(jnp.where(diag, d_b, 0.0), axis=1, keepdims=True)
+        dq_ref[j] = (scale * (d_qg * decay + dx_b + d_diag * k)).astype(
+            dq_ref.dtype)
+        dk_ref[j] = (d_rw * beta * decay + d_kg + dx_a + dy
+                     + d_diag * q).astype(dk_ref.dtype)
+        dv_ref[j] = (beta * d_ru).astype(dv_ref.dtype)
+        d_beta = (jnp.sum(d_a * dkk_ref[j], axis=1, keepdims=True)
+                  + jnp.sum(d_rw * k * decay, axis=1, keepdims=True)
+                  + jnp.sum(d_ru * v_ref[j].astype(_F32), axis=1,
+                            keepdims=True))
+        return jnp.where(chunk_of == j, jnp.sum(
+            jnp.where(diag, d_beta, 0.0), axis=0, keepdims=True), d_betas)
+
+    dbeta_ref[...] = lax.fori_loop(0, nb, backward,
+                                   jnp.zeros((nb, c), _F32))
 
 
 def _block(heads: int, c: int, dk: int, dv: int,
@@ -378,16 +590,24 @@ def _block(heads: int, c: int, dk: int, dv: int,
     return nb
 
 
+def _specs(nb: int):
+    """A grid step's blocks, ``(into, out)``: ``nb`` heads of one chunk of
+    a ``(heads, chunks, ...)`` operand, and of one with the chunks in front
+    (``(chunks, heads, ...)``), each given the shape after those two axes."""
+    into = lambda *shape: pl.BlockSpec(
+        (nb, None, *shape), lambda t, h: (h, t) + (0,) * len(shape))
+    out = lambda *shape: pl.BlockSpec(
+        (None, nb, *shape), lambda t, h: (t, h) + (0,) * len(shape))
+    return into, out
+
+
 def _local_kernel(q, k, v, g, beta, nb: int):
     """:func:`_local`'s six results for every ``(head, chunk)`` of its
     operands as one kernel, with the chunks in front as the scan reads them
     (``(chunks, heads, ...)``): a grid step is one chunk of ``nb`` heads."""
     n, m, c, dk = q.shape
     dv = v.shape[-1]
-    into = lambda *shape: pl.BlockSpec(
-        (nb, None, *shape), lambda t, h: (h, t) + (0,) * len(shape))
-    out = lambda *shape: pl.BlockSpec(
-        (None, nb, *shape), lambda t, h: (t, h) + (0,) * len(shape))
+    into, out = _specs(nb)
     lift = max(c // 2, 8)
     return tuple(pl.pallas_call(
         functools.partial(_kernel, lift=lift),
@@ -405,6 +625,41 @@ def _local_kernel(q, k, v, g, beta, nb: int):
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
     )(q, k, v, g, jnp.swapaxes(beta, 0, 1).astype(_F32)))
+
+
+def _local_transpose(q, k, v, g, beta, d_parts, nb: int):
+    """``jax.vjp`` of :func:`_local` for every ``(head, chunk)`` as one
+    kernel: the cotangents of ``q``, ``k``, ``v``, ``g``, ``beta`` given
+    those of the six results with the chunks in front, as the reverse scan
+    leaves them; a grid step is one chunk of ``nb`` heads."""
+    n, m, c, dk = q.shape
+    dv = v.shape[-1]
+    into, out = _specs(nb)
+    lift = max(c // 2, 8)
+    vmem = lambda *shape, dt=_F32: pltpu.VMEM(shape, dt)
+    *grads, d_beta = pl.pallas_call(
+        functools.partial(_transpose_kernel, lift=lift),
+        grid=(m, n // nb),
+        in_specs=[into(c, dk), into(c, dk), into(c, dv), into(c, dk),
+                  out(c), out(c, dk), out(c, dv), out(c, c), out(c, dk),
+                  out(c, dk), out(dk)],
+        out_specs=[into(c, dk), into(c, dk), into(c, dv), into(c, dk),
+                   out(c)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (q, k, v, g)]
+        + [jax.ShapeDtypeStruct((m, n, c), _F32)],
+        scratch_shapes=[vmem(2 * lift + c, dk), vmem(lift + c, dk),
+                        vmem(lift + c, dk), vmem(nb, c, c), vmem(nb, c, c),
+                        vmem(nb, c, dk), vmem(nb, c, dk, dt=q.dtype),
+                        vmem(nb, c, dv, dt=q.dtype), vmem(nb, c, dk),
+                        vmem(nb, c, dv)],
+        # chunks of 128 take 16.8 MiB, past the 16 a kernel is given unasked
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=32 * 2 ** 20),
+        interpret=_interpret(),
+    )(q, k, v, g, jnp.swapaxes(beta, 0, 1).astype(_F32), *d_parts)
+    return (*grads, jnp.swapaxes(d_beta, 0, 1).astype(beta.dtype))
 
 
 def _carry(state, w, u0, b, qg, kg, end_decay, dtype):
@@ -490,14 +745,15 @@ def _chunked_bwd(nb, res, cts):
     dstate, d_parts = lax.scan(
         step, dfinal.astype(_F32),
         (states, jnp.moveaxis(do, 1, 0), parts), reverse=True)
-    d_parts = tuple(jnp.moveaxis(d, 0, 1) for d in d_parts)
+    if nb:
+        return (*_local_transpose(q, k, v, g, beta, d_parts, nb), dstate)
     # the chunk-local part's transpose, a group of heads at a time, through
-    # the jax.numpy form whichever made the parts: its forward is made once
-    # more there, and nothing of it is kept
+    # the jax.numpy form: its forward is made once more there, and nothing
+    # of it is kept
     local = lambda *xs: _local(*xs, dtype)
     grads = _in_groups(
         lambda *xs: jax.vjp(local, *xs[:5])[1](tuple(xs[5:])),
-        (q, k, v, g, beta, *d_parts))
+        (q, k, v, g, beta, *_by_chunk(d_parts)))
     return (*grads, dstate)
 
 
@@ -507,8 +763,9 @@ _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 def kernel_chunks(q, v, *, chunk: int = 64, impl: str = "auto") -> int:
     """``(sequence, head, chunk)`` triples whose chunk-local forward the
     kernel computes in :func:`kda` for ``q`` ``(batch, heads, seq, d_k)``
-    and ``v`` ``(batch, heads, seq, d_v)`` (shaped values): all of them, or
-    0 where the ``jax.numpy`` form does."""
+    and ``v`` ``(batch, heads, seq, d_v)`` (shaped values), and whose
+    transpose the gradient's kernel does: all of them, or 0 where the
+    ``jax.numpy`` form does."""
     b, h, seq, dk = q.shape
     chunks = b * h * -(-seq // chunk)
     return chunks if _block(b * h, chunk, dk, v.shape[-1], impl) else 0
@@ -528,8 +785,9 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     ``(o, final_state)``: ``o`` of ``v``'s shape and type, the state in
     float32. ``impl``: 'pallas' forces the chunk-local forward's kernel
     (interpret mode off-TPU), 'xla' the ``jax.numpy`` form, 'auto' picks
-    the kernel on TPU, where the shape is one it takes; the gradient's
-    transpose is ``jax.numpy`` either way."""
+    the kernel on TPU, where the shape is one it takes. The gradient's
+    chunk-local transpose follows: its kernel where the forward's is taken,
+    ``jax.vjp`` of the ``jax.numpy`` form where it is not."""
     b, h, seq, dk = q.shape
     if chunk < NEAR or chunk & (chunk - 1):
         raise ValueError(f"chunk ({chunk}) is not a power of two, {NEAR} or "

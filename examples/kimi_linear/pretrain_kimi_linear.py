@@ -23,10 +23,9 @@ is built: a layer routes over every expert and adds its own experts' terms.
 ``main(argv)`` returns the run's record, as ``pretrain_lfm2.main`` does:
 ``losses``, ``loss_scales``, ``found_inf``, ``moe`` (the counters of the last
 step: the routed experts', and the KDA layers' ``kda_min_chunk_log_decay``,
-``kda_chunks`` and ``kda_kernel_chunks``), ``first_step_seconds``,
-``seconds_per_step``,
-``tokens_per_step`` and the live ``train_step`` / ``params`` / ``opt_state``
-/ ``next_batch``.
+``kda_chunks``, ``kda_kernel_chunks`` and ``kda_transpose_chunks``),
+``first_step_seconds``, ``seconds_per_step``, ``tokens_per_step`` and the
+live ``train_step`` / ``params`` / ``opt_state`` / ``next_batch``.
 """
 
 from __future__ import annotations

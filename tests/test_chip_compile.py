@@ -80,3 +80,29 @@ def test_kda_chunk_local_kernel_compiles_for_the_chip(on_chip, chunk, dtype):
         lambda *xs: kda._local_kernel(*xs, nb=nb),
         operand(dtype), operand(dtype), operand(dtype), operand("float32"),
         on_chip((heads, chunks, chunk), jnp.float32)) == 1
+
+
+@pytest.mark.parametrize("chunk,dtype", [
+    (64, "bfloat16"),   # kimi_linear_48b_a3b.pretrain_b2s8192
+    (32, "bfloat16"),   # the envelope's other chunks
+    (128, "bfloat16"),
+    (64, "float32"),    # an O0 run
+])
+def test_kda_transpose_kernel_compiles_for_the_chip(on_chip, chunk, dtype):
+    """The chunk-local part's transpose at the cell's widths, given the six
+    results' cotangents with the chunks in front as the reverse scan leaves
+    them: one Mosaic call and nothing around it but ``beta``'s two
+    transposes."""
+    from apex_tpu.ops import kda
+
+    heads, chunks, d = 64, 8192 // chunk, 128
+    nb = kda._block(heads, chunk, d, d, "auto")
+    operand = lambda dt: on_chip((heads, chunks, chunk, d), jnp.dtype(dt))
+    result = lambda *shape, dt=dtype: on_chip((chunks, heads, *shape),
+                                              jnp.dtype(dt))
+    cotangents = (result(chunk, d), result(chunk, d), result(chunk, chunk),
+                  result(chunk, d), result(chunk, d), result(d, dt="float32"))
+    assert _mosaic_calls(
+        lambda *xs: kda._local_transpose(*xs[:5], xs[5:], nb=nb),
+        operand(dtype), operand(dtype), operand(dtype), operand("float32"),
+        on_chip((heads, chunks, chunk), jnp.float32), *cotangents) == 1

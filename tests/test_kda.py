@@ -3,9 +3,10 @@ recurrence token by token: outputs, final state and every gradient, at chunk
 sizes that do and do not divide the sequence, with an initial state, and at
 decays strong enough that a chunk's cumulative log-decay passes -100, where
 a factorisation that leaves float32's range gives inf or nan. Each through
-the ``jax.numpy`` chunk-local forward (``impl='xla'``) and through its
-kernel (``'pallas'``, interpret mode here), which must compute what
-``_local`` does output by output."""
+the ``jax.numpy`` chunk-local part (``impl='xla'``) and through its two
+kernels (``'pallas'``, interpret mode here): the forward's must compute what
+``_local`` does output by output, the transpose's what ``jax.vjp`` of
+``_local`` does cotangent by cotangent."""
 
 import jax
 import jax.numpy as jnp
@@ -16,9 +17,11 @@ from apex_tpu.ops import kda as kda_module
 from apex_tpu.ops.kda import (
     BLOCK,
     NEAR,
+    _by_chunk,
     _decayed_products,
     _local,
     _local_kernel,
+    _local_transpose,
     _unit_lower_inverse,
     chunk_log_decay,
     kda,
@@ -63,6 +66,21 @@ def _both(fn, args, with_state=True):
     return out, grads
 
 
+@pytest.fixture
+def transposes(monkeypatch):
+    """Counts the transpose kernel's bodies traced (interpret mode traces
+    one a grid step)."""
+    seen = []
+    body = kda_module._transpose_kernel
+
+    def counted(*refs, **kw):
+        seen.append(1)
+        return body(*refs, **kw)
+
+    monkeypatch.setattr(kda_module, "_transpose_kernel", counted)
+    return seen
+
+
 def _close(got, want, tol):
     for a, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
@@ -80,8 +98,10 @@ def _close(got, want, tol):
     (128, 64, 4.0),     # past -100 a chunk: exp(-G) alone is inf
     (64, 32, 30.0),     # -30 a token: past -600 a chunk
 ], ids=lambda x: str(x))
-def test_chunked_equals_the_recurrence_and_so_do_its_gradients(seq, chunk,
-                                                               decay, impl):
+def test_chunked_equals_the_recurrence_and_so_do_its_gradients(
+        seq, chunk, decay, impl, transposes):
+    """With ``impl='pallas'`` the gradient goes through the transpose's
+    kernel, and through nothing else for the chunk-local part."""
     args = _inputs(seq, decay)
     if decay >= 4.0:
         worst = float(chunk_log_decay(args[3], chunk).min())
@@ -89,6 +109,7 @@ def test_chunked_equals_the_recurrence_and_so_do_its_gradients(seq, chunk,
         # the plain split of the decays is what this case plants
         assert not np.isfinite(np.exp(np.float32(-worst)))
     got = _both(lambda *a, **kw: kda(*a, chunk=chunk, impl=impl, **kw), args)
+    assert bool(transposes) == (impl == "pallas")
     want = _both(kda_recurrent, args)
     _close(got, want, 2e-5)
 
@@ -134,10 +155,12 @@ def test_bf16_operands_float32_state(impl):
 
 
 @pytest.mark.parametrize("impl", IMPLS)
-def test_every_exponent_taken_is_at_most_zero(monkeypatch, impl):
+def test_every_exponent_taken_is_at_most_zero(monkeypatch, impl,
+                                              transposes):
     """The hazard, held at its root: through forward and backward at a
     decay of -30 a token, ``exp`` never sees a positive argument (inside the
-    kernel too: interpret mode runs its body as ``jax.numpy``)."""
+    kernels too, the transpose's among them: interpret mode runs their
+    bodies as ``jax.numpy``)."""
     seen = []
     real = jnp.exp
 
@@ -152,6 +175,7 @@ def test_every_exponent_taken_is_at_most_zero(monkeypatch, impl):
     jax.block_until_ready(grad)
     jax.effects_barrier()
     assert len(seen) > 10 and max(seen) <= 0.0
+    assert bool(transposes) == (impl == "pallas")
 
 
 def test_decayed_products_pair_by_pair():
@@ -226,6 +250,38 @@ def test_the_kernel_computes_what_local_does(chunk, decay, dtype):
     for a, r in zip(got, want):
         assert a.dtype == r.dtype and a.shape == (3, B * H, *r.shape[2:])
         _close(jnp.moveaxis(a, 0, 1), r, tol)
+
+
+@pytest.mark.parametrize("chunk", [32, 64, 128])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("steep", [False, True], ids=["mild", "steep"])
+def test_the_transpose_kernel_computes_what_vjp_of_local_does(chunk, dtype,
+                                                              steep):
+    """The five cotangents, one by one, given random cotangents of the six
+    results with the chunks in front, as the reverse scan leaves them;
+    steep decays put a chunk's cumulative log-decay past -88, where the
+    plain split of the decays would leave float32. float32 to a few
+    roundings of the running sum ``G`` (the decays' exponents are
+    differences of it, -600 deep at 30 a token), bf16 to a rounding or two
+    of bf16 (the ``jax.numpy`` form rounds ``T``'s and the right-hand
+    sides' cotangents to bf16, the kernel keeps them float32)."""
+    dtype = jnp.dtype(dtype)
+    decay = {32: 30.0, 64: 4.0, 128: 2.0}[chunk] if steep else 0.1
+    q, k, v, g, beta, _ = _inputs(3 * chunk, decay, seed=17, dtype=dtype)
+    assert (float(chunk_log_decay(g, chunk).min()) < -88.0) == steep
+    parts = lambda x: x.reshape(B * H, 3, chunk, *x.shape[3:])
+    args = tuple(parts(x) for x in (q, k, v, g, beta))
+    local = lambda *xs: _local(*xs, dtype)
+    keys = jax.random.split(jax.random.PRNGKey(chunk), 6)
+    cts = tuple(jax.random.normal(key, r.shape).astype(r.dtype) for key, r
+                in zip(keys, jax.eval_shape(local, *args)))
+    want = jax.vjp(local, *args)[1](cts)
+    got = _local_transpose(*args, _by_chunk(cts), nb=2)
+    assert len(got) == len(want) == 5
+    tol = 1e-4 if dtype == jnp.float32 else 1.5e-2
+    for a, r in zip(got, want):
+        assert a.dtype == r.dtype and a.shape == r.shape
+        _close(a, r, tol)
 
 
 def test_auto_takes_the_kernel_inside_its_envelope_alone(monkeypatch):

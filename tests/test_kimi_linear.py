@@ -348,22 +348,25 @@ def test_one_scan_a_run_and_one_compile_for_the_stack():
     assert set(run["moe"]) == {
         "assignments", "max_load_over_mean", "overflow", "rows_moved",
         "expert_rows", "kda_min_chunk_log_decay", "kda_chunks",
-        "kda_kernel_chunks"}
+        "kda_kernel_chunks", "kda_transpose_chunks"}
     assert all(len(v) == 4 for v in run["moe"].values())
     assert sum(run["moe"]["overflow"]) == 0
     # 2 rows x 4 heads x 2 chunks of 64 tokens a layer; off the chip 'auto'
-    # computes their chunk-local forward in jax.numpy, not in the kernel
+    # computes their chunk-local forward and its transpose in jax.numpy, not
+    # in the kernels
     assert run["moe"]["kda_chunks"] == [16.0] * 4
     assert run["moe"]["kda_kernel_chunks"] == [0.0] * 4
+    assert run["moe"]["kda_transpose_chunks"] == [0.0] * 4
     assert all(-1e4 < x < 0 for x in run["moe"]["kda_min_chunk_log_decay"])
 
 
 
 def test_the_kernel_counts_every_chunk_it_computes(monkeypatch):
-    """With the chunk-local forward's kernel forced (interpret mode here, as
-    the chip would take it), ``kda_kernel_chunks`` is ``kda_chunks`` in
-    every KDA layer, and the trainer's losses are today's code's to bf16's
-    rounding."""
+    """With the chunk-local kernels forced (interpret mode here, as the chip
+    would take them), ``kda_kernel_chunks`` and ``kda_transpose_chunks``
+    are ``kda_chunks`` in every KDA layer, and the trainer's losses are the
+    ``jax.numpy`` form's to bf16's rounding (the second step's after a
+    gradient through the transpose's kernel)."""
     import pretrain_kimi_linear
     from apex_tpu.ops import kda as kda_module
 
@@ -371,7 +374,7 @@ def test_the_kernel_counts_every_chunk_it_computes(monkeypatch):
     monkeypatch.setattr(kda_module, "_resolve_impl", lambda _: "pallas")
     run = pretrain_kimi_linear.main([*TINY_ARGV, "--steps", "2"])
     assert run["moe"]["kda_kernel_chunks"] == run["moe"]["kda_chunks"] \
-        == [16.0] * 4
+        == run["moe"]["kda_transpose_chunks"] == [16.0] * 4
     np.testing.assert_allclose(run["losses"], plain["losses"], rtol=1e-2)
 
 

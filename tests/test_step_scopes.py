@@ -341,13 +341,15 @@ def test_under_moe_experts_only_products_and_kernels_are_buffer_sized(
 
 
 def test_the_kda_kernel_keeps_the_scan_scope(monkeypatch):
-    """With the chunk-local forward's kernel taken as the chip takes it, its
-    ``pallas_call`` lies under ``kda_scan`` in the forward pass, in the
-    recompute and in the backward pass (the scan's operands made again):
-    a kernel that lost the scope would read as a gain in
-    ``train.kda_scan_ms`` and as an impossible share in
-    ``train.kda_scan_roofline``. Off the chip every other kernel of the
-    step takes its ``jax.numpy`` form, so each ``pallas_call`` is one."""
+    """With the chunk-local kernels taken as the chip takes them, the
+    forward's ``pallas_call`` lies under ``kda_scan`` in the forward pass,
+    in the recompute and in the backward pass (the scan's operands made
+    again), and the transpose's (five results, not six) under ``kda_scan``
+    inside ``transpose(jvp(...))``, once a KDA layer: a kernel that lost
+    the scope would read as a gain in ``train.kda_scan_ms`` and as an
+    impossible share in ``train.kda_scan_roofline``. Off the chip every
+    other kernel of the step takes its ``jax.numpy`` form, so each
+    ``pallas_call`` is one of these two."""
     from apex_tpu.ops import kda
     from chipbench.programs import pretrain_kimi_linear
     from chipbench.tests import tiny_kimi_linear
@@ -355,13 +357,21 @@ def test_the_kda_kernel_keeps_the_scan_scope(monkeypatch):
     monkeypatch.setattr(kda, "_resolve_impl", lambda _: "pallas")
     _, step, args = _trainer_step(pretrain_kimi_linear,
                                   tiny_kimi_linear.cell(ROOT))
-    calls = [name for prim, name, _ in _traced(
+    found = [(name, len(shapes)) for prim, name, shapes in _traced(
         jax.make_jaxpr(step)(*args).jaxpr) if prim == "pallas_call"]
+    calls = [name for name, _ in found]
     assert calls and _some(calls, token("kda_scan")) == calls, calls
-    assert _some(calls, r"jvp\(", none=(r"transpose\(",
-                                        "rematted_computation"))
-    assert _some(calls, token("layers"), "/rematted_computation/")
-    assert _some(calls, r"transpose\(", none=("rematted_computation",))
+    forward = [name for name, results in found if results == 6]
+    transposes = [name for name, results in found if results == 5]
+    assert len(forward) + len(transposes) == len(calls)
+    assert _some(forward, r"jvp\(", none=(r"transpose\(",
+                                          "rematted_computation"))
+    assert _some(forward, token("layers"), "/rematted_computation/")
+    assert _some(forward, r"transpose\(", none=("rematted_computation",))
+    # forward, recompute and the backward's operands: three a layer
+    assert transposes and len(forward) == 3 * len(transposes)
+    assert _some(transposes, r"transpose\(jvp\(",
+                 none=("rematted_computation",)) == transposes
 
 
 def _reader_ctx(scopes):
